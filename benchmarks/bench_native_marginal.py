@@ -1,9 +1,9 @@
 """Native-stack (pjrt_tool) marginal batch cost under the paired-trial
 protocol.
 
-BASELINE.md's r3 probe measured t(9)-t(5) marginal cost twice on the same
-day and got 1.1 s/batch and 4.4 s/batch — single-shot CLI timings through
-the relay cannot support a steady-state-throughput claim.  This runs k
+An early probe measured t(9)-t(5) marginal cost twice on the same day and
+got 1.1 s/batch and 4.4 s/batch — single-shot CLI timings on the host's
+clock cannot support a steady-state-throughput claim.  This runs k
 interleaved (few, many) invocation pairs; each round's marginal cost is
 (t_many - t_few) / (n_many - n_few), which cancels the ~27 s one-time
 setup (client create + cached compile + params upload) within the round,

@@ -38,12 +38,12 @@ STEPS = 10
 
 
 def main():
-    from sparkdl_tpu.resilience.watchdog import guard_device
+    from sparkdl_tpu.utils.benchlib import accelerator_or_refuse
 
-    if not guard_device(
+    if accelerator_or_refuse(
         "FlaxImageFileEstimator(ViT-B/16->5cls) DP fine-tune step time",
         unit=f"ms/step (batch {BATCH})",
-    ):
+    ) is None:
         return 2
 
     import jax.numpy as jnp
@@ -88,7 +88,8 @@ def main():
     }
     batch = shard_batch(batch, mesh)
 
-    # two warmup steps: see bench_finetune.py (donated-state relayout)
+    # two warmup steps: see bench_finetune.py (the donated state changes
+    # layout after the first step)
     for _ in range(2):
         state, loss = step_fn(state, batch)
         float(loss)

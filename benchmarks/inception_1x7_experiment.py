@@ -1,13 +1,12 @@
 """Layout-alignment experiment: InceptionV3's factorized 1x7/7x1 convs.
 
-BASELINE.md r3 profiled the two worst InceptionV3 ops — the factorized
+An r3 profile found the two worst InceptionV3 ops — the factorized
 1x7/7x1 convs at (512,17,17,192) — at 22 TF/s / 32 GB/s and attributed
 it to T(8,128) sublane padding at W=17 (~30% waste); three Pallas
 kernels at the exact shape lost to XLA (r3, recorded negatives — do not
 retry).  r4's Xception result showed the cheap lever for this op class
 is LAYOUT PADDING, not custom kernels: K=728→768 lane alignment bought
-1.48x with zero kernel work.  This runs the analogous experiments here
-(VERDICT r4 next #4):
+1.48x with zero kernel work.  This runs the analogous experiments here:
 
 - **spatial pad**: W 17→24 before a 1x7 (H before a 7x1), crop right
   after the conv+BN+relu — 3 exact sublane tiles instead of 2+9/17.

@@ -1,14 +1,14 @@
 """Lane-alignment experiment: Xception middle flow at 728 vs 768 channels.
 
-BASELINE.md r3 left ONE open compute headroom: the middle flow's K=728
+An r3 profile left ONE open compute headroom: the middle flow's K=728
 1x1-conv fusions run at 59 TF/s = 42% of the chip's conv-demonstrated
 ~139 TF/s, and 728 = 5.69 x 128 is not MXU-lane-aligned.  This measures
 whether zero-padding the trunk to 768 = 6 x 128 (+5.6% FLOPs, numerics
 unchanged — zero channels propagate as zeros) unlocks the conv emitter's
-tiling (VERDICT r3 weak #1 / next #3).
+tiling.
 
-Two reads per width, both with the scan-amortized methodology (the only
-timing that survives the loopback relay — BASELINE.md measurement notes):
+Two reads per width, both with the scan-amortized methodology (per-call
+host timing of an asynchronous dispatch is wrong in both directions):
 
 - the full fused featurize program (what bench.py measures), and
 - a middle-flow-only program (8 residual blocks at 19x19xW), where the

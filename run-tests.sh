@@ -28,7 +28,7 @@ if [[ $rc -ne 0 ]]; then
   exit "$rc"
 fi
 
-# Honesty gate (VERDICT r3 #7): a rig that ships every optional
+# Honesty gate: a rig that ships every optional
 # dependency (torch/transformers/keras/tensorflow/orbax, a C++ toolchain
 # for the native targets) must report ZERO skipped tests — the suite's
 # 241-passed-0-skipped signal is real; if oracle tests start silently

@@ -19,7 +19,9 @@ Three pieces, one owner:
 from sparkdl_tpu.engine.cache import (
     PersistentCompileCache,
     cache_key,
+    compile_cache_root,
     default_cache_dir,
+    enable_jax_cache,
 )
 from sparkdl_tpu.engine.core import EngineFunction, ExecutionEngine, ProgramHandle
 from sparkdl_tpu.engine.executor import (
@@ -44,7 +46,9 @@ __all__ = [
     "SlotPool",
     "slot_block_fingerprint",
     "cache_key",
+    "compile_cache_root",
     "default_cache_dir",
     "dispatch_depth",
+    "enable_jax_cache",
     "engine",
 ]

@@ -28,10 +28,16 @@ Key discipline (what :func:`cache_key` hashes):
 - **jax/jaxlib versions** — serialized executables are not stable across
   runtime upgrades, so a version bump simply misses and recompiles.
 
-Disk layout (``SPARKDL_COMPILE_CACHE`` or ``~/.cache/sparkdl_tpu/
-executables``)::
+Placement: :func:`compile_cache_root` is the one root of everything this
+package caches on disk — ``$JAX_COMPILATION_CACHE_DIR`` where it is set
+(JAX reads it itself and the code sets no directory anywhere), otherwise
+the fixed, git-ignored ``.compile_cache/`` of the checkout, handed to
+JAX's own persistent compilation cache by :func:`enable_jax_cache`.  This
+store lives in the ``executables/`` subdirectory of that root
+(``SPARKDL_COMPILE_CACHE`` redirects or disables the store alone)::
 
-    <dir>/<key[:2]>/<key>.exe    pickled (payload, in_tree, out_tree)
+    <dir>/<key[:2]>/<key>.exe    pickled (payload, in_tree, out_tree,
+                                 device ids the program was compiled for)
     <dir>/<key[:2]>/<key>.json   human-readable key components
 
 Writes are atomic (tmp + rename), loads are best-effort: a corrupt,
@@ -54,15 +60,52 @@ logger = logging.getLogger(__name__)
 
 _ENV_VAR = "SPARKDL_COMPILE_CACHE"
 _OFF_VALUES = ("off", "none", "0", "disabled")
+_JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: the in-checkout root.  Fixed on purpose: the directory is part of
+#: JAX's cache key, so a root that moves (a temporary name, a pid, the
+#: time) never hits.
+_CHECKOUT_CACHE_ROOT = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".compile_cache",
+)
 
 #: soft disk budget; oldest entries are pruned past it at store time
 DEFAULT_MAX_BYTES = 20 * 1024**3
 
 
-def default_cache_dir() -> Optional[str]:
-    """The active cache directory, or None when persistence is disabled.
+def _placed_from_outside() -> str:
+    return os.environ.get(_JAX_CACHE_ENV, "").strip()
 
-    Reads ``SPARKDL_COMPILE_CACHE`` on every call so tests (and operators
+
+def compile_cache_root() -> str:
+    """The root of every compile cache the package writes:
+    ``$JAX_COMPILATION_CACHE_DIR`` where it is set, else the fixed
+    ``.compile_cache/`` inside the checkout."""
+    return _placed_from_outside() or _CHECKOUT_CACHE_ROOT
+
+
+def enable_jax_cache() -> None:
+    """Point JAX's persistent compilation cache at
+    :func:`compile_cache_root`.  Called from the places that compile
+    first (engine construction, the replica's ``main()``,
+    ``chip_smoke.py``, ``bench.py``) and idempotent: with
+    ``JAX_COMPILATION_CACHE_DIR`` set JAX has read the directory itself,
+    and a directory somebody already configured is left alone."""
+    if _placed_from_outside():
+        return
+    import jax
+
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_ROOT)
+
+
+def default_cache_dir() -> Optional[str]:
+    """The executable store's directory, or None when it is disabled.
+
+    Reads the environment on every call so tests (and operators
     mid-process) can redirect or disable it without rebuilding engines.
     """
     spec = os.environ.get(_ENV_VAR, "").strip()
@@ -70,9 +113,7 @@ def default_cache_dir() -> Optional[str]:
         return None
     if spec:
         return spec
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "sparkdl_tpu", "executables"
-    )
+    return os.path.join(compile_cache_root(), "executables")
 
 
 def _runtime_descriptor() -> Dict[str, Any]:
@@ -181,11 +222,18 @@ class PersistentCompileCache:
             return None
         try:
             with open(exe_path, "rb") as fh:
-                payload, in_tree, out_tree = pickle.load(fh)
+                payload, in_tree, out_tree, device_ids = pickle.load(fh)
+            import jax
             from jax.experimental import serialize_executable
 
+            # onto the devices it was compiled for: left to its default
+            # the loader spreads the program over EVERY device of the
+            # backend, and a one-device program then wants a shard per
+            # local device
+            by_id = {d.id: d for d in jax.devices()}
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
             )
         except Exception as exc:
             logger.warning(
@@ -212,12 +260,15 @@ class PersistentCompileCache:
             payload, in_tree, out_tree = serialize_executable.serialize(
                 compiled
             )
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
             os.makedirs(os.path.dirname(exe_path), exist_ok=True)
             fd, tmp = tempfile.mkstemp(
                 dir=os.path.dirname(exe_path), suffix=".tmp"
             )
             with os.fdopen(fd, "wb") as fh:
-                pickle.dump((payload, in_tree, out_tree), fh)
+                pickle.dump((payload, in_tree, out_tree, device_ids), fh)
             os.replace(tmp, exe_path)
             with open(meta_path + ".tmp", "w") as fh:
                 json.dump(meta or {}, fh, indent=1, default=str)

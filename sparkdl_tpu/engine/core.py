@@ -46,6 +46,7 @@ from sparkdl_tpu.engine.cache import (
     _runtime_descriptor,
     _sharding_descriptor,
     cache_key,
+    enable_jax_cache,
 )
 from sparkdl_tpu.utils.lru import LRUCache
 
@@ -150,6 +151,9 @@ class ExecutionEngine:
         cache: Optional[PersistentCompileCache] = None,
         persistent: bool = True,
     ):
+        # every program the package compiles comes through an engine, so
+        # this is where JAX's own persistent cache gets its directory
+        enable_jax_cache()
         self._programs = LRUCache(maxsize)
         self._meta: Dict[str, Dict[str, Any]] = {}
         self.cache = (

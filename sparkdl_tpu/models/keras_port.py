@@ -141,8 +141,8 @@ def pad_variables_to_module(variables, module, input_size):
     """Zero-pad ported Keras weights up to a widened TPU-layout module.
 
     Some registry modules widen channel trunks for MXU lane alignment
-    (e.g. Xception's 728 -> 768 = 6x128 middle flow, +20% measured
-    throughput — BASELINE.md r4).  The target shapes come from
+    (e.g. Xception's 728 -> 768 = 6x128 middle flow, +20% throughput
+    measured on the chip in r4).  The target shapes come from
     ``jax.eval_shape(module.init)``; every leaf whose target is wider
     pads at the high end of the differing axes with zeros — except BN
     running variances, which pad with ones (identity statistics).  The

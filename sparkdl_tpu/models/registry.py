@@ -158,7 +158,7 @@ KERAS_APPLICATION_MODELS: Dict[str, KerasApplicationModel] = {
                               (299, 299), 2048, "tf"),
         # middle_width=768 (vs Keras's 728): 6x128 MXU lane alignment
         # buys +20% throughput on this chip for +5.6% padded FLOPs
-        # (BASELINE.md r4 receipts); Keras weights port zero-padded,
+        # (measured on the chip in r4); Keras weights port zero-padded,
         # numerics unchanged
         KerasApplicationModel("Xception", Xception, "Xception",
                               (299, 299), 2048, "tf",

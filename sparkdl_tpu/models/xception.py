@@ -20,7 +20,7 @@ from sparkdl_tpu.models.layers import SeparableConv, global_avg_pool, max_pool
 
 class Xception(nn.Module):
     """``middle_width`` widens the 728-channel middle-flow trunk (e.g. to
-    768 = 6x128 for MXU lane alignment — the BASELINE.md r3 open-headroom
+    768 = 6x128 for MXU lane alignment — the r3 open-headroom
     experiment).  At the default 728 the module is exactly the Keras
     architecture; widened variants hold the Keras weights zero-padded
     (zero channels propagate as zeros through depthwise/pointwise/BN/relu

@@ -7,10 +7,13 @@ resize, threaded across rows), replacing the per-row Python loop in the
 transformer/UDF hot path.
 
 The library is built on demand with ``g++`` (no pybind11 in this
-environment; plain C ABI + ctypes).  Everything degrades gracefully: if the
-toolchain or the build is unavailable, callers fall back to the pure-Python
-path — ``is_available()`` gates every use.  Set ``SPARKDL_NO_NATIVE=1`` to
-force the Python path.
+environment; plain C ABI + ctypes) from the committed source, at first use.
+Where there is no C++ compiler (or ``SPARKDL_NO_NATIVE=1``), callers use
+the pure-Python path — ``is_available()`` gates every use.  Where there IS
+a compiler and the build or the load fails, that is an error
+(:class:`NativeBuildError`), not a quiet switch of paths: a run must not
+pack its batches through a slower path than the one its host supports
+without saying so.
 """
 
 from __future__ import annotations
@@ -37,8 +40,15 @@ _tried = False
 _inflight: Optional[threading.Event] = None
 
 
+class NativeBuildError(RuntimeError):
+    """The host has a C++ compiler, and the bridge still could not be
+    built or loaded."""
+
+
 def _build() -> bool:
-    """Compile the shared library next to the source (one-time).
+    """Compile the shared library next to the source (one-time); False
+    where the host has no compiler, :class:`NativeBuildError` where it
+    has one and the build fails.
 
     Builds to a process-unique temp name and renames into place, so
     concurrent executor processes never dlopen a half-written .so.
@@ -53,20 +63,17 @@ def _build() -> bool:
         proc = subprocess.run(
             cmd, capture_output=True, text=True, timeout=300
         )
-    except (OSError, subprocess.TimeoutExpired) as e:  # no toolchain
+    except FileNotFoundError as e:  # no toolchain on this host
         logger.info("native bridge build unavailable: %s", e)
         return False
+    except subprocess.TimeoutExpired as e:
+        raise NativeBuildError(f"native bridge build timed out: {e}") from e
     if proc.returncode != 0:
-        logger.warning(
-            "native bridge build failed (falling back to Python path):\n%s",
-            proc.stderr[-2000:],
+        raise NativeBuildError(
+            f"native bridge build failed ({' '.join(cmd)}):\n"
+            f"{proc.stderr[-2000:]}"
         )
-        return False
-    try:
-        os.replace(tmp, _SO_PATH)  # atomic on POSIX
-    except OSError as e:
-        logger.warning("native bridge install failed: %s", e)
-        return False
+    os.replace(tmp, _SO_PATH)  # atomic on POSIX
     return True
 
 
@@ -92,12 +99,16 @@ def _load() -> Optional[ctypes.CDLL]:
             waiter = _inflight
         waiter.wait()
     lib = None
+    resolved = False
     try:
         lib = _resolve()
+        resolved = True
     finally:
         with _lock:
             _lib = lib
-            _tried = True
+            # a failed build stays an error for the next caller too,
+            # instead of turning into "unavailable" after the first raise
+            _tried = resolved
             _inflight = None
         claim.set()
     return lib
@@ -124,11 +135,11 @@ def _resolve() -> Optional[ctypes.CDLL]:
     try:
         lib = ctypes.CDLL(_SO_PATH)
     except OSError as e:
-        logger.warning("native bridge load failed: %s", e)
-        return None
+        raise NativeBuildError(f"native bridge load failed: {e}") from e
     if lib.sdl_abi_version() != 1:
-        logger.warning("native bridge ABI mismatch; ignoring")
-        return None
+        raise NativeBuildError(
+            f"native bridge ABI mismatch in {_SO_PATH}; delete it to rebuild"
+        )
     lib.sdl_pack_resize_batch.restype = ctypes.c_int64
     lib.sdl_pack_resize_batch.argtypes = [
         ctypes.POINTER(ctypes.c_void_p),  # datas

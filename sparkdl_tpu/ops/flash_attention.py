@@ -212,26 +212,13 @@ def _out_struct(x, shape=None, dtype=None):
     """ShapeDtypeStruct mirroring x's vma (shard_map check_vma support)."""
     shape = x.shape if shape is None else shape
     dtype = x.dtype if dtype is None else dtype
-    vma = _vma(x)
+    vma = jax.typeof(x).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _vma(x):
-    """x's varying-manual-axes set, or None (older jax has no jax.typeof
-    and no vma tracking at all)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
-
-
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-_PARAMS = _CompilerParams(
+_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
 )
 
@@ -389,7 +376,7 @@ def flash_attention(
     b, s, h, d = q.shape
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if interpret and _vma(q):
+    if interpret and jax.typeof(q).vma:
         # Pallas interpret mode inside shard_map(check_vma=True): the
         # interpreter's scratch buffers carry no varying-axes type, so the
         # checker rejects the kernel body.  The CPU test mesh is the only

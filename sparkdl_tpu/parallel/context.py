@@ -30,18 +30,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
-from sparkdl_tpu.parallel._shard_map import shard_map
-
-
-
-def _axis_size(axis_name):
-    """``lax.axis_size`` with the 0.4.x fallback (``psum(1, axis)`` constant-
-    folds to the static mesh-axis size during tracing)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
 
 def full_attention(
     q,
@@ -94,19 +84,16 @@ def ring_attention(
     ``causal=True`` masks by global position (block offsets derived from
     ``lax.axis_index``), supporting autoregressive use.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     b, s_blk, h, d = q.shape
     q = q * scale
 
     # online-softmax accumulators, marked device-varying over the ring
-    # axis so the fori_loop carry types stay consistent (a no-op on 0.4.x,
-    # which has no varying-type tracking — and no pcast)
+    # axis so the fori_loop carry types stay consistent
     def _varying(x):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, (axis_name,), to="varying")
-        return x
+        return lax.pcast(x, (axis_name,), to="varying")
 
     acc = _varying(jnp.zeros((b, s_blk, h, d), jnp.float32))
     denom = _varying(jnp.zeros((b, h, s_blk), jnp.float32))
@@ -182,7 +169,7 @@ def ulysses_attention(
     score matrix out of HBM on long sequences (``impl="ulysses-flash"``
     in :func:`make_sp_attention`).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, s_blk, h, d = q.shape
     if h % n != 0:
         raise ValueError(

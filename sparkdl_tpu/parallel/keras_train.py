@@ -18,11 +18,11 @@ from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import optax
 
-from sparkdl_tpu.parallel._shard_map import shard_map
 from sparkdl_tpu.parallel.trainer import Mesh
 
 
@@ -77,27 +77,26 @@ def make_keras_train_step(
                     return (per * w).sum() / w_total, new_nt
                 return loss_fn(local_batch["y"], outputs), new_nt
 
+            # cast the replicated params to varying so the grads are
+            # shard-local and the one allreduce below is explicit (see
+            # trainer.make_train_step: left unvarying, the transpose has
+            # already summed them over the data axis)
             (loss, new_nt), grads = jax.value_and_grad(
                 local_loss, has_aux=True
-            )(trainable)
-            # value_and_grad runs inside the shard_map body, so grads are
-            # shard-local and the cross-device allreduce must be explicit
-            # (see trainer.make_train_step)
+            )(jax.lax.pcast(trainable, (data_axis,), to="varying"))
             if weighted:
                 # each shard's loss is its share of the global weighted
                 # mean; psum of loss and grads, with the global w_total
                 # normalization, is the exact weighted-mean gradient
-                loss = jax.lax.psum(loss, axis_name=data_axis)
-                grads = jax.tree_util.tree_map(
-                    lambda g: jax.lax.psum(g, axis_name=data_axis), grads
+                loss, grads = jax.lax.psum(
+                    (loss, grads), axis_name=data_axis
                 )
             else:
                 # equal-sized shards: mean of per-shard mean-loss grads ==
                 # the global-mean gradient
-                grads = jax.tree_util.tree_map(
-                    lambda g: jax.lax.pmean(g, axis_name=data_axis), grads
+                loss, grads = jax.lax.pmean(
+                    (loss, grads), axis_name=data_axis
                 )
-                loss = jax.lax.pmean(loss, axis_name=data_axis)
             # float stats (BN moving averages) averaged across shards;
             # integer state (RNG counters) is shard-invariant already
             new_nt = jax.tree_util.tree_map(

@@ -22,9 +22,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from sparkdl_tpu.parallel._shard_map import shard_map
 
 import optax
 
@@ -159,11 +158,14 @@ def make_train_step(
 
     def step(state: TrainState, batch):
         def sharded_grads(params, local_batch):
-            # value_and_grad runs INSIDE the shard_map body, so ``grads``
-            # are shard-local; the cross-device allreduce (the
-            # NCCL-allreduce analog, riding ICI) must be explicit.  (The
-            # implicit transpose-psum of replicated params only appears
-            # when differentiating *through* a shard_map from outside.)
+            # params come in replicated (unvarying over the data axis).
+            # Differentiating a per-shard loss w.r.t. an unvarying value
+            # makes the transpose psum the cotangents by itself, so a
+            # pmean/psum after it would count the shards a second time.
+            # Cast the params to varying first: the grads are then truly
+            # shard-local and the ONE allreduce below (the NCCL-allreduce
+            # analog, riding ICI) is explicit.
+            params = jax.lax.pcast(params, (data_axis,), to="varying")
             if weighted:
 
                 def local_weighted(p):
@@ -176,19 +178,11 @@ def make_train_step(
                 # mean; psum of both loss and grads, together with the
                 # global w_total normalization, is the exact weighted mean
                 loss, grads = jax.value_and_grad(local_weighted)(params)
-                loss = jax.lax.psum(loss, axis_name=data_axis)
-                grads = jax.tree_util.tree_map(
-                    lambda g: jax.lax.psum(g, axis_name=data_axis), grads
-                )
-                return loss, grads
+                return jax.lax.psum((loss, grads), axis_name=data_axis)
             loss, grads = jax.value_and_grad(loss_fn)(params, local_batch)
             # equal-sized shards: mean of per-shard mean-loss grads == the
             # global-mean gradient
-            grads = jax.tree_util.tree_map(
-                lambda g: jax.lax.pmean(g, axis_name=data_axis), grads
-            )
-            loss = jax.lax.pmean(loss, axis_name=data_axis)
-            return loss, grads
+            return jax.lax.pmean((loss, grads), axis_name=data_axis)
 
         batch_spec = jax.tree_util.tree_map(
             lambda x: P(*([data_axis] + [None] * (x.ndim - 1))), batch

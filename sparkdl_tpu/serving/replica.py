@@ -6,10 +6,11 @@ module wraps it in a process boundary so the supervisor can treat
 replicas like cattle: ``python -m sparkdl_tpu.serving.replica`` builds a
 server from a :class:`ReplicaSpec` (a dotted ``module:callable`` factory
 — the only thing that crosses the spawn boundary is a name, never a
-pickled closure), **pre-warms from the PR-5 persistent compile cache**
-(the spawned process inherits ``SPARKDL_COMPILE_CACHE``, so a restarted
-replica's warmup *loads* executables instead of recompiling — scale-up
-is cache-load-fast), reports liveness via the PR-8
+pickled closure), **pre-warms from the persistent compile cache**
+(every process resolves the same :func:`~sparkdl_tpu.engine.cache
+.compile_cache_root`, so a restarted replica's warmup *loads*
+executables instead of recompiling — scale-up is cache-load-fast),
+reports liveness via the PR-8
 :class:`~sparkdl_tpu.obs.server.ObsServer` ``/healthz``, and serves the
 :mod:`~sparkdl_tpu.serving.wire` protocol on a loopback TCP port.
 
@@ -804,9 +805,12 @@ def main() -> int:
     # a SPARKDL_FAULT_PLAN with faultnet.* rules installs the frame-
     # level byte-corruption tap in THIS process too, so replica->router
     # reply frames brown out alongside router->replica requests
+    from sparkdl_tpu.engine.cache import enable_jax_cache
     from sparkdl_tpu.serving import faultnet
 
     faultnet.arm()
+    # before the factory runs: it may compile before it builds an engine
+    enable_jax_cache()
     spec = ReplicaSpec.from_env()
     server = spec.build_server()
     warmup_report: Dict[str, Any] = {}

@@ -352,7 +352,7 @@ class ModelServer:
         return out
 
     def status(self, probe_device: bool = False,
-               probe_timeout_s: int = 60) -> Dict[str, Any]:
+               probe_timeout_s: float = 60.0) -> Dict[str, Any]:
         """A ``/healthz``-style snapshot: endpoints, queue depths, cache
         occupancy, and the ``serving.*`` metrics.
 
@@ -362,12 +362,12 @@ class ModelServer:
         server stays "healthy" — it is serving, just shedding one
         endpoint — so orchestrators restart on ``healthy: false`` only.
 
-        ``probe_device=True`` additionally checks device liveness through
-        the watchdogged out-of-process probe
-        (:func:`sparkdl_tpu.resilience.watchdog.check_device`) — a wedged
-        PJRT tunnel reports as unhealthy with a typed ``error_class``
-        instead of hanging the health endpoint (the failure mode that
-        motivated the probe helper)."""
+        ``probe_device=True`` additionally checks device liveness with a
+        watchdogged tiny dispatch on the device this process holds
+        (:func:`sparkdl_tpu.resilience.watchdog.check_device`; in-process,
+        because the server owns the chip and a child could not open it)
+        — a device call that does not return reports as unhealthy with a
+        typed ``error_class`` instead of hanging the health endpoint."""
         degraded = sorted(
             mid for mid, ep in self._endpoints.items() if ep.degraded
         )

@@ -9,8 +9,13 @@ loop the ISSUE's kill matrix exercises:
 - **spawn** — export the :class:`~sparkdl_tpu.serving.replica
   .ReplicaSpec` through ``SPARKDL_REPLICA_SPEC``, wait for the ready
   line, register the replica with the :class:`~sparkdl_tpu.serving
-  .router.Router`.  The child inherits ``SPARKDL_COMPILE_CACHE``, so
-  restarts warm up from disk instead of recompiling.
+  .router.Router`.  The child resolves the same compile-cache root as
+  its parent, so restarts warm up from disk instead of recompiling.
+- **one chip per replica** — a chip belongs to one process at a time, so
+  each slot's process is restricted to its own chip before it starts
+  (:func:`_one_chip_env`), a restart first waits for the dead process to
+  be gone (its exit is what lets go of the chip), and this process never
+  initialises a JAX backend itself.
 - **watch** — a monitor thread (interval ticks on an ``Event``, never a
   sleep-retry loop) notices process death via ``poll()`` and gray
   failure via the replica's own ``/healthz`` (``health_failures``
@@ -62,6 +67,25 @@ logger = logging.getLogger(__name__)
 #: :mod:`sparkdl_tpu.serving.autoscale`)
 ENV_REPLICAS = "SPARKDL_REPLICAS"
 
+#: how long a killed replica may take to be gone.  Measured on a v5e host
+#: (PR 21): a SIGKILLed process that held a chip took 5.8 s to be reaped,
+#: the driver's teardown of the device included — the old 10 s left no
+#: room for a process with real state mapped.
+_REAP_TIMEOUT_S = 60.0
+
+
+def _one_chip_env(chip: int) -> Dict[str, str]:
+    """What restricts a process to one chip on libtpu: the one chip it may
+    open, and process bounds of a single chip — so the runtime neither
+    looks for the host's other chips nor counts this process as holding
+    the whole host (holding less than the host is what lets one process
+    per chip load the library side by side).  Inert without a TPU."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
 
 class ReplicaHandle:
     """One supervised slot: the current process (if any) plus the
@@ -74,8 +98,12 @@ class ReplicaHandle:
     def __init__(
         self, slot: int, spec: ReplicaSpec,
         version: str = DEFAULT_VERSION,
+        chip: int = 0,
     ):
         self.slot = int(slot)
+        #: the one chip this slot's processes may open (kept across
+        #: restarts; free again once the slot is stopped or evicted)
+        self.chip = int(chip)
         self.name = f"replica-{slot}"
         self.spec = spec
         self.version = str(version)
@@ -103,6 +131,7 @@ class ReplicaHandle:
     def describe(self) -> Dict[str, Any]:
         return {
             "slot": self.slot,
+            "chip": self.chip,
             "name": self.name,
             "version": self.version,
             "state": self.state,
@@ -133,7 +162,7 @@ class ReplicaSupervisor:
         monitor_interval_s: float = 0.25,
         health_interval_s: float = 2.0,
         health_failures: int = 3,
-        spawn_timeout_s: float = 120.0,
+        spawn_timeout_s: float = 600.0,
         stop_timeout_s: Optional[float] = None,
         fault_plans: Optional[Dict[int, List[dict]]] = None,
     ):
@@ -238,7 +267,15 @@ class ReplicaSupervisor:
             spec = self._specs[version]
             slot = self._next_slot
             self._next_slot += 1
-            handle = ReplicaHandle(slot, spec, version=version)
+            # slot i sits on chip i until slots retire; then the lowest
+            # chip no running slot holds (a rollout's second fleet, a
+            # scale-up after a scale-down)
+            held = {
+                h.chip for h in self._handles.values()
+                if h.state not in ("stopped", "evicted")
+            }
+            chip = next(c for c in range(len(held) + 1) if c not in held)
+            handle = ReplicaHandle(slot, spec, version=version, chip=chip)
             self._handles[slot] = handle
             self._breakers[slot] = CircuitBreaker(
                 name=f"supervisor.slot{slot}",
@@ -259,7 +296,10 @@ class ReplicaSupervisor:
                            handle.name, exc)
             self._after_death(handle, exit_code=None)
             return False
+        # the chip is let go only when the process that held it is gone
+        self._reap(handle.proc)
         env = os.environ.copy()
+        env.update(_one_chip_env(handle.chip))
         env[ENV_SPEC] = handle.spec.to_json()
         rules = self._fault_plans.get(handle.slot)
         if rules and not handle.fault_armed:
@@ -283,8 +323,7 @@ class ReplicaSupervisor:
                 "%s produced no ready line within %.0fs (pid %d)",
                 handle.name, self._spawn_timeout_s, proc.pid,
             )
-            proc.kill()
-            proc.wait(timeout=10.0)
+            self._reap(proc)
             handle.last_exit = proc.returncode
             self._after_death(handle, exit_code=proc.returncode)
             return False
@@ -322,6 +361,17 @@ class ReplicaSupervisor:
             time.monotonic() - started,
         )
         return True
+
+    @staticmethod
+    def _reap(proc: Optional[subprocess.Popen]) -> None:
+        """Make sure ``proc`` is gone — killed if it still runs — and
+        reaped: no zombie replicas, and no successor racing a process
+        that still holds the chip."""
+        if proc is None:
+            return
+        if proc.poll() is None:
+            proc.kill()
+        proc.wait(timeout=_REAP_TIMEOUT_S)
 
     @staticmethod
     def _read_ready(
@@ -416,9 +466,7 @@ class ReplicaSupervisor:
                 handle.proc.pid if handle.proc else "?",
             )
             proc = handle.proc
-            if proc is not None and proc.poll() is None:
-                proc.kill()
-                proc.wait(timeout=10.0)
+            self._reap(proc)
             self._on_death(
                 handle,
                 proc.returncode if proc is not None else None,
@@ -429,8 +477,8 @@ class ReplicaSupervisor:
         vs. eviction."""
         self.router.remove(handle.name)
         proc = handle.proc
+        self._reap(proc)
         if proc is not None:
-            proc.wait(timeout=10.0)  # reap — no zombie replicas
             handle.last_exit = proc.returncode
         drain = handle._drain_thread
         if drain is not None and drain.is_alive():
@@ -609,23 +657,16 @@ class ReplicaSupervisor:
     def _stop_handle(self, handle: ReplicaHandle, graceful: bool) -> None:
         self.router.remove(handle.name)
         proc = handle.proc
-        if proc is not None and proc.poll() is None:
-            if graceful:
-                proc.send_signal(signal.SIGTERM)
-                try:
-                    proc.wait(timeout=self._stop_timeout_s)
-                except subprocess.TimeoutExpired:
-                    logger.warning(
-                        "%s ignored SIGTERM for %.0fs; killing",
-                        handle.name, self._stop_timeout_s,
-                    )
-                    proc.kill()
-                    proc.wait(timeout=10.0)
-            else:
-                proc.kill()
-                proc.wait(timeout=10.0)
-        elif proc is not None:
-            proc.wait(timeout=10.0)
+        if graceful and proc is not None and proc.poll() is None:
+            proc.send_signal(signal.SIGTERM)
+            try:
+                proc.wait(timeout=self._stop_timeout_s)
+            except subprocess.TimeoutExpired:
+                logger.warning(
+                    "%s ignored SIGTERM for %.0fs; killing",
+                    handle.name, self._stop_timeout_s,
+                )
+        self._reap(proc)
         drain = handle._drain_thread
         if drain is not None and drain.is_alive():
             drain.join(timeout=2.0)

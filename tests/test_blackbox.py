@@ -182,16 +182,16 @@ class TestResilienceCrossings:
 
         rec, out_dir = armed
         breaker = CircuitBreaker(
-            name="tunnel", failure_threshold=2, recovery_s=60.0,
+            name="device", failure_threshold=2, recovery_s=60.0,
         )
         breaker.record_failure()
         breaker.record_failure()  # trips open -> event dump
         dumps = [f for f in os.listdir(out_dir)
-                 if "breaker_open_tunnel" in f]
+                 if "breaker_open_device" in f]
         assert len(dumps) == 1
         payload = _read_json(os.path.join(out_dir, dumps[0]))
         names = [e["name"] for e in payload["events"]]
-        assert "breaker_open_tunnel" in names
+        assert "breaker_open_device" in names
 
     def test_preempted_dumps(self, armed):
         from sparkdl_tpu.resilience.preempt import PreemptionToken

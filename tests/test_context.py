@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkdl_tpu.parallel._shard_map import shard_map
+from jax import shard_map
 from sparkdl_tpu.parallel.context import (
     full_attention,
     make_sp_attention,

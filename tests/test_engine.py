@@ -121,6 +121,40 @@ class TestCacheKey:
         monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "off")
         assert default_cache_dir() is None
 
+    def test_cache_root_is_placed_from_outside_or_fixed_in_checkout(
+        self, monkeypatch
+    ):
+        """One root for every compile cache: ``JAX_COMPILATION_CACHE_DIR``
+        where it is set — and then the code sets no directory anywhere —
+        else a fixed, git-ignored directory inside the checkout, handed to
+        JAX's own cache.  The executable store sits under the same root;
+        nothing lands under ``~`` or a temporary name."""
+        import jax
+
+        from sparkdl_tpu.engine import compile_cache_root, enable_jax_cache
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.delenv("SPARKDL_COMPILE_CACHE", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+            assert compile_cache_root() == "/placed/outside"
+            assert default_cache_dir() == "/placed/outside/executables"
+            jax.config.update("jax_compilation_cache_dir", None)
+            enable_jax_cache()  # JAX reads the variable itself
+            assert jax.config.jax_compilation_cache_dir is None
+
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            fixed = os.path.join(repo, ".compile_cache")
+            assert compile_cache_root() == fixed == compile_cache_root()
+            assert default_cache_dir() == os.path.join(fixed, "executables")
+            enable_jax_cache()
+            assert jax.config.jax_compilation_cache_dir == fixed
+            with open(os.path.join(repo, ".gitignore")) as fh:
+                assert ".compile_cache/" in fh.read().split()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
 
 # ----------------------------------------------------------------------
 # in-memory LRU

@@ -349,26 +349,19 @@ def test_timer_add_seconds_accumulates():
     assert t.seconds == 1.0 and t.entries == 2
 
 
-def test_cpu_scale_shrinks_featurizer_workload(monkeypatch):
-    """benchlib CPU-fallback scaling (the r05-r09 bench wedge fix):
-    explicit > env > auto-detect precedence, and the scaled workload
-    keeps scan >= 2 so the anti-caching methodology survives."""
+def test_benchmarks_refuse_to_measure_without_an_accelerator(capsys):
+    """A number under a device unit comes from a device: on a host where
+    JAX finds only CPUs the shared benchmark guard prints the refusal
+    record (naming the device it did find) and tells the script to exit —
+    there is no smaller CPU workload to fall back to."""
+    import json
+
     from sparkdl_tpu.utils import benchlib
 
-    # identity below/at 1
-    assert benchlib.scale_featurizer_workload(512, 24, 3, 1) == (512, 24, 3)
-    # the headline shape at the default CPU scale: small but still a
-    # real scan over distinct batches
-    b, s, r = benchlib.scale_featurizer_workload(512, 24, 3, 32)
-    assert b == 16 and s >= 2 and r == 2
-    # never degenerates to zero
-    b, s, r = benchlib.scale_featurizer_workload(1, 2, 1, 1000)
-    assert b >= 1 and s >= 2 and r >= 1
-
-    # precedence: explicit beats env beats auto
-    monkeypatch.setenv(benchlib.CPU_SCALE_ENV, "7")
-    assert benchlib.resolve_cpu_scale(3) == 3
-    assert benchlib.resolve_cpu_scale() == 7
-    monkeypatch.delenv(benchlib.CPU_SCALE_ENV)
-    # this environment is CPU-only, so auto-detect engages the default
-    assert benchlib.resolve_cpu_scale() == benchlib.DEFAULT_CPU_SCALE
+    assert benchlib.accelerator_or_refuse("m", mfu=None) is None
+    record = json.loads(capsys.readouterr().out.strip())
+    assert record["ok"] is False and record["value"] is None
+    assert record["mfu"] is None
+    assert record["error_class"] == "NoAccelerator"
+    assert record["device"]["platform"] == "cpu"
+    assert not hasattr(benchlib, "scale_featurizer_workload")

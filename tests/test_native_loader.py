@@ -66,3 +66,26 @@ def test_load_builds_once_outside_the_lock(mod, monkeypatch, tmp_path):
     # the verdict is memoized: no second build attempt afterwards
     assert mod._load() is None
     assert len(calls) == 1
+
+
+@pytest.mark.parametrize(
+    "cxx, raises",
+    [("false", True), ("/nonexistent/c++", False)],
+    ids=["compiler-fails", "no-compiler"],
+)
+def test_failed_build_is_an_error_only_where_a_compiler_exists(
+    cxx, raises, monkeypatch, tmp_path
+):
+    """No C++ compiler: the Python path is the path (``_build`` -> False).
+    A compiler that fails: an error, not a quiet switch to the slower
+    path."""
+    src = tmp_path / "src.cpp"
+    src.write_text("int main() { return 0; }")
+    monkeypatch.setattr(native, "_SRC_PATH", str(src))
+    monkeypatch.setattr(native, "_SO_PATH", str(tmp_path / "out.so"))
+    monkeypatch.setenv("CXX", cxx)
+    if raises:
+        with pytest.raises(native.NativeBuildError, match="build failed"):
+            native._build()
+    else:
+        assert native._build() is False

@@ -104,7 +104,7 @@ class TestClassify:
         assert is_transient(XlaRuntimeError("RESOURCE_EXHAUSTED: hbm oom"))
         assert is_transient(XlaRuntimeError("UNAVAILABLE: socket closed"))
         assert not is_transient(XlaRuntimeError("INVALID_ARGUMENT: shape"))
-        # no status word at all = the wedged/torn-tunnel shape
+        # no status word at all = the torn-connection shape
         assert is_transient(XlaRuntimeError("connection reset mid-stream"))
         # same message on an unknown type stays permanent (fail-fast)
         assert not is_transient(RuntimeError("UNAVAILABLE: socket closed"))
@@ -349,7 +349,7 @@ class TestWatchdog:
     def test_fast_call_passes_through(self):
         assert watchdogged(lambda: 42, hard_timeout_s=30.0) == 42
 
-    def test_worker_exception_is_relayed(self):
+    def test_worker_exception_reaches_the_caller(self):
         def boom():
             raise InjectedPermanentError("from worker")
 
@@ -369,7 +369,6 @@ class TestWatchdog:
                     soft_timeout_s=0.05,
                     hard_timeout_s=0.6,
                     name="stall_test",
-                    diagnostic_code="print('diagnostic-alive')",
                 )
         elapsed = time.monotonic() - start
         assert elapsed < 10.0, f"watchdog took {elapsed:.1f}s to give up"
@@ -381,15 +380,44 @@ class TestWatchdog:
         ).value == 1
 
     def test_check_device_structured_record(self):
-        rec = check_device(timeout_s=60, probe_code="print('cpu-ok')")
-        assert rec == {"ok": True, "error_class": None, "detail": "cpu-ok"}
+        # JAX_PLATFORMS=cpu (conftest): the held device answers "cpu"
+        rec = check_device(timeout_s=60)
+        assert rec == {"ok": True, "error_class": None, "detail": "cpu"}
 
     def test_check_device_failure_has_error_class(self):
-        rec = check_device(
-            timeout_s=60, probe_code="import sys; sys.exit(3)"
-        )
+        """A dispatch that does not return is a typed record, in bounded
+        time — not a raise and not a hang."""
+        plan = FaultPlan().add("watchdog.device_probe", stall_s=15.0, at=1)
+        start = time.monotonic()
+        with active_plan(plan):
+            rec = check_device(timeout_s=0.5)
+        assert time.monotonic() - start < 10.0
         assert rec["ok"] is False
         assert rec["error_class"] == "DeviceUnresponsive"
+
+    def test_check_device_starts_no_process(self, monkeypatch):
+        """The caller holds the chip; a child that asked for it would
+        fail where the parent is healthy.  ``status(probe_device=True)``
+        therefore must never start one."""
+        from sparkdl_tpu.serving import ModelServer
+
+        def refuse(*args, **kwargs):
+            raise AssertionError(f"child process started: {args!r}")
+
+        for mod, name in (
+            (subprocess, "Popen"), (os, "fork"), (os, "posix_spawn"),
+            (os, "system"),
+        ):
+            monkeypatch.setattr(mod, name, refuse)
+        with ModelServer() as server:
+            server.register(
+                "m", lambda x: x, item_shape=(2,), compile=False
+            )
+            status = server.status(probe_device=True)
+        assert status["device"] == {
+            "ok": True, "error_class": None, "detail": "cpu",
+        }
+        assert status["healthy"] is True
 
 
 # ---------------------------------------------------------------------------

@@ -182,8 +182,9 @@ class TestMetricsExport:
 
     @pytest.mark.slow
     def test_status_probe_device(self):
-        """probe_device=True runs the bounded out-of-process liveness
-        probe (utils/probes.py) — healthy on a working backend."""
+        """probe_device=True bounds a tiny dispatch on the held device
+        (resilience.watchdog.check_device) — healthy on a working
+        backend."""
         with make_server() as server:
             st = server.status(probe_device=True, probe_timeout_s=120)
         assert st["device"]["ok"], st["device"]

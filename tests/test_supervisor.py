@@ -571,6 +571,42 @@ def test_replica_warm_kill_restarts():
         assert handle.state == "live"
 
 
+def test_each_replica_is_restricted_to_its_own_chip():
+    """A chip belongs to one process at a time: the supervisor hands the
+    replica in slot i chip i — and no other — in the child's environment
+    before the spawn, keeps that chip across a restart (which first waits
+    for the dead process to be gone), and gives a retired slot's chip to
+    the next slot that needs one."""
+
+    def child_env(handle):
+        with open(f"/proc/{handle.proc.pid}/environ", "rb") as fh:
+            return dict(
+                kv.split("=", 1)
+                for kv in fh.read().decode().split("\0") if "=" in kv
+            )
+
+    with fast_supervisor(replicas=2) as sup:
+        first, second = sup.handles()
+        assert (first.chip, second.chip) == (0, 1)
+        for handle in (first, second):
+            env = child_env(handle)
+            assert env["TPU_VISIBLE_CHIPS"] == str(handle.chip)
+            assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        # a crash: the slot restarts on the SAME chip, after the reaping
+        dead = first.proc
+        sup.kill_replica(first.slot)
+        assert wait_until(lambda: first.generation == 2 and
+                          first.state == "live")
+        assert dead.returncode is not None  # reaped before the respawn
+        assert child_env(first)["TPU_VISIBLE_CHIPS"] == "0"
+        # scale down, then up: the freed chip is handed out again
+        sup.stop_replica(first.slot)
+        sup.scale_to(2)
+        newest = sup.handles()[-1]
+        assert newest.slot == 2 and newest.chip == 0
+
+
 def test_spawn_and_restart_fault_sites():
     """Injected faults at ``supervisor.spawn`` and then at
     ``supervisor.restart`` each count as a failed run; the loop keeps

@@ -1,0 +1,225 @@
+"""Kernels and main-path programs compile for the chip — without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+*described*, not attached (``/opt/skills/guides/on-chip-measurement`` §2).
+Interpret-mode kernel tests and the eight virtual CPU devices cannot see
+what it refuses: a slice off the tiling, too much fast memory, a program
+that does not fit, a collective too many.  These compiles are that check,
+kept as tests so every later PR is guarded at no chip time.  A compile that
+passes is not a chip run.
+
+All of it lives in THIS file on purpose: the topology is described inside a
+module-scoped fixture (never at import, never ``autouse``, never in
+``conftest.py``), only the xdist worker that is handed this file loads the
+TPU library, and every compile runs in the test's own process.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to JAX's persistent cache
+    but cannot be read back without the chip: the next one would warn and
+    compile again.  Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _fits(compiled) -> None:
+    m = compiled.memory_analysis()
+    total = (
+        m.temp_size_in_bytes + m.argument_size_in_bytes
+        + m.output_size_in_bytes
+    )
+    assert total < V5E_HBM_BYTES, f"program needs {total} bytes of HBM"
+
+
+# (b, s, h, d): ViT-B/16 at 224² and ViT-L/16 at 384² (CLS token: a
+# sequence no block divides), and the long-sequence shape the kernel was
+# timed at
+FLASH_SHAPES = [(8, 197, 12, 64), (8, 577, 16, 64), (4, 4096, 8, 128)]
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_flash_attention_kernel_compiles(
+    shape, direction, one_chip, no_compile_cache
+):
+    """The Pallas kernel itself — ``interpret=False``, steered here because
+    ``jax.default_backend()`` is the CPU — lowers through Mosaic for the
+    chip, and the lowering contains the kernel, not the dense substitute."""
+    from sparkdl_tpu.ops import flash_attention
+
+    def forward(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fn = forward if direction == "forward" else backward
+    compiled = jax.jit(fn).lower(spec, spec, spec).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_inception_featurizer_program_compiles(one_chip, no_compile_cache):
+    """The fused featurizer program as ``DeepImageFeaturizer`` really
+    builds it — uint8 in, cast + resize + flip-folded stem + preprocess +
+    InceptionV3 in bf16, batch buffer donated — at batch 64."""
+    from sparkdl_tpu import DeepImageFeaturizer
+
+    stage = DeepImageFeaturizer(
+        inputCol="image", outputCol="features", modelName="InceptionV3",
+        modelWeights="random",
+    )
+    forward, entry = stage._build_forward()
+    h, w = entry.input_size
+    batch = jax.ShapeDtypeStruct((64, h, w, 3), np.uint8, sharding=one_chip)
+    compiled = (
+        jax.jit(forward._fn, donate_argnums=(0,)).lower(batch).compile()
+    )
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (64, 2048) and out.dtype == jnp.float32
+    _fits(compiled)
+
+
+def test_served_masked_block_program_compiles(one_chip, no_compile_cache):
+    """The ONE executable of a ragged endpoint as the batcher builds it: the
+    masked ``(n_slots, *item)`` block with the input prologue (uint8 ingest,
+    resize from source size, Keras-parity normalise) fused in front of the
+    model."""
+    from sparkdl_tpu.models import get_keras_application_model
+    from sparkdl_tpu.serving.batcher import MicroBatcher, ServingConfig
+    from sparkdl_tpu.serving.cache import ProgramCache
+    from sparkdl_tpu.transformers.utils import make_input_prologue
+    from sparkdl_tpu.utils.benchlib import fill_variables
+
+    entry = get_keras_application_model("MobileNetV2")
+    h, w = entry.input_size
+    module = entry.make_module(dtype=jnp.bfloat16)
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        variables = fill_variables(
+            module, jnp.zeros((1, h, w, 3), jnp.float32)
+        )
+
+    def forward(x):
+        out = module.apply(variables, x.astype(jnp.bfloat16))
+        return out.astype(jnp.float32)
+
+    n_slots, source = 32, (256, 320)
+    endpoint = MicroBatcher(
+        "mnv2", forward, ServingConfig(max_batch=n_slots), ProgramCache(),
+        item_shape=(*source, 3), dtype=np.uint8, fingerprint="test:mnv2",
+        prologue=make_input_prologue((h, w), entry.preprocess),
+    )
+    try:
+        fused = endpoint._masked_fused()
+    finally:
+        endpoint.close()
+    block = jax.ShapeDtypeStruct(
+        (n_slots, *source, 3), np.uint8, sharding=one_chip
+    )
+    mask = jax.ShapeDtypeStruct((n_slots,), np.bool_, sharding=one_chip)
+    compiled = jax.jit(fused, donate_argnums=(0, 1)).lower(block, mask).compile()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape[0] == n_slots and out.dtype == jnp.float32
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["pmean", "psum"])
+def test_dp_train_step_allreduces_gradients_once(
+    weighted, topo, no_compile_cache
+):
+    """The data-parallel Keras train step on a four-chip mesh: the compiled
+    program all-reduces every gradient exactly once — not twice (an
+    explicit reduce on top of the transpose's own), not never — in both
+    the plain-mean and the weighted branch."""
+    import keras
+
+    from sparkdl_tpu.estimators.losses import (
+        get_optimizer,
+        get_per_sample_loss_fn,
+        mean_squared_error,
+    )
+    from sparkdl_tpu.parallel.keras_train import (
+        init_keras_train_state,
+        make_keras_train_step,
+    )
+
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        model = keras.Sequential([
+            keras.layers.Input((16, 16, 3)),
+            keras.layers.Conv2D(5, 3),  # the only f32[3,3,3,5] in the step
+            keras.layers.BatchNormalization(),
+            keras.layers.ReLU(),
+            keras.layers.GlobalAveragePooling2D(),
+            keras.layers.Dense(7, activation="softmax"),
+        ])
+    tx = get_optimizer("sgd", 0.1)
+    # a callable has no per-sample form: the estimator then takes the
+    # unweighted pmean branch
+    loss = (
+        get_per_sample_loss_fn("mean_squared_error") if weighted
+        else mean_squared_error
+    )
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    step = make_keras_train_step(model, loss, tx, mesh, weighted=weighted)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+    state = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=replicated
+        ),
+        jax.eval_shape(lambda: init_keras_train_state(model, tx)),
+    )
+    batch = {
+        "x": jax.ShapeDtypeStruct((32, 16, 16, 3), np.float32, sharding=sharded),
+        "y": jax.ShapeDtypeStruct((32, 7), np.float32, sharding=sharded),
+    }
+    if weighted:
+        batch["w"] = jax.ShapeDtypeStruct((32,), np.float32, sharding=sharded)
+    text = step.lower(state, batch).compile().as_text()
+    reduced = re.findall(r"= ([^\n]*?) all-reduce(?:-start)?\(", text)
+    assert reduced, "no all-reduce at all in the data-parallel step"
+    kernel_reduces = sum(shape.count("f32[3,3,3,5]") for shape in reduced)
+    assert kernel_reduces == 1, (
+        f"the conv kernel's gradient is all-reduced {kernel_reduces} times:"
+        f" {reduced}"
+    )
