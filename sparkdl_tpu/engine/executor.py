@@ -19,15 +19,24 @@ with one engine-owned discipline:
 
 The window is deliberately not a thread: jax's own runtime provides the
 asynchrony; this class only decides *when* to synchronize.
+
+It is also where the host can tell that the device has nothing to do:
+every window counts into one process-wide number of results dispatched
+and not yet fetched (:class:`_Outstanding`), and the time that number
+spends at zero is recorded as ``engine.starved`` boundary spans
+(:mod:`sparkdl_tpu.obs.trace`).
 """
 
 from __future__ import annotations
 
 import os
+import threading
 from collections import deque
 from typing import Any, Iterator, List, Optional, Tuple
 
 import numpy as np
+
+from sparkdl_tpu.obs.trace import tracer
 
 _DEPTH_ENV = "SPARKDL_DISPATCH_DEPTH"
 DEFAULT_DEPTH = 2
@@ -85,6 +94,55 @@ def _fetch_host(result: Any) -> Any:
         return arr
 
     return jax.tree_util.tree_map(leaf_to_host, result)
+
+
+class _Outstanding:
+    """Results dispatched and not yet fetched, over every
+    :class:`DispatchWindow` of the process.
+
+    While the count is zero the device provably has no work from this
+    process.  The clock is stamped when the count falls to zero, and the
+    next ``submitted`` that finds it zero records the boundary span
+    ``engine.starved`` from the stamp to now (none before the first
+    submit of the process).  That is a lower bound on the device's idle
+    time — exact where the host blocks on the last result of a
+    partition — and, being a span in the tracer's ring, it can be laid
+    over what the dispatching thread ran meanwhile.  A window dropped
+    with results in flight and never ``abandon``-ed holds the count up,
+    which only lowers the bound."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._count = 0
+        self._empty_since_ns: Optional[int] = None
+
+    def submitted(self) -> None:
+        with self._lock:
+            since = self._empty_since_ns if self._count == 0 else None
+            self._count += 1
+        if since is not None:
+            with tracer.boundary("engine.starved", start_ns=since):
+                pass
+
+    def fetched(self, n: int = 1) -> None:
+        if n <= 0:
+            return
+        with self._lock:
+            self._count -= n
+            if self._count == 0:
+                self._empty_since_ns = tracer.clock_ns()
+
+
+_outstanding = _Outstanding()
+
+
+def _host_nbytes(host: Any) -> int:
+    import jax
+
+    return sum(
+        int(getattr(leaf, "nbytes", 0))
+        for leaf in jax.tree_util.tree_leaves(host)
+    )
 
 
 class FetchFailure:
@@ -154,16 +212,22 @@ class DispatchWindow:
     def _pop(self) -> Tuple[Any, Any]:
         result, meta = self._inflight.popleft()
         self._gauge.set(len(self._inflight))
-        if self.capture_errors:
-            try:
-                return _fetch_host(result), meta
-            except Exception as exc:  # delivered, not raised
-                return FetchFailure(exc), meta
-        return _fetch_host(result), meta
+        try:
+            with tracer.boundary("engine.fetch_wait") as span:
+                host = _fetch_host(result)
+                span.set_attribute("bytes", _host_nbytes(host))
+            return host, meta
+        except Exception as exc:
+            if not self.capture_errors:
+                raise
+            return FetchFailure(exc), meta  # delivered, not raised
+        finally:
+            _outstanding.fetched()
 
     def submit(self, result: Any, meta: Any = None) -> List[Tuple[Any, Any]]:
         """Enqueue a dispatched result; returns the (host_result, meta)
         pairs that just fell out of the window (possibly empty)."""
+        _outstanding.submitted()
         _start_host_copy(result)
         self._inflight.append((result, meta))
         self._gauge.set(len(self._inflight))
@@ -180,5 +244,6 @@ class DispatchWindow:
     def abandon(self) -> None:
         """Drop in-flight results without fetching (error-path cleanup;
         the device arrays are released to GC)."""
+        _outstanding.fetched(len(self._inflight))
         self._inflight.clear()
         self._gauge.set(0)

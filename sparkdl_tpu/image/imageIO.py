@@ -236,13 +236,17 @@ def filesToDF(session, path: str, numPartitions: int = 4):
 
     Reference analog: ``imageIO.filesToDF`` over ``sc.binaryFiles``†.
     """
+    from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.sql.session import TPUSession
 
     session = session or TPUSession.getActiveSession()
     rows = []
-    for f in _list_files(path):
-        with open(f, "rb") as fh:
-            rows.append((f, fh.read()))
+    with tracer.boundary("image.read_files") as span:
+        for f in _list_files(path):
+            with open(f, "rb") as fh:
+                rows.append((f, fh.read()))
+        span.set_attribute("files", len(rows))
+        span.set_attribute("bytes", sum(len(raw) for _, raw in rows))
     return session.createDataFrame(
         rows, ["filePath", "fileData"], numPartitions=numPartitions
     )
@@ -294,25 +298,29 @@ def readImagesWithCustomFn(
     files_df = filesToDF(session, path, numPartitions=numPartitions)
 
     def decode_partition(part):
+        from sparkdl_tpu.obs.trace import tracer
         from sparkdl_tpu.utils.metrics import metrics
 
         decode_errors = metrics.counter("data.decode_errors")
         images, origins = [], []
-        for fp, raw in zip(part["filePath"], part["fileData"]):
-            try:
-                struct = decode_f(raw, fp)
-            except Exception as exc:
-                if on_error == "raise":
-                    raise ImageDecodeError(fp, exc) from exc
-                struct = None
-            if struct is None:
-                if on_error == "raise":
-                    raise ImageDecodeError(fp)
-                decode_errors.add(1)
-                logger.warning("dropping undecodable image %s", fp)
-                continue
-            images.append(struct)
-            origins.append(fp)
+        with tracer.boundary("image.decode") as span:
+            for fp, raw in zip(part["filePath"], part["fileData"]):
+                try:
+                    struct = decode_f(raw, fp)
+                except Exception as exc:
+                    if on_error == "raise":
+                        raise ImageDecodeError(fp, exc) from exc
+                    struct = None
+                if struct is None:
+                    if on_error == "raise":
+                        raise ImageDecodeError(fp)
+                    decode_errors.add(1)
+                    logger.warning("dropping undecodable image %s", fp)
+                    continue
+                images.append(struct)
+                origins.append(fp)
+            span.set_attribute("rows", len(images))
+            span.set_attribute("errors", len(part["filePath"]) - len(images))
         return {"filePath": origins, "image": images}
 
     schema = StructType(

@@ -32,19 +32,34 @@ Design rules:
   the rest by a deterministic per-*trace* hash, so a kept trace is kept
   whole (no orphaned children).  Dropped spans count into
   ``sparkdl.spans_sampled_out``; context propagation is unaffected
-  (sampling gates delivery to sinks, not span creation).
+  (sampling gates delivery to sinks, not span creation);
+- **layer boundaries are always recorded**: :meth:`Tracer.boundary`
+  spans sit only where work crosses a layer (a handful per batch, not
+  per item or request), so they are made whether or not the tracer is
+  enabled, kept in one bounded in-memory ring (:meth:`Tracer.recent`)
+  and, while open, mirrored into any running ``jax.profiler`` session
+  as ``sparkdl.<name>`` annotations — the program's spans then share
+  the profiler's clock with the device plane.
 """
 
 from __future__ import annotations
 
 import os
 import random
+import sys
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 ENV_SEED = "SPARKDL_TRACE_SEED"
+
+#: boundary spans the ring holds: a cached featurize pass makes about 25
+#: a second, so ten 30 s windows of it
+BOUNDARY_RING_SIZE = 8192
+#: what a boundary span is called in a ``jax.profiler`` trace
+ANNOTATION_PREFIX = "sparkdl."
 
 #: a remote span reference carried over the wire: ``(trace_id, span_id)``
 RemoteParent = Tuple[int, int]
@@ -91,6 +106,32 @@ class _IdSource:
 _ids = _IdSource()
 
 
+class BoundaryRecord(NamedTuple):
+    """One finished boundary span as the ring keeps it (times on the
+    ``time.perf_counter_ns`` clock)."""
+
+    name: str
+    span_id: int
+    parent_id: Optional[int]
+    thread_id: int
+    start_ns: int
+    end_ns: int
+    attributes: Dict[str, Any]
+
+
+def _profiler_annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` for a boundary span, or
+    None where jax is not loaded (this module never imports it: a
+    process that has not touched jax has no profiler session to feed)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One timed region of work.
 
@@ -103,12 +144,16 @@ class Span:
     __slots__ = (
         "trace_id", "span_id", "parent_id", "name", "attributes",
         "events", "start_wall", "_start", "_end", "_tracer", "_lock",
+        "boundary", "thread_id", "start_ns", "end_ns",
     )
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: Optional["Span"], attributes: Dict[str, Any],
-                 remote: Optional[RemoteParent] = None):
+                 remote: Optional[RemoteParent] = None,
+                 boundary: bool = False, start_ns: Optional[int] = None):
         self._tracer = tracer
+        self.boundary = boundary
+        self.thread_id = threading.get_ident()
         self.name = name
         self.span_id = _ids.next_id()
         if parent is not None:
@@ -125,7 +170,16 @@ class Span:
         self.attributes = dict(attributes)
         self.events: List[Dict[str, Any]] = []
         self.start_wall = time.time()
-        self._start = time.perf_counter()
+        now_ns = tracer.clock_ns()
+        if start_ns is None:
+            start_ns = now_ns
+        else:
+            # a boundary span backdated to a stamp its caller took from
+            # the same clock: the wall anchor moves back with it
+            self.start_wall -= (now_ns - start_ns) / 1e9
+        self.start_ns = start_ns
+        self.end_ns: Optional[int] = None
+        self._start = start_ns / 1e9
         self._end: Optional[float] = None
         self._lock = threading.Lock()
 
@@ -166,7 +220,8 @@ class Span:
         with self._lock:
             if self._end is not None:
                 return
-            self._end = time.perf_counter()
+            self.end_ns = self._tracer.clock_ns()
+            self._end = self.end_ns / 1e9
         self._tracer._deliver(self)
 
     # ------------------------------------------------------------------
@@ -202,6 +257,8 @@ class Tracer:
     :meth:`current` returns None until :meth:`enable` installs at least
     the enabled flag (sinks are optional — spans without a sink still
     propagate context, e.g. for tests reading ``current()``).
+    :meth:`boundary` spans alone are made either way, and kept in the
+    ring :meth:`recent` reads.
     """
 
     def __init__(self):
@@ -220,6 +277,10 @@ class Tracer:
         # slow_ms None = no slow-span exemption configured
         self._sample_rate = 1.0
         self._sample_slow_ms: Optional[float] = None
+        #: the spans' clock; a test puts a scripted one here
+        self.clock_ns: Callable[[], int] = time.perf_counter_ns
+        self._ring: "deque[BoundaryRecord]" = deque(maxlen=BOUNDARY_RING_SIZE)
+        self._ring_lock = threading.Lock()
 
     # -- lifecycle -----------------------------------------------------
     def enable(self, sink: Optional[Callable[[Dict[str, Any]], None]] = None
@@ -290,6 +351,15 @@ class Tracer:
         return coin >= rate
 
     def _deliver(self, span: Span) -> None:
+        if span.boundary:
+            record = BoundaryRecord(
+                span.name, span.span_id, span.parent_id, span.thread_id,
+                span.start_ns, span.end_ns, span.attributes,
+            )
+            with self._ring_lock:
+                self._ring.append(record)
+            if not self.enabled:
+                return
         if self._sampled_out(span):
             from sparkdl_tpu.utils.metrics import metrics
 
@@ -375,6 +445,44 @@ class Tracer:
         finally:
             self._current.reset(token)
             sp.end()
+
+
+    @contextmanager
+    def boundary(self, name: str, parent: Optional[Span] = None,
+                 start_ns: Optional[int] = None, **attributes: Any):
+        """Open a span where work crosses a LAYER boundary (a partition,
+        a pack, a dispatch, a fetch: a handful per batch).  An ordinary
+        :class:`Span` — current for the block, child of ``parent`` or of
+        the current span, delivered to the sinks while the tracer is
+        enabled — that is made also while it is disabled: every finished
+        one goes into the ring :meth:`recent` returns.  While open it is
+        a ``jax.profiler.TraceAnnotation("sparkdl.<name>")`` too, so a
+        profiler session holds it on the device plane's clock.
+
+        Across threads pass ``parent`` explicitly: :meth:`capture`
+        returns None while tracing is disabled.  ``start_ns`` backdates
+        the span to a ``clock_ns`` stamp taken when an interval began
+        that only its end reveals (``engine.starved``); such a span
+        cannot be annotated."""
+        if parent is None:
+            parent = self._current.get()
+        sp = Span(self, name, parent, attributes, boundary=True,
+                  start_ns=start_ns)
+        annotation = _profiler_annotation(name) if start_ns is None else None
+        token = self._current.set(sp)
+        try:
+            yield sp
+        finally:
+            self._current.reset(token)
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            sp.end()
+
+    def recent(self) -> List[BoundaryRecord]:
+        """A snapshot of the ring: the newest finished boundary spans,
+        oldest first by END time (a parent follows its children)."""
+        with self._ring_lock:
+            return list(self._ring)
 
 
 #: the process-wide tracer (analog of ``utils.metrics.metrics``)

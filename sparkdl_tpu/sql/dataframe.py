@@ -133,14 +133,18 @@ class DataFrame:
     # actions
     # ------------------------------------------------------------------
     def collect(self) -> List[Row]:
+        from sparkdl_tpu.obs.trace import tracer
+
         names = self.columns
         rows: List[Row] = []
-        for part in self._partitions:
-            n = _partition_nrows(part)
-            cols = [part[c] for c in names]
-            rows.extend(Row._make(names, vals) for vals in zip(*cols))
-            if n and not names:
-                raise RuntimeError("partition with rows but no columns")
+        with tracer.boundary("sql.collect") as span:
+            for part in self._partitions:
+                n = _partition_nrows(part)
+                cols = [part[c] for c in names]
+                rows.extend(Row._make(names, vals) for vals in zip(*cols))
+                if n and not names:
+                    raise RuntimeError("partition with rows but no columns")
+            span.set_attribute("rows", len(rows))
         return rows
 
     def take(self, num: int) -> List[Row]:

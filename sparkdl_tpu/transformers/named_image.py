@@ -29,6 +29,7 @@ from sparkdl_tpu.ml.base import Transformer
 from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.models import get_keras_application_model
 from sparkdl_tpu.models.registry import SUPPORTED_MODELS, decode_predictions
+from sparkdl_tpu.obs.trace import tracer
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
 from sparkdl_tpu.sql.types import Row
@@ -282,9 +283,16 @@ class _NamedImageTransformer(Transformer, HasInputCol, HasOutputCol):
             # i+1 decodes on a prefetch thread and dispatches before chunk
             # i's fetch.  The decode plan (shape + dtype) is decided over
             # the whole partition so exactly one program compiles.
-            decode = make_image_decode_plan(rows, 3, (height, width))
-            result = run_batched_rows(forward, rows, decode, batch_size)
-            out[output_col] = self._postprocess(result)
+            # The boundary spans name where a partition's time goes
+            # (obs.trace): run_batched_rows adds ``batches`` and hangs the
+            # pack, wait, place, dispatch and fetch spans under this one.
+            with tracer.boundary(
+                "featurize.partition", rows=len(rows), batch_size=batch_size
+            ):
+                decode = make_image_decode_plan(rows, 3, (height, width))
+                result = run_batched_rows(forward, rows, decode, batch_size)
+                with tracer.boundary("featurize.postprocess", rows=len(rows)):
+                    out[output_col] = self._postprocess(result)
             return out
 
         return dataset.mapPartitions(process_partition)

@@ -245,7 +245,6 @@ class NativeDeepImageFeaturizer(Transformer, HasInputCol, HasOutputCol):
                         k = min(batch, n - i * batch)
                         feats.append(np.asarray(outs[0])[:k])
             metrics.counter("sparkdl.rows_processed").add(n)
-            metrics.counter("sparkdl.batches_run").add(-(-n // batch))
             flat = np.concatenate(feats).astype(np.float64)
             out[output_col] = [DenseVector(v) for v in flat]
             return out

@@ -433,18 +433,25 @@ def make_image_decode_plan(
     :func:`run_batched_rows`.  Raises :class:`MixedImageSizesError` when
     the partition mixes sizes and ``size`` is None.
     """
-    hws = {(int(r["height"]), int(r["width"])) for r in rows}
-    uniform = len(hws) == 1
-    if not uniform and size is None:
-        raise MixedImageSizesError(
-            f"partition mixes image sizes {sorted(hws)} and no target size "
-            "is configured; resize upstream or set an input size"
+    from sparkdl_tpu.obs.trace import tracer
+
+    with tracer.boundary("featurize.plan", rows=len(rows)) as span:
+        hws = {(int(r["height"]), int(r["width"])) for r in rows}
+        uniform = len(hws) == 1
+        if not uniform and size is None:
+            raise MixedImageSizesError(
+                f"partition mixes image sizes {sorted(hws)} and no target "
+                "size is configured; resize upstream or set an input size"
+            )
+        prefer_u8 = (
+            uniform
+            and n_channels in (1, 3)
+            and all(int(r["mode"]) in _U8_MODES for r in rows)
         )
-    prefer_u8 = (
-        uniform
-        and n_channels in (1, 3)
-        and all(int(r["mode"]) in _U8_MODES for r in rows)
-    )
+        # what the plan decides: the dtype and (H, W, C) of every packed row
+        packed_hw = next(iter(hws)) if uniform else size
+        span.set_attribute("dtype", "uint8" if prefer_u8 else "float32")
+        span.set_attribute("shape", (*map(int, packed_hw), n_channels))
 
     def decode(chunk):
         return decode_image_batch(
@@ -530,6 +537,7 @@ def run_batched_multi(
 
     Returns one concatenated array per function output.
     """
+    from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
     from sparkdl_tpu.utils.profiler import maybe_trace
 
@@ -574,11 +582,15 @@ def run_batched_multi(
 
     window = DispatchWindow(depth=0 if _serial_inference() else None)
     # 'sparkdl.serve' is end-to-end loop wall time (the sustained-rate
-    # denominator); 'sparkdl.forward' is the dispatch+fetch subset.  Here
-    # inputs are pre-decoded so the two coincide; run_batched_rows (lazy
-    # decode in the loop) is where they diverge.
+    # denominator); 'sparkdl.forward' is the HOST's time in place +
+    # dispatch + blocking on fetches, not the device's: with the window
+    # full it is mostly the wait for results, with it empty mostly the
+    # transfer in.  Here inputs are pre-decoded so the two coincide;
+    # run_batched_rows (lazy decode in the loop) is where they diverge.
+    # The engine.* boundary spans split it by layer.
     serve_timer = metrics.timer("sparkdl.serve")
     forward_timer = metrics.timer("sparkdl.forward")
+    program = _program_name(fn)
     try:
         with maybe_trace(), serve_timer.time(), forward_timer.time():
             for lo in range(0, n, batch_size):
@@ -586,7 +598,12 @@ def run_batched_multi(
                 k = chunks[0].shape[0]
                 if k < batch_size:
                     chunks = [pad_to_batch(c, batch_size) for c in chunks]
-                results = fn(*[_place(c) for c in chunks])
+                with tracer.boundary(
+                    "engine.place", bytes=sum(c.nbytes for c in chunks)
+                ):
+                    placed = [_place(c) for c in chunks]
+                with tracer.boundary("engine.dispatch", program=program):
+                    results = fn(*placed)
                 if not isinstance(results, (tuple, list)):
                     results = (results,)
                 for host, k_done in window.submit(tuple(results), meta=k):
@@ -596,7 +613,6 @@ def run_batched_multi(
     finally:
         window.abandon()
     metrics.counter("sparkdl.rows_processed").add(n)
-    metrics.counter("sparkdl.batches_run").add(-(-n // batch_size))
     rate = metrics.images_per_sec()
     if rate:
         logger.debug("run_batched: %d rows, %.1f rows/sec sustained", n, rate)
@@ -612,6 +628,14 @@ def run_batched(
     """Single-input, single-output convenience wrapper of
     :func:`run_batched_multi`."""
     return run_batched_multi(fn, [batch], batch_size)[0]
+
+
+def _program_name(fn: Callable) -> str:
+    """What a dispatched callable is called in spans: an engine
+    function's ``name``, else the function's own."""
+    return str(
+        getattr(fn, "name", None) or getattr(fn, "__name__", type(fn).__name__)
+    )
 
 
 def _serial_inference() -> bool:
@@ -652,6 +676,7 @@ def run_batched_rows(
     background decode thread follows the package's clean-shutdown protocol
     and feeds the ``data.*`` metrics.
     """
+    from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
     from sparkdl_tpu.utils.profiler import maybe_trace
 
@@ -673,10 +698,25 @@ def run_batched_rows(
     serial = _serial_inference()
     bounds = [(lo, min(lo + batch_size, n)) for lo in range(0, n, batch_size)]
 
+    # the caller's span (``featurize.partition``): the packs run on the
+    # prefetch thread, which inherits no context, so they take their
+    # parent from here (``tracer.capture()`` is None while tracing is
+    # off); a serial pack nests in the wait that runs it
+    partition = tracer.current()
+    if partition is not None:
+        partition.set_attribute("batches", len(bounds))
+
     def decode_chunk(lo, hi):
-        batch = decode(rows[lo:hi])
-        k = batch.shape[0]
-        return pad_to_batch(batch, batch_size), k
+        with tracer.boundary(
+            "data.pack", parent=tracer.current() or partition
+        ) as span:
+            batch = decode(rows[lo:hi])
+            k = batch.shape[0]
+            batch = pad_to_batch(batch, batch_size)
+            span.set_attribute("rows", k)
+            span.set_attribute("padded_rows", batch.shape[0])
+            span.set_attribute("bytes", batch.nbytes)
+        return batch, k
 
     if serial:
         chunk_iter = (decode_chunk(lo, hi) for lo, hi in bounds)
@@ -698,19 +738,32 @@ def run_batched_rows(
     # decode_image_batch — not here, to avoid double counting)
     collected: List[np.ndarray] = []
     window = DispatchWindow(depth=0 if serial else None)
-    # 'sparkdl.forward' times only dispatch + device fetch: pulling the
-    # next chunk (lazy decode in serial mode, queue wait in pipelined
-    # mode) advances 'sparkdl.load' inside the decode closure, so timing
-    # the whole loop would double-count load under forward.  The whole
-    # loop — load waits included — runs under 'sparkdl.serve', the
-    # sustained end-to-end rate images_per_sec() reports.
+    # 'sparkdl.forward' is the HOST's time in place + dispatch + blocking
+    # on fetches — not the device's: the device works on while the host
+    # packs, and waits while the host is here placing.  The engine.*
+    # boundary spans split it by layer.  Pulling the next chunk (lazy
+    # decode in serial mode, queue wait in pipelined mode) advances
+    # 'sparkdl.load' inside the decode closure, so timing the whole loop
+    # would double-count load under forward.  The whole loop — load waits
+    # included — runs under 'sparkdl.serve', the sustained end-to-end
+    # rate images_per_sec() reports.
     serve_timer = metrics.timer("sparkdl.serve")
     forward_timer = metrics.timer("sparkdl.forward")
+    program = _program_name(fn)
+    done = object()
     try:
         with maybe_trace(), serve_timer.time():
-            for batch, k in chunk_iter:
+            while True:
+                with tracer.boundary("engine.load_wait"):
+                    chunk = next(chunk_iter, done)
+                if chunk is done:
+                    break
+                batch, k = chunk
                 with forward_timer.time():
-                    result = fn(_place(batch))  # async dispatch
+                    with tracer.boundary("engine.place", bytes=batch.nbytes):
+                        placed = _place(batch)
+                    with tracer.boundary("engine.dispatch", program=program):
+                        result = fn(placed)  # async dispatch
                     if isinstance(result, (tuple, list)):
                         raise TypeError(
                             "run_batched_rows requires a single-output fn "
@@ -729,7 +782,6 @@ def run_batched_rows(
         if close is not None:
             close()
     metrics.counter("sparkdl.rows_processed").add(n)
-    metrics.counter("sparkdl.batches_run").add(len(bounds))
     return np.concatenate(collected, axis=0)
 
 

@@ -1,0 +1,304 @@
+"""Layer-boundary spans (``Tracer.boundary``): ordinary spans that are
+recorded whether or not the tracer is enabled, kept in one bounded ring
+(``tracer.recent()``) and mirrored into the profiler as ``sparkdl.*``
+annotations; and the engine's account of a device with nothing to do
+(``engine.starved``)."""
+
+import collections
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+from sparkdl_tpu.engine import DispatchWindow, executor
+from sparkdl_tpu.obs import JsonlTraceSink, tracer
+from sparkdl_tpu.obs import trace as trace_mod
+from sparkdl_tpu.obs.trace import BOUNDARY_RING_SIZE, Tracer
+
+
+@pytest.fixture(autouse=True)
+def tracing_off_and_an_empty_ring(monkeypatch):
+    tracer.disable()
+    monkeypatch.setattr(
+        tracer, "_ring", collections.deque(maxlen=BOUNDARY_RING_SIZE))
+    yield
+    tracer.disable()
+
+
+def since(mark):
+    """The global ring's records that started after ``mark``."""
+    return [r for r in tracer.recent() if r.start_ns >= mark]
+
+
+# ----------------------------------------------------------------------
+# the primitive
+# ----------------------------------------------------------------------
+def test_recorded_while_disabled_and_not_delivered_to_sinks():
+    sink = JsonlTraceSink(capacity=16)
+    tracer.add_sink(sink)  # a sink alone does not enable
+    assert not tracer.enabled
+    mark = tracer.clock_ns()
+    with tracer.boundary("layer.work", rows=3) as span:
+        assert tracer.current() is span
+        assert tracer.capture() is None  # disabled: nothing to propagate
+    assert tracer.current() is None
+    (rec,) = since(mark)
+    assert rec.name == "layer.work" and rec.attributes == {"rows": 3}
+    assert rec.span_id == span.span_id and rec.parent_id is None
+    assert rec.thread_id == threading.get_ident()
+    assert rec.end_ns > rec.start_ns >= mark
+    assert sink.spans() == []
+    # per-item spans stay behind ``enabled``
+    with tracer.span("item") as item:
+        assert item is None
+    assert len(since(mark)) == 1
+
+
+def test_delivered_to_sinks_when_enabled_and_nests_with_ordinary_spans():
+    sink = JsonlTraceSink(capacity=16)
+    tracer.enable(sink)
+    mark = tracer.clock_ns()
+    with tracer.span("request") as outer:
+        with tracer.boundary("layer.work") as mid:
+            with tracer.span("inner") as inner:
+                pass
+    assert mid.parent_id == outer.span_id and mid.trace_id == outer.trace_id
+    assert inner.parent_id == mid.span_id
+    assert [s["name"] for s in sink.spans()] == [
+        "inner", "layer.work", "request"]
+    assert [r.name for r in since(mark)] == ["layer.work"]  # ring: boundaries only
+
+
+def test_sampled_out_of_the_sinks_but_never_out_of_the_ring():
+    sink = JsonlTraceSink(capacity=16)
+    tracer.enable(sink)
+    tracer.configure_sampling(0.0)
+    mark = tracer.clock_ns()
+    with tracer.boundary("layer.work"):
+        pass
+    assert sink.spans() == []
+    assert [r.name for r in since(mark)] == ["layer.work"]
+
+
+def test_nesting_explicit_cross_thread_parent_and_same_thread_self_time():
+    own = Tracer()
+    ticks = iter(range(0, 10_000, 10))
+    own.clock_ns = lambda: next(ticks)
+    seen = {}
+
+    def pack(parent):
+        seen["inherited"] = own.current()  # a new thread has no context
+        with own.boundary("data.pack", parent=parent):
+            pass
+
+    with own.boundary("partition") as part:       # 0 ..
+        with own.boundary("plan"):                # 10 .. 20
+            pass
+        worker = threading.Thread(target=pack, args=(part,))
+        worker.start()                            # 30 .. 40 on the worker
+        worker.join(timeout=10)
+        assert not worker.is_alive()
+        with own.boundary("dispatch"):            # 50 .. 60
+            pass
+    recs = {r.name: r for r in own.recent()}      # partition ends at 70
+    assert seen["inherited"] is None
+    assert [r.name for r in own.recent()] == [
+        "plan", "data.pack", "dispatch", "partition"]  # order of END times
+    for child in ("plan", "data.pack", "dispatch"):
+        assert recs[child].parent_id == recs["partition"].span_id
+    assert recs["data.pack"].thread_id != recs["partition"].thread_id
+    assert recs["plan"].thread_id == recs["partition"].thread_id
+    # self time: duration less the children on the SAME thread; the pack
+    # ran beside the partition's thread and takes nothing from it
+    part_rec = recs["partition"]
+    same_thread = sum(
+        r.end_ns - r.start_ns for r in own.recent()
+        if r.parent_id == part_rec.span_id
+        and r.thread_id == part_rec.thread_id)
+    assert part_rec.end_ns - part_rec.start_ns == 70
+    assert (part_rec.end_ns - part_rec.start_ns) - same_thread == 50
+
+
+def test_backdated_start():
+    own = Tracer()
+    own.clock_ns = lambda: 5_000
+    with own.boundary("engine.starved", start_ns=1_000) as span:
+        pass
+    (rec,) = own.recent()
+    assert (rec.start_ns, rec.end_ns) == (1_000, 5_000)
+    assert span.duration_ms == pytest.approx(0.004)
+
+
+def test_ring_is_bounded_and_recent_is_a_snapshot():
+    own = Tracer()
+    for i in range(BOUNDARY_RING_SIZE + 10):
+        with own.boundary("b", i=i):
+            pass
+    snap = own.recent()
+    assert len(snap) == BOUNDARY_RING_SIZE
+    assert snap[0].attributes == {"i": 10}  # the oldest ten fell out
+    with own.boundary("later"):
+        pass
+    assert len(snap) == BOUNDARY_RING_SIZE and snap[-1].name == "b"
+    assert own.recent()[-1].name == "later"
+
+
+def test_obs_trace_alone_does_not_import_jax():
+    code = (
+        "import sys\n"
+        "from sparkdl_tpu.obs.trace import tracer\n"
+        "with tracer.boundary('layer.work'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'a boundary span imported jax'\n"
+        "print(len(tracer.recent()))\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "1"
+
+
+def test_open_boundary_is_a_profiler_annotation(monkeypatch):
+    import jax
+
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with tracer.boundary("engine.dispatch"):
+        log.append("body")
+    # a backdated span cannot be annotated
+    with tracer.boundary("engine.starved", start_ns=tracer.clock_ns() - 10):
+        pass
+    assert log == [("enter", "sparkdl.engine.dispatch"), "body",
+                   ("exit", "sparkdl.engine.dispatch")]
+    assert trace_mod.ANNOTATION_PREFIX == "sparkdl."
+
+
+# ----------------------------------------------------------------------
+# engine.starved
+# ----------------------------------------------------------------------
+@pytest.fixture
+def scripted(monkeypatch):
+    """A fresh process-wide count and a clock that ticks 1 µs a reading and
+    moves on by hand."""
+    now = [0]
+
+    def clock():
+        now[0] += 1_000
+        return now[0]
+
+    monkeypatch.setattr(executor, "_outstanding", executor._Outstanding())
+    monkeypatch.setattr(tracer, "clock_ns", clock)
+    return now
+
+
+def starved(mark=0):
+    return [r for r in since(mark) if r.name == "engine.starved"]
+
+
+def test_starved_none_before_first_submit_then_one_per_emptying(scripted):
+    now = scripted
+    window = DispatchWindow(depth=1)
+    now[0] = 1_000_000
+    assert window.submit(np.zeros(4), meta=0) == []
+    assert starved() == []  # nothing before the process's first submit
+    (host, meta), = window.submit(np.ones(4), meta=1)  # count 2 -> 1
+    assert meta == 0 and starved() == []
+    list(window.drain())  # count 0: the clock is stamped
+    last_fetch = [r for r in since(0) if r.name == "engine.fetch_wait"][-1]
+    assert last_fetch.attributes == {"bytes": 32}
+    now[0] += 5_000_000  # the host does something else
+    window.submit(np.zeros(4), meta=2)
+    (gap,) = starved()
+    assert gap.start_ns == last_fetch.end_ns + 1_000  # the very next reading
+    assert 5_000_000 < gap.end_ns - gap.start_ns < 5_010_000
+    window.submit(np.zeros(4), meta=3)  # not empty: no new interval
+    assert len(starved()) == 1
+    window.abandon()  # dropping the rest empties the count too
+    now[0] += 2_000_000
+    window.submit(np.zeros(4), meta=4)
+    assert len(starved()) == 2
+    assert starved()[-1].end_ns - starved()[-1].start_ns > 2_000_000
+    list(window.drain())
+
+
+def test_starved_two_windows_share_the_count(scripted):
+    now = scripted
+    first, second = DispatchWindow(depth=2), DispatchWindow(depth=2)
+    first.submit(np.zeros(2))
+    second.submit(np.zeros(2))
+    list(first.drain())  # the second window still has a result out
+    now[0] += 1_000_000
+    first.submit(np.zeros(2))
+    assert starved() == []
+    list(first.drain())
+    list(second.drain())  # now the process has nothing dispatched
+    now[0] += 3_000_000
+    second.submit(np.zeros(2))
+    (gap,) = starved()
+    assert gap.end_ns - gap.start_ns > 3_000_000
+    list(second.drain())
+
+
+def test_a_failed_fetch_still_counts_as_fetched(scripted, monkeypatch):
+    def boom(result):
+        raise RuntimeError("fetch failed")
+
+    monkeypatch.setattr(executor, "_fetch_host", boom)
+    delivering = DispatchWindow(depth=0, capture_errors=True)
+    (failure, meta), = delivering.submit(np.zeros(2), meta="m")
+    assert isinstance(failure, executor.FetchFailure) and meta == "m"
+    assert executor._outstanding._count == 0
+    raising = DispatchWindow(depth=0)
+    with pytest.raises(RuntimeError):
+        raising.submit(np.zeros(2))
+    assert executor._outstanding._count == 0
+    assert len(starved()) == 1  # between the two submits
+
+
+# ----------------------------------------------------------------------
+# the spans of the batched loop
+# ----------------------------------------------------------------------
+def test_place_bytes_are_exact_for_a_padded_last_batch():
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.transformers.utils import run_batched_rows
+
+    rows = [np.full((5, 3), i, np.float32) for i in range(20)]
+    mark = tracer.clock_ns()
+    with tracer.boundary("featurize.partition", rows=len(rows)) as part:
+        out = run_batched_rows(
+            lambda x: jnp.sum(x, axis=(1, 2)), rows, np.stack, batch_size=8)
+    np.testing.assert_allclose(out, [15.0 * i for i in range(20)])
+    recs = since(mark)
+    by_name = {}
+    for r in recs:
+        by_name.setdefault(r.name, []).append(r)
+    row_bytes = 5 * 3 * 4
+    # 20 rows in batches of 8: the last one holds 4 rows padded to 8, and
+    # all 8 rows' bytes go to the device
+    assert [r.attributes["bytes"] for r in by_name["engine.place"]] == [
+        8 * row_bytes] * 3
+    packs = sorted(by_name["data.pack"], key=lambda r: r.start_ns)
+    assert [p.attributes["rows"] for p in packs] == [8, 8, 4]
+    assert [p.attributes["padded_rows"] for p in packs] == [8, 8, 8]
+    assert all(p.parent_id == part.span_id for p in packs)
+    assert all(p.thread_id != threading.get_ident() for p in packs)
+    assert len(by_name["engine.dispatch"]) == 3
+    assert len(by_name["engine.load_wait"]) == 4  # the last finds the end
+    assert sum(r.attributes["bytes"] for r in by_name["engine.fetch_wait"]) \
+        == 3 * 8 * 4
+    (partition,) = by_name["featurize.partition"]
+    assert partition.attributes == {"rows": 20, "batches": 3}
