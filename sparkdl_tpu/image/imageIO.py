@@ -10,12 +10,14 @@ handle BGR↔RGB exactly like the reference's ``buildSpImageConverter``.
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import io
 import logging
 import os
 from collections import namedtuple
-from typing import Callable, List, Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 from PIL import Image
@@ -167,17 +169,35 @@ class ImageDecodeError(ValueError, _PermanentError):
         super().__init__(f"cannot decode image {origin!r}{detail}")
 
 
+# PIL mode → (raw packing that gives the stored channel order, nChannels,
+# OpenCV type): ``tobytes("raw", "BGR")`` is the bytes of asarray → reverse
+# the channels → ascontiguousarray → tobytes in one copy, not three
+_STORED_AS = {
+    "L": ("L", 1, ocvTypes["CV_8UC1"]),
+    "RGB": ("BGR", 3, ocvTypes["CV_8UC3"]),
+    "RGBA": ("BGRA", 4, ocvTypes["CV_8UC4"]),
+}
+
+
 def _decode_image_bytes(raw: bytes, origin: str = "") -> Optional[Row]:
     """Decode compressed image bytes (PNG/JPEG/...) → image struct, or None
     if undecodable (matching the reference's null-tolerant decode)."""
     try:
         img = Image.open(io.BytesIO(raw))
-        if img.mode not in ("L", "RGB", "RGBA"):
+        if img.mode not in _STORED_AS:
             img = img.convert("RGB")
-        arr = np.asarray(img)
+        packing, n_channels, ocv_type = _STORED_AS[img.mode]
+        data = img.tobytes("raw", packing)
     except Exception:
         return None
-    return rgbArrayToStruct(arr, origin) if arr.ndim == 3 else imageArrayToStruct(arr, origin)
+    return Row(
+        origin=origin,
+        height=img.height,
+        width=img.width,
+        nChannels=n_channels,
+        mode=ocv_type,
+        data=data,
+    )
 
 
 def PIL_decode_and_resize(size):
@@ -221,18 +241,27 @@ _IMG_EXTENSIONS = (".jpg", ".jpeg", ".png", ".gif", ".bmp", ".webp")
 
 def _list_files(path: str) -> List[str]:
     if os.path.isdir(path):
-        files = sorted(
-            os.path.join(path, f)
-            for f in os.listdir(path)
-            if os.path.isfile(os.path.join(path, f))
-        )
+        # the entry's own type where the listing carries it: no stat a file
+        with os.scandir(path) as entries:
+            files = sorted(e.path for e in entries if e.is_file())
     else:
         files = sorted(glob.glob(path))
     return files
 
 
+def _read_file(path: str) -> Tuple[str, bytes]:
+    # unbuffered: no isatty() and no tell() before readall(), which a
+    # buffered open().read() ends in too — two system calls a file fewer
+    with open(path, "rb", buffering=0) as fh:
+        return path, fh.readall()
+
+
 def filesToDF(session, path: str, numPartitions: int = 4):
     """Read files from a directory/glob → DataFrame (filePath, fileData).
+
+    Eager and in listing order, on the calling thread: a pool of threads
+    was measured here and lost to the serial loop wherever the files come
+    from the page cache (PERF.md §6, PR 30).
 
     Reference analog: ``imageIO.filesToDF`` over ``sc.binaryFiles``†.
     """
@@ -240,16 +269,50 @@ def filesToDF(session, path: str, numPartitions: int = 4):
     from sparkdl_tpu.sql.session import TPUSession
 
     session = session or TPUSession.getActiveSession()
-    rows = []
     with tracer.boundary("image.read_files") as span:
-        for f in _list_files(path):
-            with open(f, "rb") as fh:
-                rows.append((f, fh.read()))
+        rows = [_read_file(f) for f in _list_files(path)]
         span.set_attribute("files", len(rows))
         span.set_attribute("bytes", sum(len(raw) for _, raw in rows))
+        span.set_attribute("workers", 1)
     return session.createDataFrame(
         rows, ["filePath", "fileData"], numPartitions=numPartitions
     )
+
+
+def _pool_width(n_items: int) -> int:
+    """Worker threads for decoding a partition of ``n_items`` files: one
+    per CPU this process may run on, and never fewer than two files a
+    worker — below that (1) the loop runs inline on the calling thread."""
+    try:
+        cpus = len(os.sched_getaffinity(0))
+    except AttributeError:  # not on this platform
+        cpus = os.cpu_count() or 1
+    return max(1, min(cpus, n_items // 2))
+
+
+@contextlib.contextmanager
+def _ordered_map(
+    fn: Callable, items: Sequence
+) -> Iterator[Tuple[Iterable, int]]:
+    """``(results, workers)``: ``fn`` over ``items`` with the results in
+    input order, on a pool of :func:`_pool_width` threads that lives for
+    the ``with`` block (threads, not processes: PIL's decoders release
+    the GIL, a struct pickled back costs what a decode saves, and a fork
+    after the TPU runtime is up is not safe).  An exception of ``fn`` is
+    raised where its result is consumed, so the first one in input order
+    wins; leaving the block early cancels what has not started and joins
+    every thread."""
+    workers = _pool_width(len(items))
+    if workers == 1:
+        yield map(fn, items), 1
+        return
+    pool = ThreadPoolExecutor(
+        max_workers=workers, thread_name_prefix="sparkdl-image-io"
+    )
+    try:
+        yield pool.map(fn, items), workers
+    finally:
+        pool.shutdown(wait=True, cancel_futures=True)
 
 
 def readImages(
@@ -266,7 +329,12 @@ def readImages(
     ``data.decode_errors`` counter and logs the origin.
     ``on_error="raise"`` fails the read with :class:`ImageDecodeError`
     naming the corrupt file — for pipelines where a dropped row means a
-    silently wrong join downstream."""
+    silently wrong join downstream.
+
+    Each partition's files are decoded on a pool of threads sized from the
+    host's CPUs (inline for a few files); rows keep the listing order, and
+    every struct's bytes, the counter and the error raised are those of a
+    serial decode."""
     return readImagesWithCustomFn(
         path,
         decode_f=_decode_image_bytes,
@@ -287,7 +355,12 @@ def readImagesWithCustomFn(
     Optional[Row]``; a None return (or a raise) from ``decode_f`` is a
     decode failure, handled per ``on_error`` ("skip" counts it in
     ``data.decode_errors`` and drops the row, "raise" aborts with
-    :class:`ImageDecodeError`)."""
+    :class:`ImageDecodeError` for the first such file in listing order).
+
+    ``decode_f`` is called from several threads at once (as Spark calls it
+    from several executors): it must not depend on shared mutable state or
+    on call order.  Rows come back in listing order all the same; skips
+    are logged and counted on the calling thread."""
     if on_error not in ("skip", "raise"):
         raise ValueError(
             f'on_error must be "skip" or "raise", got {on_error!r}'
@@ -297,30 +370,35 @@ def readImagesWithCustomFn(
     session = session or TPUSession.getActiveSession()
     files_df = filesToDF(session, path, numPartitions=numPartitions)
 
+    def decode_one(item):
+        """On a worker: the struct or None, and what ``decode_f`` raised."""
+        fp, raw = item
+        try:
+            return decode_f(raw, fp), None
+        except Exception as exc:
+            return None, exc
+
     def decode_partition(part):
         from sparkdl_tpu.obs.trace import tracer
         from sparkdl_tpu.utils.metrics import metrics
 
         decode_errors = metrics.counter("data.decode_errors")
+        files = list(zip(part["filePath"], part["fileData"]))
         images, origins = [], []
         with tracer.boundary("image.decode") as span:
-            for fp, raw in zip(part["filePath"], part["fileData"]):
-                try:
-                    struct = decode_f(raw, fp)
-                except Exception as exc:
-                    if on_error == "raise":
-                        raise ImageDecodeError(fp, exc) from exc
-                    struct = None
-                if struct is None:
-                    if on_error == "raise":
-                        raise ImageDecodeError(fp)
-                    decode_errors.add(1)
-                    logger.warning("dropping undecodable image %s", fp)
-                    continue
-                images.append(struct)
-                origins.append(fp)
+            with _ordered_map(decode_one, files) as (results, workers):
+                for (fp, _), (struct, exc) in zip(files, results):
+                    if struct is None:
+                        if on_error == "raise":
+                            raise ImageDecodeError(fp, exc) from exc
+                        decode_errors.add(1)
+                        logger.warning("dropping undecodable image %s", fp)
+                        continue
+                    images.append(struct)
+                    origins.append(fp)
             span.set_attribute("rows", len(images))
-            span.set_attribute("errors", len(part["filePath"]) - len(images))
+            span.set_attribute("errors", len(files) - len(images))
+            span.set_attribute("workers", workers)
         return {"filePath": origins, "image": images}
 
     schema = StructType(

@@ -302,3 +302,59 @@ def test_place_bytes_are_exact_for_a_padded_last_batch():
         == 3 * 8 * 4
     (partition,) = by_name["featurize.partition"]
     assert partition.attributes == {"rows": 20, "batches": 3}
+
+
+# ----------------------------------------------------------------------
+# the spans of readImages, whose loops run on a pool of threads
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("files,partitions,width", [
+    (24, 1, 4), (24, 3, 4), (24, 2, 1), (3, 1, None),
+], ids=["pooled-1", "pooled-3", "inline-2", "three-files"])
+def test_read_images_spans_are_roots_on_the_calling_thread(
+        tmp_path, monkeypatch, files, partitions, width):
+    """One ``image.read_files`` a call and one ``image.decode`` a partition,
+    on the calling thread, with nothing of the workers' under them: their
+    self time (what ``file_read/decode_ms_per_image.featurize`` read) is
+    their whole duration.  ``workers`` says how wide the decode's pool was;
+    the files are read inline."""
+    from PIL import Image
+
+    from sparkdl_tpu.image import imageIO
+    from sparkdl_tpu.sql.session import TPUSession
+
+    rng = np.random.RandomState(0)
+    for i in range(files):
+        Image.fromarray(rng.randint(0, 255, (8 + i, 12, 3), dtype=np.uint8)).save(
+            tmp_path / f"f{i:02d}.png")
+    (tmp_path / "f01.png").write_bytes(b"corrupt")
+    if width is not None:
+        monkeypatch.setattr(imageIO, "_pool_width", lambda n_items: width)
+    else:  # the real rule: three files are decoded inline
+        width = 1
+    session = TPUSession.builder.master("local[*]").getOrCreate()
+    tracer.enable()  # worker threads would deliver their spans too
+    sink = JsonlTraceSink(capacity=256)
+    tracer.add_sink(sink)
+    mark = tracer.clock_ns()
+    try:
+        df = imageIO.readImages(str(tmp_path), session, numPartitions=partitions)
+    finally:
+        tracer.remove_sink(sink)
+    recs = since(mark)
+    assert sorted(r.name for r in recs) == (
+        ["image.decode"] * partitions + ["image.read_files"])
+    assert all(r.parent_id is None for r in recs)
+    assert all(r.thread_id == threading.get_ident() for r in recs)
+    assert len(sink.spans()) == len(recs)  # and no span from a worker
+    (read,) = [r for r in recs if r.name == "image.read_files"]
+    on_disk = sum(p.stat().st_size for p in tmp_path.iterdir())
+    assert read.attributes == {"files": files, "bytes": on_disk, "workers": 1}
+    decodes = sorted((r for r in recs if r.name == "image.decode"),
+                     key=lambda r: r.start_ns)
+    assert sum(r.attributes["rows"] for r in decodes) == df.count() == files - 1
+    assert [r.attributes["errors"] for r in decodes] == [1] + [0] * (partitions - 1)
+    assert all(r.attributes["workers"] == width for r in decodes)
+    assert all(set(r.attributes) == {"rows", "errors", "workers"} for r in decodes)
+    # the reader's rule: self time = duration less same-thread children
+    spans = {r.span_id for r in recs}
+    assert not [r for r in tracer.recent() if r.parent_id in spans]
