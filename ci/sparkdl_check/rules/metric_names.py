@@ -44,11 +44,14 @@ from ci.sparkdl_check.core import FileContext, Rule, rule
 #: "csql" (open windows, rows/s, late-row counter, watermark-to-emit
 #: latency with exemplars) joined with the ISSUE-19 continuous-SQL
 #: plane.
+#: "generate" (denoising and commit forwards, tokens fixed) and "moe"
+#: (tokens routed and dropped, expert load) joined with the ISSUE-31
+#: block-diffusion stage over a sparse-expert decoder.
 ALLOWED_PREFIXES = (
     "sparkdl", "data", "serving", "resilience", "estimator", "engine",
     "streaming", "slo", "ts", "supervisor", "router", "wire",
     "rollout", "tenant", "fleet", "replica", "faultnet", "diag",
-    "profile", "cache", "decode", "batcher", "csql",
+    "profile", "cache", "decode", "batcher", "csql", "generate", "moe",
 )
 
 METRIC_FACTORIES = {"counter", "timer", "gauge", "histogram"}

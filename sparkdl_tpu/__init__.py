@@ -38,6 +38,7 @@ _EXPORTS = {
     "TPUTransformer": "sparkdl_tpu.transformers.tf_tensor",
     "TFTransformer": "sparkdl_tpu.transformers.tf_tensor",
     "KerasTransformer": "sparkdl_tpu.transformers.keras_tensor",
+    "BlockDiffusionTransformer": "sparkdl_tpu.transformers.block_diffusion",
     "KerasImageFileEstimator": "sparkdl_tpu.estimators.keras_image_file_estimator",
     "registerKerasImageUDF": "sparkdl_tpu.udf.keras_image_model",
     "makeGraphUDF": "sparkdl_tpu.graph.tensorframes_udf",

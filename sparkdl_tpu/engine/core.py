@@ -37,7 +37,7 @@ import itertools
 import logging
 import os
 import time
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -58,6 +58,39 @@ _anon_ids = itertools.count(1)
 _COMPILE_TIMEOUT_ENV = "SPARKDL_ENGINE_COMPILE_TIMEOUT_S"
 _DEFAULT_COMPILE_SOFT_S = 300.0
 _DEFAULT_COMPILE_HARD_S = 1800.0
+
+
+class _JaxCacheHits:
+    """Counts the compiles that JAX's own persistent cache served (its
+    monitoring event), so that :meth:`ExecutionEngine._resolve` can tell a
+    fresh executable from a loaded one."""
+
+    _EVENT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.count = 0
+        self._listening = False
+
+    def listen(self) -> None:
+        if not self._listening:
+            import jax
+
+            self._listening = True
+            jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_):
+        if event == self._EVENT:
+            self.count += 1
+
+
+_jax_cache_hits = _JaxCacheHits()
+
+
+def _donate_argnums(donate, n_args: int) -> Tuple[int, ...]:
+    """``donate`` as positions: True is every argument, a sequence itself."""
+    if isinstance(donate, (tuple, list)):
+        return tuple(int(i) for i in donate)
+    return tuple(range(n_args)) if donate else ()
 
 
 def _compile_timeouts() -> Tuple[float, float]:
@@ -174,12 +207,16 @@ class ExecutionEngine:
         fn: Callable,
         example_args: Sequence[Any],
         fingerprint: Optional[str] = None,
-        donate: bool = False,
+        donate: Union[bool, Sequence[int]] = False,
         name: Optional[str] = None,
     ) -> ProgramHandle:
         """Resolve the executable for ``fn`` at the concrete signature of
         ``example_args`` (arrays or ShapeDtypeStructs; pytree args
         supported): in-memory LRU → persistent cache → AOT compile.
+
+        ``donate`` is True (every argument), False, or the positions of
+        the arguments to donate — a program that takes its weights as
+        arguments donates its carried state and not them.
 
         ``fingerprint`` must durably identify the function *and any
         weights it closes over*; without one the program is compiled and
@@ -233,9 +270,9 @@ class ExecutionEngine:
             for shape, dtype, sharding in leaf_specs
         ]
         arg_specs.append(((0,), str(treedef), None))  # pytree structure
+        # True keeps the key it always had, (0,); positions are their own
         return cache_key(
-            fp, arg_specs, donate_argnums=(0,) if donate else ()
-        )
+            fp, arg_specs, donate_argnums=_donate_argnums(donate, 1))
 
     def _resolve(
         self, fn, treedef, leaf_specs, key, fingerprint, donate, name
@@ -281,14 +318,15 @@ class ExecutionEngine:
 
         def build():
             jitted = jax.jit(
-                fn, donate_argnums=tuple(range(len(specs))) if donate else ()
-            )
+                fn, donate_argnums=_donate_argnums(donate, len(specs)))
             return jitted.lower(*specs).compile()
 
         from sparkdl_tpu.obs.trace import tracer
         from sparkdl_tpu.resilience.watchdog import watchdogged
 
         metrics.counter("engine.cache_miss").add(1)
+        _jax_cache_hits.listen()
+        hits_before = _jax_cache_hits.count
         start = time.perf_counter()
         with metrics.timer("engine.compile").time():
             if tracer.enabled:
@@ -308,7 +346,12 @@ class ExecutionEngine:
                 )
         elapsed = time.perf_counter() - start
         self._remember(key, compiled, fingerprint, name, "compile")
-        if persistable:
+        # An executable that JAX's own persistent cache served (the same
+        # program under another fingerprint) is not stored again: that cache
+        # serves it to the next process too, and an executable that was
+        # loaded does not always serialise whole — on the CPU a stored copy
+        # of one fails at its first run with "Function ... not found".
+        if persistable and _jax_cache_hits.count == hits_before:
             self.cache.store(
                 key, compiled,
                 meta={

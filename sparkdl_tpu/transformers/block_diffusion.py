@@ -1,0 +1,431 @@
+"""BlockDiffusionTransformer — fixed-length generation by diffusion over
+blocks, over a DataFrame column of prompts (arrays of token ids).
+
+Every other stage is one dispatch a batch.  This one is one prefill (in
+chunks) and then a chain of block steps over a key/value cache that stays on
+the device, donated from dispatch to dispatch; a step yields up to
+``blockLength`` tokens a row, not one.  Between the batch's placement and
+its last fetch only token ids, the per-block record and scalars cross to the
+host.  The model's weights are program ARGUMENTS, placed once per model
+object (:func:`~sparkdl_tpu.transformers.utils.place_params_once`): an
+executable holds no weight constants, and two models of one config share
+their executables.
+
+How a batch is laid out: its rows are sorted by prompt length, longest
+first.  The prompt's whole blocks are prefilled in chunks of about
+``PREFILL_TOKENS`` tokens, each chunk padded to its longest row's length
+rounded up to ``_LENGTH_STEP`` (one compile per distinct chunk shape); pad
+positions are never attended (a row's cache is read up to its own length).
+The ``P mod blockLength`` tokens left open the row's first generated block
+as known positions.  Then ``ceil((P mod B + genLength) / B)`` block steps
+run for the whole batch; the last batch of a partition is padded with
+one-block dummy rows.
+
+Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
+``generate.partition`` (root) > ``generate.plan``, ``engine.place``,
+``generate.prefill``, ``generate.block``, ``engine.fetch_wait``,
+``generate.postprocess``.  Counters: ``generate.denoise_forwards``,
+``generate.commit_forwards``, ``generate.tokens_fixed`` (per real row),
+``moe.tokens_routed``, ``moe.tokens_dropped``, ``moe.expert_load_max``,
+``moe.expert_load_mean`` (from the routing counts that come back with every
+program's result).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Optional
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from sparkdl_tpu.ml.base import Transformer
+from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
+from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
+from sparkdl_tpu.transformers.utils import (
+    _serial_inference,
+    place_params_once,
+)
+
+#: chunk lengths and the cache's span are multiples of this (one compile
+#: per distinct shape; the TPU tiles the span by it)
+_LENGTH_STEP = 128
+#: tokens (rows x padded length) of one prefill chunk: enough rows for every
+#: expert to get hundreds, few enough for the float32 scores (16 rows of 512
+#: are 0.5 GB a layer) to fit beside the weights
+PREFILL_TOKENS = 8192
+
+
+def _round_up(n: int, step: int) -> int:
+    return -(-int(n) // step) * step
+
+
+class BatchPlan:
+    """The layout of one batch of prompts: which row sits where, what is
+    prefilled in which chunk, what opens each row's first block."""
+
+    def __init__(self, prompts: List[np.ndarray], rows: int, block: int,
+                 gen: int, prefill_tokens: int = PREFILL_TOKENS):
+        real = len(prompts)
+        lengths = np.zeros(rows, np.int64)
+        lengths[:real] = [len(p) for p in prompts]
+        whole = lengths // block * block
+        self.rest = (lengths - whole).astype(np.int32)
+        # a dummy row (batch padding) is one block of known zeros
+        self.blocks_of_row = -(-(self.rest + gen) // block)
+        self.blocks_of_row[real:] = 0
+        self.blocks = int(self.blocks_of_row[:real].max())
+        self.order = np.argsort(-whole, kind="stable")  # longest first
+        self.whole = whole[self.order].astype(np.int32)
+        #: the cache's slots: the prompts' up to ``longest``, then every
+        #: row's generated blocks in the same slots
+        longest = _round_up(max(int(self.whole[0]), block), _LENGTH_STEP)
+        self.longest = longest
+        self.span = _round_up(longest + self.blocks * block, _LENGTH_STEP)
+        self.tokens = np.zeros((rows, longest), np.int32)
+        self.first = np.zeros((rows, block), np.int32)
+        self.known = np.zeros((rows, block), bool)
+        for at, row in enumerate(self.order):
+            if row >= real:
+                self.known[at] = True
+                continue
+            prompt = np.asarray(prompts[row], np.int32)
+            w, r = int(self.whole[at]), int(self.rest[row])
+            self.tokens[at, :w] = prompt[:w]
+            self.first[at, :r] = prompt[w:]
+            self.known[at, :r] = True
+        #: (first row, rows, padded length) of each prefill chunk; a chunk
+        #: that would run past the batch's end starts earlier instead and
+        #: writes some rows' first positions again, with the same values
+        self.chunks = []
+        at = 0
+        while at < rows and self.whole[at] > 0:
+            length = _round_up(int(self.whole[at]), _LENGTH_STEP)
+            count = int(min(rows, max(1, prefill_tokens // length)))
+            self.chunks.append((min(at, rows - count), count, length))
+            at += count
+        self.prefilled = int(self.whole.sum())
+
+    def fixed_in_block(self, index: int) -> int:
+        """Positions the real rows fix in block ``index``."""
+        live = self.blocks_of_row[self.order] > index
+        unknown = (~self.known).sum(axis=1) if index == 0 else (
+            np.full(len(live), self.known.shape[1]))
+        return int(unknown[live].sum())
+
+
+class _Runner:
+    """One model's placed params, programs and spare caches."""
+
+    def __init__(self, model, block: int, steps: int, mask_id: int):
+        self.model, self.block, self.steps, self.mask_id = (
+            model, block, steps, mask_id)
+        self.device = jax.local_devices()[0]
+        self.params = place_params_once(model, model.params, self.device)
+        self.programs: Dict[Any, Any] = {}
+        self.caches: Dict[Any, Any] = {}
+
+    def place(self, array):
+        return jax.device_put(array, self.device)
+
+    def cache(self, rows: int, span: int):
+        shape, dtype = self.model.cache_spec(rows, span)
+        held = self.caches.pop(shape, None)
+        if held is None:
+            # what a cache holds past a row's length is never read
+            held = (jnp.zeros(shape, dtype, device=self.device),
+                    jnp.zeros(shape, dtype, device=self.device))
+        return held
+
+    def keep(self, cache_k, cache_v):
+        self.caches[tuple(cache_k.shape)] = (cache_k, cache_v)
+
+    def _program(self, key, make_fn, example, name):
+        """The engine's executable for ``key``; ``make_fn`` builds the
+        function only when this runner has not resolved it yet.  The cache
+        (arguments 1 and 2) is donated, the weights are not."""
+        from sparkdl_tpu.engine import engine
+
+        handle = self.programs.get(key)
+        if handle is None:
+            handle = self.programs[key] = engine.program(
+                make_fn(), example, donate=(1, 2), name=name,
+                fingerprint=f"{self.model.fingerprint}:{name}:{key}",
+            )
+        return handle
+
+    def prefill(self, cache_k, cache_v, tokens, whole, first_row, count,
+                length):
+        model, block = self.model, self.block
+
+        def make():
+            def sdar_prefill(params, cache_k, cache_v, tokens, whole,
+                             first_row):
+                chunk = jax.lax.dynamic_slice(
+                    tokens, (first_row, 0), (count, length))
+                lengths = jax.lax.dynamic_slice(whole, (first_row,), (count,))
+                k, v, counts = model.prefill(params, chunk, lengths, block)
+                at = (0, first_row, 0, 0, 0)
+                return (jax.lax.dynamic_update_slice(cache_k, k, at),
+                        jax.lax.dynamic_update_slice(cache_v, v, at), counts)
+
+            return sdar_prefill
+
+        args = (self.params, cache_k, cache_v, tokens, whole,
+                np.int32(first_row))
+        key = ("prefill", count, length, block, tuple(cache_k.shape),
+               tuple(tokens.shape))
+        return self._program(key, make, args, "sdar_prefill")(*args)
+
+    def block_step(self, cache_k, cache_v, prefix, start, where, tokens,
+                   known):
+        model, steps, mask_id = self.model, self.steps, self.mask_id
+
+        def make():
+            def sdar_block(params, cache_k, cache_v, prefix, start, where,
+                           tokens, known):
+                return model.block_step(
+                    params, cache_k, cache_v, prefix, start, where, tokens,
+                    known, steps, mask_id)
+
+            return sdar_block
+
+        args = (self.params, cache_k, cache_v, prefix, start, where, tokens,
+                known)
+        key = ("block", steps, mask_id, tuple(cache_k.shape),
+               tuple(tokens.shape))
+        return self._program(key, make, args, "sdar_block")(*args)
+
+
+def _runner(model, block: int, steps: int, mask_id: int) -> _Runner:
+    """One runner per generation setting, kept ON the model object: the
+    placed weights, the programs and the spare cache live and die with it."""
+    held = vars(model).setdefault("_block_diffusion_runners", {})
+    key = (block, steps, mask_id)
+    if key not in held:
+        held[key] = _Runner(model, block, steps, mask_id)
+    return held[key]
+
+
+class BlockDiffusionTransformer(Transformer, HasInputCol, HasOutputCol):
+    """Generates ``genLength`` tokens after every prompt of ``inputCol`` by
+    diffusion over blocks of ``blockLength`` positions: each block starts as
+    ``maskTokenId`` at its unknown positions, ``denoisingSteps`` forwards fix
+    the most confident ones (greedy, static low-confidence remasking), one
+    more commits the block to the cache.  ``denoisingSteps`` is the trade of
+    quality against steps: a block costs ``denoisingSteps + 1`` forwards.
+
+    ``outputCol`` gets an int32 array of ``genLength`` tokens a row.
+    ``recordCol`` (optional) gets a float64 array [positions, 3] a row —
+    (token, the step it was fixed at, the log-probability it was fixed
+    with) for every position of the row's generated blocks, from the first
+    block (which the prompt's last ``P mod blockLength`` tokens open: step
+    -1) to the end of the last one (which may run past ``genLength``).
+    """
+
+    model = Param(
+        "undefined", "model",
+        "the model's functions and params: an object with .params, "
+        ".fingerprint, .cache_spec(rows, span), .prefill(...) and "
+        ".block_step(...) (sparkdl_tpu.models.sdar_moe.SdarMoeModel)",
+    )
+    recordCol = Param(
+        "undefined", "recordCol",
+        "optional column for the per-position record", TypeConverters.toString,
+    )
+    genLength = Param(
+        "undefined", "genLength", "tokens generated a row",
+        TypeConverters.toInt,
+    )
+    blockLength = Param(
+        "undefined", "blockLength", "positions a block", TypeConverters.toInt,
+    )
+    denoisingSteps = Param(
+        "undefined", "denoisingSteps", "denoising forwards a block",
+        TypeConverters.toInt,
+    )
+    maskTokenId = Param(
+        "undefined", "maskTokenId", "the [MASK] token's id",
+        TypeConverters.toInt,
+    )
+    batchSize = Param(
+        "undefined", "batchSize", "rows per device batch",
+        TypeConverters.toInt,
+    )
+
+    @keyword_only
+    def __init__(
+        self,
+        inputCol: Optional[str] = None,
+        outputCol: Optional[str] = None,
+        recordCol: Optional[str] = None,
+        model: Any = None,
+        genLength: int = 64,
+        blockLength: int = 4,
+        denoisingSteps: int = 4,
+        maskTokenId: Optional[int] = None,
+        batchSize: int = 64,
+    ):
+        super().__init__()
+        self._setDefault(genLength=64, blockLength=4, denoisingSteps=4,
+                         batchSize=64)
+        self.setParams(**self._input_kwargs)
+
+    @keyword_only
+    def setParams(
+        self,
+        inputCol: Optional[str] = None,
+        outputCol: Optional[str] = None,
+        recordCol: Optional[str] = None,
+        model: Any = None,
+        genLength: int = 64,
+        blockLength: int = 4,
+        denoisingSteps: int = 4,
+        maskTokenId: Optional[int] = None,
+        batchSize: int = 64,
+    ):
+        given = {k: v for k, v in self._input_kwargs.items() if v is not None}
+        return self._set(**given)
+
+    def _transform(self, dataset):
+        from sparkdl_tpu.obs.trace import tracer
+        from sparkdl_tpu.utils.metrics import metrics
+
+        input_col, output_col = self.getInputCol(), self.getOutputCol()
+        record_col = (self.getOrDefault(self.recordCol)
+                      if self.isDefined(self.recordCol) else None)
+        model = self.getOrDefault(self.model)
+        gen = self.getOrDefault(self.genLength)
+        block = self.getOrDefault(self.blockLength)
+        steps = self.getOrDefault(self.denoisingSteps)
+        rows = self.getOrDefault(self.batchSize)
+        if not self.isDefined(self.maskTokenId):
+            raise ValueError("maskTokenId is required: the model's [MASK] id")
+        mask_id = self.getOrDefault(self.maskTokenId)
+        if not 1 <= steps <= block:
+            raise ValueError(
+                f"denoisingSteps={steps} must lie in 1..blockLength={block}: "
+                "a step fixes at least one position")
+        runner = _runner(model, block, steps, mask_id)
+
+        def process_partition(part):
+            prompts = part[input_col]
+            out = dict(part)
+            if not prompts:
+                out[output_col] = []
+                if record_col:
+                    out[record_col] = []
+                return out
+            bounds = range(0, len(prompts), rows)
+            with tracer.boundary(
+                "generate.partition", rows=len(prompts), batches=len(bounds),
+                prompt_tokens=int(sum(len(p) for p in prompts)),
+                generated_tokens=len(prompts) * gen,
+            ):
+                tokens: List[np.ndarray] = []
+                records: List[np.ndarray] = []
+                for lo in bounds:
+                    got = _generate_batch(
+                        runner, prompts[lo:lo + rows], rows, gen)
+                    tokens.extend(got[0])
+                    records.extend(got[1])
+            metrics.counter("sparkdl.rows_processed").add(len(prompts))
+            out[output_col] = tokens
+            if record_col:
+                out[record_col] = records
+            return out
+
+        return dataset.mapPartitions(process_partition)
+
+
+def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
+    """(tokens [gen] a prompt, record [positions, 3] a prompt), in order."""
+    from sparkdl_tpu.engine import DispatchWindow
+    from sparkdl_tpu.obs.trace import tracer
+    from sparkdl_tpu.utils.metrics import metrics
+
+    block, steps = runner.block, runner.steps
+    with tracer.boundary("generate.plan", rows=len(prompts)) as span:
+        plan = BatchPlan(prompts, rows, block, gen)
+        span.set_attribute("chunks", len(plan.chunks))
+        span.set_attribute("blocks", plan.blocks)
+        span.set_attribute("span", plan.span)
+    host = (plan.tokens, plan.whole, plan.first, plan.known,
+            np.zeros_like(plan.first), np.zeros_like(plan.known),
+            np.array([plan.longest, plan.longest], np.int32))
+    with tracer.boundary("engine.place", bytes=sum(a.nbytes for a in host)):
+        tokens, whole, first, known, later, unknown, where = (
+            runner.place(a) for a in host)
+    cache_k, cache_v = runner.cache(rows, plan.span)
+    window = DispatchWindow(depth=0 if _serial_inference() else None)
+    fetched: List[Any] = []
+
+    def landed(pairs):
+        for result, (kind, routed_tokens) in pairs:
+            _count_routing(result[-1], routed_tokens,
+                           runner.model.experts_per_token)
+            if kind == "block":
+                fetched.append(result)
+
+    try:
+        with tracer.boundary("generate.prefill", tokens=plan.prefilled,
+                             chunks=len(plan.chunks)):
+            for first_row, count, length in plan.chunks:
+                cache_k, cache_v, counts = runner.prefill(
+                    cache_k, cache_v, tokens, whole, first_row, count, length)
+                landed(window.submit(
+                    (counts,), meta=("prefill", count * length)))
+        start = whole
+        fixed = [plan.fixed_in_block(index) for index in range(plan.blocks)]
+        for index in range(plan.blocks):
+            with tracer.boundary(
+                "generate.block", index=index, denoise_forwards=steps,
+                commit_forwards=1, fixed=fixed[index],
+            ):
+                cache_k, cache_v, start, where, record = runner.block_step(
+                    cache_k, cache_v, whole, start, where,
+                    first if index == 0 else later,
+                    known if index == 0 else unknown)
+            landed(window.submit(
+                record, meta=("block", (steps + 1) * rows * block)))
+        landed(window.drain())
+    finally:
+        window.abandon()
+    runner.keep(cache_k, cache_v)
+
+    with tracer.boundary("generate.postprocess", rows=len(prompts)):
+        # [rows, blocks * B] in the plan's order, then back to the input's
+        record = np.stack([
+            np.concatenate([np.asarray(r[i], np.float64) for r in fetched], 1)
+            for i in range(3)
+        ], axis=-1)
+        back = np.empty(rows, np.int64)
+        back[plan.order] = np.arange(rows)
+        record = record[back[:len(prompts)]]
+        tokens_out, records_out = [], []
+        for row in range(len(prompts)):
+            rest = int(plan.rest[row])
+            kept = record[row, :int(plan.blocks_of_row[row]) * block]
+            tokens_out.append(kept[rest:rest + gen, 0].astype(np.int32))
+            records_out.append(kept)
+    needed = int(plan.blocks_of_row.sum())
+    metrics.counter("generate.denoise_forwards").add(needed * steps)
+    metrics.counter("generate.commit_forwards").add(needed)
+    metrics.counter("generate.tokens_fixed").add(sum(fixed))
+    return tokens_out, records_out
+
+
+def _count_routing(counts, tokens: int, per_token: int) -> None:
+    """``counts`` [L, E]: the (token, expert) pairs each expert of each
+    layer got in one program's forwards, through each layer of which
+    ``tokens`` tokens went with ``per_token`` experts each."""
+    from sparkdl_tpu.utils.metrics import metrics
+
+    counts = np.asarray(counts)
+    routed = int(counts.sum())
+    metrics.counter("moe.tokens_routed").add(routed)
+    metrics.counter("moe.tokens_dropped").add(
+        tokens * counts.shape[0] * per_token - routed)
+    metrics.counter("moe.expert_load_max").add(float(counts.max()))
+    metrics.counter("moe.expert_load_mean").add(float(counts.mean()))

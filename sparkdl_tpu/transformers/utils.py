@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import logging
 import os
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -613,9 +614,11 @@ def run_batched_multi(
     finally:
         window.abandon()
     metrics.counter("sparkdl.rows_processed").add(n)
-    rate = metrics.images_per_sec()
-    if rate:
-        logger.debug("run_batched: %d rows, %.1f rows/sec sustained", n, rate)
+    if logger.isEnabledFor(logging.DEBUG):
+        # rows of every kind (not only images), over the whole process
+        logger.debug(
+            "run_batched: %d rows; %.1f rows/sec sustained by the process",
+            n, metrics.images_per_sec() or 0.0)
     assert collected is not None
     return tuple(np.concatenate(acc, axis=0) for acc in collected)
 
@@ -828,6 +831,33 @@ def place_params(params, device=None):
             return jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
         device = jax.devices()[0]
     return jax.device_put(params, device)
+
+
+_PLACED_ONCE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def place_params_once(owner, params, device=None):
+    """:func:`place_params`, once per ``owner`` (the model object that holds
+    ``params``): the placed pytree is remembered with the owner, so a second
+    ``transform`` of the same model sends nothing — gigabytes of weights are
+    program ARGUMENTS and must not cross again on every pass.  Leaves that
+    are device arrays already are not sent by ``device_put`` either.  The
+    ``engine.place_params`` span's ``bytes`` counts what really crossed: the
+    host leaves."""
+    from sparkdl_tpu.obs.trace import tracer
+
+    held = _PLACED_ONCE.get(owner)
+    if held is not None and held[0] is params and held[1] == device:
+        return held[2]
+    sent = sum(
+        int(np.asarray(leaf).nbytes)
+        for leaf in jax.tree_util.tree_leaves(params)
+        if not isinstance(leaf, jax.Array)
+    )
+    with tracer.boundary("engine.place_params", bytes=sent):
+        placed = place_params(params, device)
+    _PLACED_ONCE[owner] = (params, device, placed)
+    return placed
 
 
 _KERAS_FN_CACHE = LRUCache(8)

@@ -83,6 +83,13 @@ def test_tracing_example():
     assert "request spans coalesced into" in out
 
 
+def test_block_diffusion_example():
+    """Tiny and quick (one process, ~15 s): not marked slow."""
+    out = _run_example("block_diffusion.py", timeout=300)
+    assert "generated 48 tokens for 6 prompts" in out
+    assert out.count("mean confidence") == 6
+
+
 @pytest.mark.slow
 def test_sql_analytics_example():
     out = _run_example("sql_analytics.py")

@@ -1,0 +1,253 @@
+"""SDAR-MoE (``sparkdl_tpu/models/sdar_moe.py``, ``sparkdl_tpu/ops/moe.py``)
+against the plain reference (``chipbench/reference/sdar_moe.py``: float32,
+no cache, one row at a time, a loop over experts) at a tiny size on the CPU.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import sdar_moe as reference
+from sparkdl_tpu.models import sdar_moe
+from sparkdl_tpu.ops.moe import gmm_grouped_dot, moe_ffn, route
+
+CONFIG = dict(
+    vocab_size=256, hidden_size=64, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16, num_experts=8,
+    num_experts_per_tok=2, moe_intermediate_size=32, norm_topk_prob=True,
+    rope_theta=1e6, rms_norm_eps=1e-6,
+)
+CFG = sdar_moe.SdarMoeConfig.from_dict(CONFIG)
+BLOCK, MASK = 4, 255
+
+
+@pytest.fixture(scope="module")
+def params():
+    return reference.make_params(CONFIG, 2**31 + 77, "float32")
+
+
+def _layer(params, index=0):
+    return {k: v[index] for k, v in params["layers"].items()}
+
+
+def _skewed(lp, starved=5):
+    """A router that favours expert 1 and never picks ``starved``."""
+    router = np.array(lp["router"])
+    router[:, 1] += 0.3
+    router[:, starved] = 0.0
+    router[0, starved] = -50.0  # after the norm x[0] decides; see _tokens
+    return dict(lp, router=jnp.asarray(router))
+
+
+def _tokens(n=24, seed=3):
+    x = np.random.default_rng(seed).normal(size=(n, 64)).astype(np.float32)
+    x[:, 0] = np.abs(x[:, 0]) + 1.0  # so that the starved expert's logit is low
+    return jnp.asarray(x)
+
+
+def _experts(lp, lo=0, hi=8):
+    return {k: lp[k][lo:hi] for k in ("w_gate", "w_up", "w_down")}
+
+
+@pytest.mark.parametrize("top_k", [1, 2, 4])
+def test_moe_layer_equals_the_loop_over_experts(params, top_k):
+    lp, x = _skewed(_layer(params)), _tokens()
+    config = dict(CONFIG, num_experts_per_tok=top_k)
+    want = reference.moe(config, lp, x)
+    got, counts = moe_ffn(x, lp["router"], _experts(lp), top_k=top_k)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert int(counts.sum()) == x.shape[0] * top_k  # nothing dropped
+    assert int(counts[5]) == 0 and int(counts[1]) == counts.max()
+    _, ref_counts = reference.route(config, lp, x, None)
+    np.testing.assert_array_equal(counts, ref_counts)
+
+
+def test_router_renormalises_its_top_k_and_breaks_ties_low(params):
+    x = _tokens(6)
+    router = jnp.zeros((64, 8))  # every expert ties
+    weights, experts = route(x, router, 3)
+    np.testing.assert_array_equal(experts, np.tile([0, 1, 2], (6, 1)))
+    np.testing.assert_allclose(weights, 1 / 3, rtol=1e-6)
+    weights, _ = route(x, _layer(params)["router"], 3, norm_topk=False)
+    assert float(weights.sum(-1).max()) < 1.0
+
+
+@pytest.mark.parametrize("cut", [4, 2, 7])
+def test_the_shares_add_up_to_the_uncut_layer(params, cut):
+    """``experts_held`` = (0, cut) and (cut, 8), summed, equal the reference's
+    whole layer: what expert parallelism asks of the layer."""
+    lp, x = _skewed(_layer(params, 1)), _tokens(seed=4)
+    whole = reference.moe(CONFIG, lp, x)
+    parts = []
+    for lo, hi in ((0, cut), (cut, 8)):
+        part, counts = moe_ffn(
+            x, lp["router"], _experts(lp, lo, hi), top_k=2,
+            experts_held=(lo, hi))
+        assert int(counts.sum()) == 2 * x.shape[0]  # routed over ALL experts
+        np.testing.assert_allclose(
+            part, reference.moe(CONFIG, dict(lp, **_experts(lp, lo, hi)), x,
+                                experts_held=(lo, hi)), atol=2e-6)
+        parts.append(part)
+    np.testing.assert_allclose(parts[0] + parts[1], whole, atol=2e-6)
+
+
+def test_a_stack_of_layers_is_used_through_its_index(params):
+    """The scan hands ``moe_ffn`` every layer's experts and an index."""
+    x = _tokens()
+    stack = {k: params["layers"][k] for k in ("w_gate", "w_up", "w_down")}
+    for index in (0, 1):
+        lp = _layer(params, index)
+        want, _ = moe_ffn(x, lp["router"], _experts(lp), top_k=2)
+        got, _ = jax.jit(
+            lambda i: moe_ffn(x, lp["router"], stack, top_k=2, stack_index=i)
+        )(jnp.int32(index))
+        np.testing.assert_allclose(got, want, atol=1e-6)
+    with pytest.raises(ValueError, match="experts_held"):
+        moe_ffn(x, lp["router"], _experts(lp, 0, 4), top_k=2)
+
+
+@pytest.mark.parametrize("rows", [48, 42, 7])
+def test_the_chips_grouped_product_equals_ragged_dot(rows):
+    """The TPU's path (``megablox.gmm``, interpreted here): groups that are
+    empty, rows behind the last group, and a row count no tile divides."""
+    rng = np.random.default_rng(rows)
+    x = jnp.asarray(rng.normal(size=(rows, 64)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(16, 64, 32)), jnp.float32)
+    sizes = np.zeros(16, np.int32)
+    sizes[8:16] = np.array([10, 0, 7, 3, 12, 0, 9, 1]) * rows // 48
+    used = int(sizes.sum())
+    assert used < rows
+    want = jax.lax.ragged_dot(x, w, jnp.asarray(sizes))
+    got = gmm_grouped_dot(x, w, jnp.asarray(sizes), interpret=True)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got[:used], want[:used], atol=1e-5)
+
+
+def _rows(seed=0, n=3, length=16):
+    rng = np.random.default_rng(seed)
+    # one short row: its pads must not be seen; the reference runs on two
+    # distinct lengths only (every eager shape is a compile on the CPU)
+    return (rng.integers(0, MASK, (n, length)).astype(np.int32),
+            np.array([16, 16, 8][:n], np.int32))
+
+
+def test_whole_model_logits_float32(params):
+    tokens, lengths = _rows()
+    got = np.asarray(jax.jit(
+        lambda p, t, l: sdar_moe.forward_logits(p, CFG, t, l, BLOCK)
+    )(params, tokens, lengths))
+    for row, n in enumerate(lengths):
+        want = np.asarray(reference.forward(
+            params, CONFIG, tokens[row, :n], BLOCK))
+        assert np.abs(got[row, :n] - want).max() <= 1e-5
+
+
+def test_whole_model_logits_bfloat16(params):
+    """bfloat16 keeps 8 bits: every rounding is 2**-9 relative, and a
+    logit (spread ~0.17 here) passes through ~20 of them in two layers, so
+    0.02 of the spread holds with room; float8 would read ~0.1."""
+    tokens, lengths = _rows(1)
+    low = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), params)
+    exact = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), low)
+    got = np.asarray(jax.jit(
+        lambda p, t, l: sdar_moe.forward_logits(p, CFG, t, l, BLOCK)
+    )(low, tokens, lengths))
+    for row, n in enumerate(lengths):
+        want = np.asarray(reference.forward(
+            exact, CONFIG, tokens[row, :n], BLOCK))
+        gap = np.abs(got[row, :n] - want).max() / want.std()
+        assert gap < 0.05, gap
+
+
+@pytest.mark.parametrize("rest", [0, 1, 3])
+def test_block_causal_attention_through_the_cache(params, rest):
+    """Prefill of the whole blocks, then one block forward against the
+    cache, give the reference's full no-cache forward at the block."""
+    tokens, _ = _rows(2)
+    whole = np.array([12, 12, 4], np.int32)
+    k, v, _ = sdar_moe.prefill(params, CFG, tokens[:, :12], whole, BLOCK)
+    span, base = 32, 16
+    cache_k = jnp.zeros((2, 3, 2, span, 16)).at[:, :, :, :12].set(k)
+    cache_v = jnp.zeros((2, 3, 2, span, 16)).at[:, :, :, :12].set(v)
+    block = np.full((3, BLOCK), MASK, np.int32)
+    for row in range(3):
+        block[row, :rest] = tokens[row, whole[row]:whole[row] + rest]
+    logits, *_ = sdar_moe._block_forward(
+        params, CFG, cache_k, cache_v, jnp.asarray(whole), jnp.asarray(whole),
+        jnp.array([base, base], jnp.int32), jnp.asarray(block), True)
+    for row in range(3):
+        sequence = list(tokens[row, :whole[row]]) + list(block[row])
+        want = reference.forward(
+            params, CONFIG, sequence, BLOCK,
+            range(whole[row], whole[row] + BLOCK))
+        np.testing.assert_allclose(logits[row], want, atol=1e-5)
+
+
+def test_a_committed_block_is_seen_by_the_next(params):
+    tokens, _ = _rows(5)
+    whole = jnp.array([8, 8, 8], jnp.int32)
+    k, v, _ = sdar_moe.prefill(params, CFG, tokens[:, :8], whole, BLOCK)
+    cache_k = jnp.zeros((2, 3, 2, 24, 16)).at[:, :, :, :8].set(k)
+    cache_v = jnp.zeros((2, 3, 2, 24, 16)).at[:, :, :, :8].set(v)
+    where = jnp.array([8, 8], jnp.int32)
+    unknown = jnp.zeros((3, BLOCK), bool)
+    first = sdar_moe.block_step(
+        params, CFG, cache_k, cache_v, whole, whole, where,
+        jnp.zeros((3, BLOCK), jnp.int32), unknown, steps=2, mask_id=MASK)
+    cache_k, cache_v, start, where, (fixed, at, _, counts) = first
+    assert (np.asarray(at) >= 0).all() and not (np.asarray(fixed) == MASK).any()
+    assert int(counts.sum()) == 3 * 2 * 3 * BLOCK * 2  # forwards x layers x pairs
+    np.testing.assert_array_equal(where, [8, 12])
+    probe = jnp.full((3, BLOCK), MASK, jnp.int32)
+    logits, *_ = sdar_moe._block_forward(
+        params, CFG, cache_k, cache_v, whole, start, where, probe, True)
+    for row in range(3):
+        n = int(whole[row])
+        sequence = list(tokens[row, :n]) + list(np.asarray(fixed[row])) \
+            + [MASK] * BLOCK
+        want = reference.forward(params, CONFIG, sequence, BLOCK,
+                                 range(n + BLOCK, n + 2 * BLOCK))
+        np.testing.assert_allclose(logits[row], want, atol=1e-5)
+
+
+@pytest.mark.parametrize("steps_left", [1, 2, 3, 4])
+def test_a_denoising_step_fixes_what_the_reference_would(steps_left):
+    rng = np.random.default_rng(steps_left)
+    logits = rng.normal(size=(5, BLOCK, 32)).astype(np.float32)
+    logits[0, 2] = logits[0, 1]  # a tie between positions: the lower wins
+    logits[1, :, MASK % 32] = 50.0  # the mask token is never predicted
+    masked = rng.random((5, BLOCK)) < 0.7
+    masked[0] = True
+    tokens = np.where(masked, MASK % 32, 7).astype(np.int32)
+    new, still, fixed, logprob = sdar_moe.fix_most_confident(
+        jnp.asarray(logits), jnp.asarray(tokens), jnp.asarray(masked),
+        steps_left, MASK % 32)
+    for row in range(5):
+        logp = reference.log_probs(jnp.asarray(logits[row]), MASK % 32)
+        want, want_tokens = reference.choose(logp, list(masked[row]), steps_left)
+        assert sorted(np.flatnonzero(fixed[row])) == want
+        assert [int(new[row, i]) for i in want] == want_tokens
+        np.testing.assert_allclose(
+            np.asarray(logprob[row])[want], logp.max(-1)[want], atol=1e-5)
+        np.testing.assert_array_equal(still[row], masked[row] & ~fixed[row])
+
+
+def test_config_from_the_published_keys_and_param_shapes():
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                        "configs", "sdar_30b_a3b-blockdiffusion.json")
+    with open(path) as fh:
+        published = json.load(fh)
+    cfg = sdar_moe.SdarMoeConfig.from_dict(published)
+    assert (cfg.hidden_size, cfg.num_experts, cfg.held) == (2048, 128, (0, 128))
+    shapes = sdar_moe.param_shapes(cfg)
+    assert shapes == reference.shapes(published)
+    leaves = jax.tree_util.tree_leaves(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    assert sum(int(np.prod(s)) for s in leaves) == 4_361_055_744
+    tiny = sdar_moe.init_params(CFG, 1, jnp.float32)
+    assert jax.tree_util.tree_map(lambda a: a.shape, tiny) == \
+        sdar_moe.param_shapes(CFG)

@@ -223,3 +223,78 @@ def test_dp_train_step_allreduces_gradients_once(
         f"the conv kernel's gradient is all-reduced {kernel_reduces} times:"
         f" {reduced}"
     )
+
+
+# -- SDAR-MoE at its published widths (PR 31) --------------------------------
+
+SDAR_WIDTHS = dict(
+    vocab_size=151936, hidden_size=2048, num_attention_heads=32,
+    num_key_value_heads=4, head_dim=128, num_experts=128,
+    num_experts_per_tok=8, moe_intermediate_size=768,
+)
+
+
+def _sdar_shapes(cfg, one_chip):
+    from sparkdl_tpu.models import sdar_moe
+
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        sdar_moe.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``ops.moe.grouped_dot`` asks the backend which product to use, and
+    here that is the CPU: steer it in the test, as the guide says."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("rows", [8192, 65536, 24],
+                         ids=["block", "prefill", "odd"])
+def test_grouped_expert_product_compiles(
+        rows, one_chip, no_compile_cache, as_on_the_chip):
+    """The grouped matmul at the block step's and the prefill's row counts
+    (and one that no tile divides), over a stack of 6 x 128 experts."""
+    from sparkdl_tpu.ops.moe import grouped_dot
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for k, n in ((2048, 768), (768, 2048)):
+        compiled = jax.jit(grouped_dot).lower(
+            spec((rows, k)), spec((768, k, n)), spec((768,), jnp.int32)
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        _fits(compiled)
+
+
+def test_sdar_block_step_compiles_and_fits(
+        one_chip, no_compile_cache, as_on_the_chip):
+    """The cell's block step (256 rows, 640 slots, 4 + 1 forwards) at the
+    published widths, two layers deep — the layers are scanned, so depth
+    changes the arguments' bytes and not the program — and the bytes of the
+    six-layer cell reckoned from it."""
+    from sparkdl_tpu.models import sdar_moe
+
+    cfg = sdar_moe.SdarMoeConfig(num_hidden_layers=2, **SDAR_WIDTHS)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = spec((2, 256, 4, 640, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda p, ck, cv, prefix, start, where, tokens, known:
+        sdar_moe.block_step(p, cfg, ck, cv, prefix, start, where, tokens,
+                            known, steps=4, mask_id=151669),
+        donate_argnums=(1, 2),
+    ).lower(
+        _sdar_shapes(cfg, one_chip), cache, cache, spec((256,)),
+        spec((256,)), spec((2,)), spec((256, 4)), spec((256, 4), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and text.count("while(") >= 5
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * 2 * 256 * 4 * 640 * 128 * 2  # donated
+    layer = 2 * 623_087_872 + 2 * 256 * 4 * 640 * 128 * 2 * 2  # weights, cache
+    six = m.argument_size_in_bytes + 4 * layer + m.temp_size_in_bytes
+    assert 0.25 * V5E_HBM_BYTES < six < 0.85 * V5E_HBM_BYTES, six
