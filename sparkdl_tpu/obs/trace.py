@@ -478,6 +478,16 @@ class Tracer:
                 annotation.__exit__(None, None, None)
             sp.end()
 
+    def start_boundary(self, name: str, **attributes: Any) -> Span:
+        """A ROOT boundary span that its maker ends by hand
+        (``span.end()``, from any thread), for a stretch no ``with``
+        block can hold: two partitions of one dispatch loop are open at
+        once on one thread.  It is never the current span, so its
+        children name it as ``parent`` explicitly, and it is no profiler
+        annotation (annotations of one thread nest, these overlap);
+        ended, it goes into the ring like any other."""
+        return Span(self, name, None, attributes, boundary=True)
+
     def recent(self) -> List[BoundaryRecord]:
         """A snapshot of the ring: the newest finished boundary spans,
         oldest first by END time (a parent follows its children)."""

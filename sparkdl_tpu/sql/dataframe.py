@@ -1446,9 +1446,34 @@ class DataFrame:
     ) -> "DataFrame":
         """Apply ``fn`` to each partition's column dict → new column dict.
 
-        This is the engine primitive under every model transformer (the
-        TensorFrames ``map_blocks`` analog — SURVEY.md §3.1 hot loop)."""
-        out_parts = [fn(dict(part)) for part in self._partitions]
+        This is the engine primitive under the model transformers (the
+        TensorFrames ``map_blocks`` analog — SURVEY.md §3.1 hot loop):
+        one self-contained call a partition, one after the other."""
+        return self.mapAllPartitions(
+            lambda parts: [fn(part) for part in parts], schema
+        )
+
+    def mapAllPartitions(
+        self,
+        fn: Callable[[List[Partition]], List[Partition]],
+        schema: Optional[StructType] = None,
+    ) -> "DataFrame":
+        """Hand ``fn`` ALL partitions' column dicts at once and take back
+        the output partitions, one for each and in the same order — eager,
+        as :meth:`mapPartitions` is.
+
+        For a stage that keeps a device fed: it sees where one partition
+        ends and the next begins, so it can run ONE pipeline over all of
+        them and start on the next partition's rows while the last
+        results of this one are still on their way back
+        (``transformers.utils.run_batched_partitions``), where
+        ``mapPartitions`` would build and drain a pipeline a partition."""
+        out_parts = list(fn([dict(part) for part in self._partitions]))
+        if len(out_parts) != len(self._partitions):
+            raise ValueError(
+                f"mapAllPartitions: {len(self._partitions)} partitions in, "
+                f"{len(out_parts)} out"
+            )
         if schema is None:
             schema = StructType()
             probe = next((p for p in out_parts if _partition_nrows(p)), None)

@@ -38,7 +38,7 @@ from sparkdl_tpu.transformers.utils import (
     cast_and_resize_on_device,
     make_image_decode_plan,
     place_params,
-    run_batched_rows,
+    run_batched_partitions,
 )
 
 logger = logging.getLogger(__name__)
@@ -269,33 +269,42 @@ class _NamedImageTransformer(Transformer, HasInputCol, HasOutputCol):
         forward, entry = self._build_forward()
         height, width = entry.input_size
 
-        def process_partition(part):
-            rows = part[input_col]
-            out = dict(part)
-            if not rows:
-                out[output_col] = []
-                return out
-            # uniform-size partitions pack at source size — as uint8 when
-            # the rows allow (cast, resize, preprocess and CNN fuse into
-            # the one jitted forward program); mixed-size partitions
-            # resize-while-packing (native bridge when available).
-            # Decode and forward run pipelined (run_batched_rows): chunk
-            # i+1 decodes on a prefetch thread and dispatches before chunk
-            # i's fetch.  The decode plan (shape + dtype) is decided over
-            # the whole partition so exactly one program compiles.
-            # The boundary spans name where a partition's time goes
-            # (obs.trace): run_batched_rows adds ``batches`` and hangs the
-            # pack, wait, place, dispatch and fetch spans under this one.
-            with tracer.boundary(
-                "featurize.partition", rows=len(rows), batch_size=batch_size
-            ):
-                decode = make_image_decode_plan(rows, 3, (height, width))
-                result = run_batched_rows(forward, rows, decode, batch_size)
-                with tracer.boundary("featurize.postprocess", rows=len(rows)):
-                    out[output_col] = self._postprocess(result)
-            return out
+        # Uniform-size partitions pack at source size — as uint8 when the
+        # rows allow (cast, resize, preprocess and CNN fuse into the one
+        # jitted forward program); mixed-size partitions resize-while-
+        # packing (native bridge when available).  The decode plan (shape
+        # + dtype) is decided over a whole partition, so one program
+        # compiles a partition's shape.
+        def plan(rows):
+            return make_image_decode_plan(rows, 3, (height, width))
 
-        return dataset.mapPartitions(process_partition)
+        def process_partitions(parts):
+            # ONE pipeline over all partitions (run_batched_partitions):
+            # chunk i+1 packs on a prefetch thread and dispatches before
+            # chunk i's fetch, across the border between two partitions
+            # too, so a partition's rows are built while the device works
+            # on the next one's first batches.  The boundary spans name
+            # where a partition's time goes (obs.trace): the loop opens a
+            # root ``featurize.partition`` a non-empty partition and hangs
+            # the plan, pack, wait, place, dispatch and fetch spans under
+            # it; ``inflight`` says what the device had to do meanwhile.
+            outs = [{**part, output_col: []} for part in parts]
+
+            def postprocess(done):
+                with tracer.boundary(
+                    "featurize.postprocess", parent=done.span,
+                    rows=len(done.result), inflight=done.inflight,
+                ):
+                    outs[done.index][output_col] = self._postprocess(
+                        done.result)
+
+            run_batched_partitions(
+                forward, [part[input_col] for part in parts], plan,
+                postprocess, batch_size, span_name="featurize.partition",
+            )
+            return outs
+
+        return dataset.mapAllPartitions(process_partitions)
 
 
 class DeepImageFeaturizer(_NamedImageTransformer):

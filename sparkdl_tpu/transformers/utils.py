@@ -18,7 +18,7 @@ rules applied here:
   (SURVEY.md §2 "Data-parallel inference").
 
 The load/decode side of the loop — chunking, background prefetch, clean
-shutdown — is :mod:`sparkdl_tpu.data` (see :func:`run_batched_rows`);
+shutdown — is :mod:`sparkdl_tpu.data` (see :func:`run_batched_partitions`);
 this module owns what happens once a batch is decoded.
 """
 
@@ -26,8 +26,12 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
+import time
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import numpy as np
 
@@ -649,43 +653,91 @@ def _serial_inference() -> bool:
     )
 
 
-def run_batched_rows(
+class FinishedPartition(NamedTuple):
+    """What :func:`run_batched_partitions` hands ``finish`` when the last
+    result of a partition is back on the host."""
+
+    #: the partition's place in the ``partitions`` it came from
+    index: int
+    #: the outputs of its rows, in row order
+    result: np.ndarray
+    #: its boundary span, still open: what ``finish`` does nests under it
+    #: by naming it as ``parent`` (None where the caller had none open)
+    span: Any
+    #: results of LATER partitions dispatched and unfetched now: what the
+    #: device has to do while ``finish`` keeps the dispatching thread
+    inflight: int
+
+
+def run_batched_partitions(
     fn: Callable,
-    rows: Sequence,
-    decode: Callable[[Sequence], np.ndarray],
+    partitions: Sequence[Sequence],
+    plan: Callable[[Sequence], Callable[[Sequence], np.ndarray]],
+    finish: Callable[[FinishedPartition], None],
     batch_size: int = DEFAULT_BATCH_SIZE,
-) -> np.ndarray:
-    """Decode+forward pipeline over row chunks — the serving-path
-    transfer/compute overlap (the reference delegated this to
-    TensorFrames' blocked pipelining; SURVEY.md §2):
+    span_name: Optional[str] = None,
+) -> None:
+    """Decode+forward pipeline over the row chunks of ALL ``partitions``
+    of one call — the serving-path transfer/compute overlap (the reference
+    delegated this to TensorFrames' blocked pipelining; SURVEY.md §2), kept
+    going across the border between two partitions:
 
     - host decode of chunk i+1 runs on a prefetch thread while chunk i is
       on device (the inference analog of the estimator's
-      ``StreamingShardLoader``);
-    - dispatched results ride the engine's depth-N
+      ``StreamingShardLoader``) — straight through a border: the packer
+      turns to the next partition while the last chunks of this one are
+      still on the device;
+    - dispatched results ride ONE depth-N
       :class:`~sparkdl_tpu.engine.DispatchWindow`
       (``SPARKDL_DISPATCH_DEPTH``, default 2): chunk i's device→host copy
       streams asynchronously while chunks i+1..i+N compute, so the fetch
-      finds the bytes already on host.
+      finds the bytes already on host;
+    - when the LAST result of a partition falls out of the window,
+      ``finish(FinishedPartition)`` runs on the dispatching thread — by
+      then N chunks of the next partition are dispatched, so the device
+      works while the caller post-processes.  ``engine.borders`` counts
+      the partitions finished with a non-empty one still to come in the
+      same call, ``engine.borders_fed`` those of them that found
+      ``inflight`` > 0.
 
-    ``decode(chunk_rows) -> np.ndarray`` must be row-aligned with
-    ``rows``.  Chunks are ``batch_size`` rows (mesh-rounded, as in
-    :func:`run_batched_multi`); the ragged final chunk pads by repeating
-    its last row, so exactly one batch shape is ever compiled per decode
-    shape.  ``SPARKDL_SERIAL_INFERENCE=1`` disables both overlaps.
+    A partition border is metadata: a chunk never spans two partitions.
+    ``plan(rows) -> decode`` is called once a non-empty partition, when
+    the packer reaches it (the decode policy — dtype, packed shape — is
+    decided over the partition's rows), and ``decode(chunk_rows) ->
+    np.ndarray`` must be row-aligned.  Chunks are ``batch_size`` rows
+    (mesh-rounded, as in :func:`run_batched_multi`); a partition's ragged
+    final chunk pads by repeating its last row, so exactly one batch shape
+    is ever compiled per decode shape, and every batch holds the rows it
+    would hold were the partitions run one call each.  Empty partitions
+    are passed over (no ``finish``).  Partitions finish in order.
+
+    Host memory: at most 2 packed chunks ahead, N results in flight and
+    the fetched results of the one partition not yet finished.
+    ``SPARKDL_SERIAL_INFERENCE=1`` disables both overlaps (no prefetch
+    thread, depth 0).  An error — in ``plan``, ``decode``, ``fn`` or
+    ``finish`` — raises out of the call with the window abandoned and the
+    prefetch thread joined.
 
     The load/decode prefix is a :mod:`sparkdl_tpu.data` pipeline
-    (``from_items(chunk bounds) → map(decode) → prefetch(2)``), so the
+    (``from_items(chunk indexes) → map(pack) → prefetch(2)``), so the
     background decode thread follows the package's clean-shutdown protocol
     and feeds the ``data.*`` metrics.
+
+    Spans (:mod:`sparkdl_tpu.obs.trace`): with ``span_name`` every
+    non-empty partition gets a ROOT boundary span of that name (``rows``,
+    ``batch_size``, ``batches``) from the moment either thread turns to it
+    until its ``finish`` returns — two of them overlap at a border — and
+    ``data.pack``, ``engine.load_wait``, ``engine.place``,
+    ``engine.dispatch`` and ``engine.fetch_wait`` are its children by
+    explicit parent.  Without, they hang under the caller's current span,
+    which gets ``batches``.
     """
+    from sparkdl_tpu.engine import DispatchWindow
     from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
     from sparkdl_tpu.utils.profiler import maybe_trace
 
-    n = len(rows)
-    if n == 0:
-        raise ValueError("run_batched_rows requires non-empty rows")
+    asked_batch_size = batch_size
     mesh = data_parallel_mesh()
     if mesh is not None:
         n_dev = int(mesh.devices.size)
@@ -698,49 +750,113 @@ def run_batched_rows(
     else:
         _place = jnp.asarray
 
+    # every chunk of the call, in the order it is packed, dispatched and
+    # fetched: (partition, lo, hi)
+    chunks = [
+        (p, lo, min(lo + batch_size, len(rows)))
+        for p, rows in enumerate(partitions)
+        for lo in range(0, len(rows), batch_size)
+    ]
+    if not chunks:
+        return
+    last_chunk = {p: i for i, (p, _, _) in enumerate(chunks)}
     serial = _serial_inference()
-    bounds = [(lo, min(lo + batch_size, n)) for lo in range(0, n, batch_size)]
 
-    # the caller's span (``featurize.partition``): the packs run on the
-    # prefetch thread, which inherits no context, so they take their
-    # parent from here (``tracer.capture()`` is None while tracing is
-    # off); a serial pack nests in the wait that runs it
-    partition = tracer.current()
-    if partition is not None:
-        partition.set_attribute("batches", len(bounds))
+    # The packs run on the prefetch thread, which inherits no context, and
+    # two partitions are open at once on the dispatching thread: every
+    # span takes its partition's span as parent explicitly.  Whichever
+    # thread turns to a partition first opens its span (the packer, a few
+    # chunks ahead, but for the first).
+    ambient = tracer.current()
+    if span_name is None and ambient is not None:
+        ambient.set_attribute("batches", len(chunks))
+    spans: Dict[int, Any] = {}
+    spans_lock = threading.Lock()
 
-    def decode_chunk(lo, hi):
-        with tracer.boundary(
-            "data.pack", parent=tracer.current() or partition
-        ) as span:
-            batch = decode(rows[lo:hi])
+    def span_of(p):
+        if span_name is None:
+            return ambient
+        with spans_lock:
+            span = spans.get(p)
+            if span is None:
+                n = len(partitions[p])
+                span = spans[p] = tracer.start_boundary(
+                    span_name, rows=n, batch_size=asked_batch_size,
+                    batches=-(-n // batch_size))
+            return span
+
+    decodes: Dict[int, Callable] = {}
+
+    def pack(i):
+        p, lo, hi = chunks[i]
+        # a serial pack (and plan) nests in the wait that runs it
+        parent = tracer.current() if serial else span_of(p)
+        if lo == 0:
+            with tracer.use_span(parent):
+                decodes[p] = plan(partitions[p])
+        with tracer.boundary("data.pack", parent=parent) as span:
+            batch = decodes[p](partitions[p][lo:hi])
             k = batch.shape[0]
             batch = pad_to_batch(batch, batch_size)
             span.set_attribute("rows", k)
             span.set_attribute("padded_rows", batch.shape[0])
             span.set_attribute("bytes", batch.nbytes)
-        return batch, k
+        if i == last_chunk[p]:
+            del decodes[p]
+        return batch
 
     if serial:
-        chunk_iter = (decode_chunk(lo, hi) for lo, hi in bounds)
+        packed = (pack(i) for i in range(len(chunks)))
     else:
         # prefetch(2) bounds host memory at ~2 extra decoded chunks; the
         # pipeline's close protocol (cancel -> drain -> join) means a
         # failed call can't leak the decode thread plus its chunks
         from sparkdl_tpu.data import Dataset
 
-        chunk_iter = iter(
-            Dataset.from_items(bounds, name="chunk_bounds")
-            .map(lambda b: decode_chunk(*b))
+        packed = iter(
+            Dataset.from_items(range(len(chunks)), name="chunk_indexes")
+            .map(pack)
             .prefetch(2)
         )
 
-    from sparkdl_tpu.engine import DispatchWindow
-
     # (images_processed is advanced by the decode layer — e.g.
     # decode_image_batch — not here, to avoid double counting)
-    collected: List[np.ndarray] = []
     window = DispatchWindow(depth=0 if serial else None)
+    collected: List[np.ndarray] = []  # of the one partition not yet finished
+    fetched = 0  # results come back in order: the next is chunks[fetched]'s
+    away_s = 0.0  # spent in ``finish``: the caller's time, not the loop's
+    borders = metrics.counter("engine.borders")
+    borders_fed = metrics.counter("engine.borders_fed")
+
+    def oldest():
+        """The span of the partition whose result leaves the window next
+        (the chunk about to be submitted's, when the window is empty): a
+        fetch waits, and a starved stretch ends, under that one."""
+        return span_of(chunks[fetched][0])
+
+    def take(host):
+        nonlocal fetched, away_s
+        p, lo, hi = chunks[fetched]
+        collected.append(host[: hi - lo])
+        fetched += 1
+        if fetched <= last_chunk[p]:
+            return
+        result = np.concatenate(collected, axis=0)
+        collected.clear()
+        inflight = len(window)
+        if fetched < len(chunks):  # a border: a partition is still to come
+            borders.add(1)
+            if inflight:
+                borders_fed.add(1)
+        span = span_of(p)
+        began = time.perf_counter()
+        try:
+            finish(FinishedPartition(p, result, span, inflight))
+        finally:
+            away_s += time.perf_counter() - began
+            if span_name is not None:
+                span.end()
+
     # 'sparkdl.forward' is the HOST's time in place + dispatch + blocking
     # on fetches — not the device's: the device works on while the host
     # packs, and waits while the host is here placing.  The engine.*
@@ -748,24 +864,25 @@ def run_batched_rows(
     # decode in serial mode, queue wait in pipelined mode) advances
     # 'sparkdl.load' inside the decode closure, so timing the whole loop
     # would double-count load under forward.  The whole loop — load waits
-    # included — runs under 'sparkdl.serve', the sustained end-to-end
-    # rate images_per_sec() reports.
-    serve_timer = metrics.timer("sparkdl.serve")
+    # included, ``finish`` left out — runs under 'sparkdl.serve', the
+    # sustained end-to-end rate images_per_sec() reports.
     forward_timer = metrics.timer("sparkdl.forward")
     program = _program_name(fn)
-    done = object()
+    started = time.perf_counter()
     try:
-        with maybe_trace(), serve_timer.time():
-            while True:
-                with tracer.boundary("engine.load_wait"):
-                    chunk = next(chunk_iter, done)
-                if chunk is done:
-                    break
-                batch, k = chunk
+        with maybe_trace():
+            for p, _, _ in chunks:
+                span = span_of(p)
+                with tracer.boundary("engine.load_wait", parent=span):
+                    batch = next(packed)
                 with forward_timer.time():
-                    with tracer.boundary("engine.place", bytes=batch.nbytes):
+                    with tracer.boundary(
+                        "engine.place", parent=span, bytes=batch.nbytes
+                    ):
                         placed = _place(batch)
-                    with tracer.boundary("engine.dispatch", program=program):
+                    with tracer.boundary(
+                        "engine.dispatch", parent=span, program=program
+                    ):
                         result = fn(placed)  # async dispatch
                     if isinstance(result, (tuple, list)):
                         raise TypeError(
@@ -774,18 +891,49 @@ def run_batched_rows(
                             "output in the forward, or use "
                             "run_batched_multi"
                         )
-                    for host, k_done in window.submit(result, meta=k):
-                        collected.append(host[:k_done])
-            with forward_timer.time():
-                for host, k_done in window.drain():
-                    collected.append(host[:k_done])
+                    with tracer.use_span(oldest()):
+                        fell_out = window.submit(result)
+                for host, _ in fell_out:
+                    take(host)
+            # the wait that finds the end (and the prefetch thread gone)
+            with tracer.boundary("engine.load_wait", parent=span):
+                next(packed, None)
+            in_order = window.drain()
+            while len(window):
+                with forward_timer.time(), tracer.use_span(oldest()):
+                    host, _ = next(in_order)
+                take(host)
     finally:
         window.abandon()
-        close = getattr(chunk_iter, "close", None)
+        close = getattr(packed, "close", None)
         if close is not None:
             close()
-    metrics.counter("sparkdl.rows_processed").add(n)
-    return np.concatenate(collected, axis=0)
+        for span in spans.values():  # an error left them open
+            span.end()
+        metrics.timer("sparkdl.serve").add_seconds(
+            time.perf_counter() - started - away_s)
+    metrics.counter("sparkdl.rows_processed").add(
+        sum(len(rows) for rows in partitions))
+
+
+def run_batched_rows(
+    fn: Callable,
+    rows: Sequence,
+    decode: Callable[[Sequence], np.ndarray],
+    batch_size: int = DEFAULT_BATCH_SIZE,
+) -> np.ndarray:
+    """:func:`run_batched_partitions` over ONE partition, for the stages
+    that still take a partition at a time: ``decode(chunk_rows) ->
+    np.ndarray`` is the partition's decode plan, made by the caller; the
+    outputs of all ``rows`` come back as one array.  The loop's spans hang
+    under the caller's current span."""
+    if len(rows) == 0:
+        raise ValueError("run_batched_rows requires non-empty rows")
+    out: List[np.ndarray] = []
+    run_batched_partitions(
+        fn, [rows], lambda _: decode, lambda done: out.append(done.result),
+        batch_size)
+    return out[0]
 
 
 def normalize_channels(img: np.ndarray, n_channels: int) -> np.ndarray:

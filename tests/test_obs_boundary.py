@@ -304,6 +304,205 @@ def test_place_bytes_are_exact_for_a_padded_last_batch():
     assert partition.attributes == {"rows": 20, "batches": 3}
 
 
+def test_start_boundary_is_a_root_ended_by_hand_and_never_current():
+    own = Tracer()
+    with own.boundary("outer") as outer:
+        first = own.start_boundary("partition", rows=3)
+        second = own.start_boundary("partition", rows=4)  # both open at once
+        assert own.current() is outer
+        with own.boundary("child", parent=first):
+            pass
+        first.end()
+        second.end()
+        second.end()  # idempotent
+    recs = own.recent()
+    assert [r.name for r in recs] == ["child", "partition", "partition", "outer"]
+    assert [r.parent_id for r in recs] == [first.span_id, None, None, None]
+    assert [r.attributes for r in recs[1:3]] == [{"rows": 3}, {"rows": 4}]
+
+
+@pytest.fixture
+def three_partition_transform(monkeypatch):
+    """A ``DeepImageFeaturizer.transform`` over partitions of 20, 7 and 12
+    uint8 rows in batches of 8, with a small program in the model's place:
+    ``(rows, transform)``."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.image import imageIO
+    from sparkdl_tpu.sql.session import TPUSession
+    from sparkdl_tpu.transformers.named_image import DeepImageFeaturizer
+    from sparkdl_tpu.transformers.utils import cast_and_resize_on_device
+
+    monkeypatch.setattr(executor, "_outstanding", executor._Outstanding())
+    rng = np.random.RandomState(1)
+    sizes = [20, 7, 12]
+    images = [
+        imageIO.imageArrayToStruct(
+            rng.randint(0, 255, (12, 12, 3)).astype(np.uint8), origin=f"o{i}")
+        for i in range(sum(sizes))
+    ]
+    session = TPUSession.builder.master("local[*]").getOrCreate()
+    df = session.createDataFrame([(im,) for im in images], ["image"],
+                                 numPartitions=1)
+    parts, lo = [], 0
+    for n in sizes:
+        parts.append({"image": images[lo:lo + n]})
+        lo += n
+    df = df._with_partitions(parts)
+
+    @jax.jit
+    def forward(x):
+        x = cast_and_resize_on_device(x, (12, 12))
+        return jnp.tanh(x / 255.0).mean(axis=(1, 2))
+
+    featurizer = DeepImageFeaturizer(
+        inputCol="image", outputCol="features", modelName="InceptionV3",
+        batchSize=8)
+    monkeypatch.setattr(
+        featurizer, "_build_forward",
+        lambda: (forward, types.SimpleNamespace(input_size=(12, 12))))
+    want = np.asarray(forward(np.stack(
+        [imageIO.imageStructToArray(im) for im in images])))
+    return sizes, want, lambda: featurizer.transform(df)
+
+
+def test_the_device_is_fed_across_partition_borders(three_partition_transform):
+    """The mechanism, from the ring: one window over all partitions of a
+    ``transform``, so the next partition's first batches are dispatched
+    before this one's rows are built."""
+    from sparkdl_tpu.utils.metrics import metrics
+
+    sizes, want, transform = three_partition_transform
+    depth = executor.dispatch_depth()
+    assert depth == 2
+    borders = metrics.counter("engine.borders").value
+    fed = metrics.counter("engine.borders_fed").value
+    mark = tracer.clock_ns()
+    out = transform()
+    recs = since(mark)
+    assert [len(p["features"]) for p in out._partitions] == sizes
+    got = np.stack([np.asarray(r.features.toArray()) for r in out.collect()])
+    np.testing.assert_array_equal(got, want.astype(np.float64))
+
+    partitions = sorted((r for r in recs if r.name == "featurize.partition"),
+                        key=lambda r: r.start_ns)
+    assert [r.attributes for r in partitions] == [
+        {"rows": n, "batch_size": 8, "batches": -(-n // 8)} for n in sizes]
+    assert all(r.parent_id is None for r in partitions)
+    ids = [r.span_id for r in partitions]
+
+    def children(name, partition):
+        return sorted((r for r in recs
+                       if r.name == name and r.parent_id == ids[partition]),
+                      key=lambda r: r.start_ns)
+
+    for name, count in [("featurize.plan", [1, 1, 1]),
+                        ("data.pack", [3, 1, 2]),
+                        ("engine.load_wait", [3, 1, 3]),  # the last finds the end
+                        ("engine.place", [3, 1, 2]),
+                        ("engine.dispatch", [3, 1, 2]),
+                        ("engine.fetch_wait", [3, 1, 2]),
+                        ("featurize.postprocess", [1, 1, 1])]:
+        assert [len(children(name, p)) for p in range(3)] == count, name
+        # and none of them anywhere else: not a root, not another's child
+        assert sum(count) == len([r for r in recs if r.name == name]), name
+    post = [children("featurize.postprocess", p)[0] for p in range(3)]
+    assert [r.attributes for r in post] == [
+        {"rows": 20, "inflight": depth},  # one batch of each later partition
+        {"rows": 7, "inflight": depth},
+        {"rows": 12, "inflight": 0}]
+    for p in range(2):
+        first_dispatch_of_next = children("engine.dispatch", p + 1)[0]
+        assert first_dispatch_of_next.start_ns < post[p].start_ns
+        assert partitions[p + 1].start_ns < partitions[p].end_ns  # overlap
+    assert metrics.counter("engine.borders").value - borders == 2
+    assert metrics.counter("engine.borders_fed").value - fed == 2
+    dispatches = [r for r in recs if r.name == "engine.dispatch"]
+    fetches = [r for r in recs if r.name == "engine.fetch_wait"]
+    first, last = (min(r.start_ns for r in dispatches),
+                   max(r.end_ns for r in fetches))
+    assert not [r for r in starved(mark)
+                if r.end_ns > first and r.start_ns < last]
+
+
+def test_long_partitions_find_the_window_full_at_their_border(monkeypatch):
+    """``inflight`` reads ``depth`` wherever the next partition has that
+    many batches, and the benchmark's readers count every row once."""
+    import os
+
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.transformers.utils import run_batched_partitions
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from chipbench.readers import span_self_time
+
+    monkeypatch.setattr(executor, "_outstanding", executor._Outstanding())
+    depth = executor.dispatch_depth()
+    data = np.arange(200, dtype=np.float32).reshape(100, 2)
+    partitions = [list(range(0, 30)), list(range(30, 64)), list(range(64, 100))]
+    seen = []
+
+    def finish(done):
+        with tracer.boundary("featurize.postprocess", parent=done.span,
+                             rows=len(done.result), inflight=done.inflight):
+            seen.append((done.index, done.inflight, done.result))
+
+    with tracer.boundary("sql.collect", rows=0):
+        pass  # the ring reaches back beyond the window's start
+    mark = tracer.clock_ns()
+    run_batched_partitions(
+        lambda x: jnp.asarray(x) + 1.0, partitions,
+        lambda rows: (lambda chunk: data[np.asarray(chunk)]), finish, 8,
+        span_name="featurize.partition")
+    wall_s = (max(r.end_ns for r in tracer.recent()) - mark) / 1e9
+    assert [(p, inflight) for p, inflight, _ in seen] == [
+        (0, depth), (1, depth), (2, 0)]
+    np.testing.assert_array_equal(
+        np.concatenate([result for _, _, result in seen]), data + 1.0)
+    roots, lo, hi = span_self_time.cover(tracer.recent(), wall_s)
+    assert [r.name for r in roots] == ["featurize.partition"] * 3
+    assert span_self_time.units(
+        roots, {"span": "featurize.partition", "attr": "rows"}) == 100
+    # self times as the readers take them: every child counted once
+    waits = [r for r in since(mark) if r.name == "engine.load_wait"]
+    assert span_self_time.self_ns(
+        tracer.recent(), {"engine.load_wait"}, lo, hi) == sum(
+            r.end_ns - r.start_ns for r in waits)
+
+
+def test_serial_inference_keeps_its_meaning_over_partitions(
+        three_partition_transform, monkeypatch):
+    """Depth 0 and no prefetch thread: every pack and plan nests in the
+    wait that runs it, on the dispatching thread, and no border is fed."""
+    from sparkdl_tpu.utils.metrics import metrics
+
+    sizes, want, transform = three_partition_transform
+    monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
+    fed = metrics.counter("engine.borders_fed").value
+    mark = tracer.clock_ns()
+    out = transform()
+    recs = since(mark)
+    got = np.stack([np.asarray(r.features.toArray()) for r in out.collect()])
+    np.testing.assert_array_equal(got, want.astype(np.float64))
+    assert {r.thread_id for r in recs} == {threading.get_ident()}
+    waits = {r.span_id for r in recs if r.name == "engine.load_wait"}
+    assert all(r.parent_id in waits for r in recs
+               if r.name in ("data.pack", "featurize.plan"))
+    assert [r.attributes["inflight"] for r in recs
+            if r.name == "featurize.postprocess"] == [0, 0, 0]
+    assert metrics.counter("engine.borders_fed").value == fed
+    partitions = sorted((r for r in recs if r.name == "featurize.partition"),
+                        key=lambda r: r.start_ns)
+    assert [r.attributes["rows"] for r in partitions] == sizes
+    for a, b in zip(partitions, partitions[1:]):
+        assert a.end_ns <= b.start_ns  # one after the other
+
+
 # ----------------------------------------------------------------------
 # the spans of readImages, whose loops run on a pool of threads
 # ----------------------------------------------------------------------

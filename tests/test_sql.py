@@ -73,6 +73,33 @@ def test_map_partitions(df):
     assert sum(r.sum for r in out.collect()) == sum(range(10))
 
 
+def test_map_all_partitions_sees_every_border_and_keeps_the_partitioning(df):
+    seen = []
+
+    def fn(parts):
+        seen.append([list(p["id"]) for p in parts])
+        # a stage may look across the border: each row gets the length of
+        # the partition AFTER its own
+        sizes = [len(p["id"]) for p in parts] + [0]
+        return [{"id": p["id"], "next": [sizes[i + 1]] * len(p["id"])}
+                for i, p in enumerate(parts)]
+
+    parted = df.repartition(3)
+    out = parted.mapAllPartitions(fn)
+    assert seen == [[[0, 1, 2], [3, 4, 5], [6, 7, 8, 9]]]  # one call, eager
+    assert [list(p["id"]) for p in out._partitions] == seen[0]
+    assert [(r.id, r.next) for r in out.collect()] == [
+        (i, 3 if i < 3 else 4 if i < 6 else 0) for i in range(10)]
+    # and what mapPartitions gives, it gives
+    per_partition = parted.mapPartitions(lambda p: {"id": p["id"]})
+    together = parted.mapAllPartitions(
+        lambda parts: [{"id": p["id"]} for p in parts])
+    assert together.schema == per_partition.schema
+    assert together.collect() == per_partition.collect()
+    with pytest.raises(ValueError, match="3 partitions in, 1 out"):
+        parted.mapAllPartitions(lambda parts: parts[:1])
+
+
 def test_map_in_arrow(df):
     import pyarrow as pa
 

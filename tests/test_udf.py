@@ -278,6 +278,121 @@ class TestServingPipeline:
                 lambda x: jnp.asarray(x), list(range(8)), decode, 4
             )
 
+    @pytest.mark.parametrize("case", [
+        "unequal-ragged", "empty-between", "two-plans", "serial"])
+    def test_one_pipeline_over_partitions_equals_a_call_a_partition(
+        self, case, monkeypatch
+    ):
+        """run_batched_partitions over all partitions gives, bit for bit,
+        what one run_batched_rows call a partition gives: a border is
+        metadata, every batch holds the rows it held."""
+        import jax
+        import jax.numpy as jnp
+
+        from sparkdl_tpu.transformers.utils import (
+            cast_and_resize_on_device,
+            make_image_decode_plan,
+            run_batched_partitions,
+            run_batched_rows,
+        )
+
+        rng = np.random.RandomState(3)
+
+        def images(n, sizes, dtype=np.uint8):
+            out = []
+            for i in range(n):
+                h, w = sizes[i % len(sizes)]
+                arr = rng.randint(0, 255, (h, w, 3)).astype(dtype)
+                out.append(imageIO.imageArrayToStruct(arr, origin=f"i{i}"))
+            return out
+
+        if case == "two-plans":
+            # a uniform uint8 partition, then a mixed-size one that packs
+            # float32 at the target size: two plans, two program shapes
+            partitions = [images(13, [(20, 16)]),
+                          images(11, [(12, 18), (30, 22), (16, 16)])]
+        elif case == "empty-between":
+            partitions = [images(16, [(16, 16)]), [], images(5, [(16, 16)])]
+        else:
+            partitions = [images(19, [(16, 16)]), images(3, [(16, 16)]),
+                          images(9, [(16, 16)])]
+        if case == "serial":
+            monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
+        shapes = []
+
+        @jax.jit
+        def fn(x):
+            shapes.append((x.dtype.name, x.shape))  # once a traced shape
+            x = cast_and_resize_on_device(x, (16, 16))
+            return jnp.tanh(x / 255.0).mean(axis=1).reshape(x.shape[0], -1)
+
+        def plan(rows):
+            return make_image_decode_plan(rows, 3, (16, 16))
+
+        want = [
+            run_batched_rows(fn, rows, plan(rows), 8) if rows else None
+            for rows in partitions
+        ]
+        traced = list(shapes)
+        got = {}
+        run_batched_partitions(
+            fn, partitions, plan,
+            lambda done: got.__setitem__(done.index, done.result), 8)
+        assert sorted(got) == [p for p, rows in enumerate(partitions) if rows]
+        for p, result in got.items():
+            assert result.dtype == want[p].dtype
+            np.testing.assert_array_equal(result, want[p])
+            assert len(result) == len(partitions[p])
+        assert shapes == traced  # the same programs: nothing new compiled
+        if case == "two-plans":
+            assert traced == [("uint8", (8, 20, 16, 3)),
+                              ("float32", (8, 16, 16, 3))]
+
+    @pytest.mark.parametrize("where", ["decode", "plan", "finish"])
+    def test_an_error_in_the_second_partition_leaves_nothing_behind(
+        self, where
+    ):
+        """Eager semantics: the error raises out of the call, the prefetch
+        thread is joined and no result stays dispatched and unfetched."""
+        import threading
+
+        import jax.numpy as jnp
+
+        from sparkdl_tpu.engine import executor
+        from sparkdl_tpu.transformers.utils import run_batched_partitions
+
+        data = np.arange(80, dtype=np.float32).reshape(40, 2)
+        partitions = [list(range(0, 20)), list(range(20, 40))]
+        finished = []
+
+        def plan(rows):
+            if where == "plan" and rows[0] == 20:
+                raise RuntimeError("plan exploded")
+
+            def decode(chunk):
+                if where == "decode" and chunk[0] >= 28:
+                    raise RuntimeError("decode exploded")
+                return data[np.asarray(chunk)]
+
+            return decode
+
+        def finish(done):
+            if where == "finish" and done.index == 0:
+                raise RuntimeError("finish exploded")
+            finished.append(done.index)
+
+        def live():
+            return {t for t in threading.enumerate() if t.is_alive()}
+
+        before = live()
+        outstanding = executor._outstanding._count
+        with pytest.raises(RuntimeError, match=f"{where} exploded"):
+            run_batched_partitions(
+                lambda x: jnp.asarray(x) * 2.0, partitions, plan, finish, 8)
+        assert live() <= before
+        assert executor._outstanding._count == outstanding
+        assert finished in ([], [0]) and (where != "finish" or not finished)
+
     def test_mixed_shape_partition_single_program(
         self, tpu_session, keras_model_file, keras_model, tmp_path
     ):
