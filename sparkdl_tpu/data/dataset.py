@@ -20,7 +20,7 @@ Design rules (tf.data — arxiv 2101.12127 — adapted to this engine):
 
 Consumers: ``estimators/data.py`` (``StreamingShardLoader`` and both
 ``_fit`` loops), the transformer run loop's chunked decode
-(``transformers/utils.run_batched_rows``), and anything user-side that
+(``transformers/utils.run_batched_partitions``), and anything user-side that
 wants a saturated device.
 """
 

@@ -2,9 +2,9 @@
 
 Replaces the hand-rolled queue threads that used to live in
 ``estimators/data.py`` (``StreamingShardLoader``) and
-``transformers/utils.py`` (``run_batched_rows``), both of which spin-polled
-a 0.1 s ``put`` timeout and could drop their ``None`` sentinel when the
-consumer left mid-epoch.  Here the protocol is deadlock-free by
+``transformers/utils.py`` (now ``run_batched_partitions``), both of which
+spin-polled a 0.1 s ``put`` timeout and could drop their ``None`` sentinel
+when the consumer left mid-epoch.  Here the protocol is deadlock-free by
 construction:
 
 - the producer uses plain *blocking* puts and ALWAYS pushes a final

@@ -24,11 +24,7 @@ from sparkdl_tpu.engine.cache import (
     enable_jax_cache,
 )
 from sparkdl_tpu.engine.core import EngineFunction, ExecutionEngine, ProgramHandle
-from sparkdl_tpu.engine.executor import (
-    DispatchWindow,
-    FetchFailure,
-    dispatch_depth,
-)
+from sparkdl_tpu.engine.executor import DispatchWindow, FetchFailure
 from sparkdl_tpu.engine.slots import Slot, SlotPool, slot_block_fingerprint
 
 #: the process-wide engine used by transformers, UDFs, and estimators
@@ -48,7 +44,6 @@ __all__ = [
     "cache_key",
     "compile_cache_root",
     "default_cache_dir",
-    "dispatch_depth",
     "enable_jax_cache",
     "engine",
 ]

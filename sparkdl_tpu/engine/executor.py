@@ -3,10 +3,9 @@
 jax dispatch is asynchronous — ``fn(batch)`` returns a future-like
 device array immediately — but a naive loop squanders that by calling
 ``jax.device_get`` right after dispatch, serializing host transfer
-behind device compute.  The repo grew two partial fixes (the one-deep
-``r_prev`` overlap in ``transformers/utils.py`` and nothing at all on
-the ``run_batched_multi`` / serving paths); this window replaces both
-with one engine-owned discipline:
+behind device compute.  This window is the one engine-owned discipline
+for that, in the transformers' loop
+(``transformers/utils.run_batched_partitions``), serving and streaming:
 
 - ``submit(result, meta)`` enqueues a dispatched device result and
   immediately starts its **device→host copy in the background**
@@ -14,8 +13,7 @@ with one engine-owned discipline:
   only what exceeds the window depth;
 - with depth N, batch i's host fetch happens while batches i+1..i+N are
   still computing, so the transfer fully hides behind device compute;
-- depth 0 degrades to strict dispatch→fetch serialization (the
-  ``SPARKDL_SERIAL_INFERENCE=1`` kill switch).
+- depth 0 degrades to strict dispatch→fetch serialization.
 
 The window is deliberately not a thread: jax's own runtime provides the
 asynchrony; this class only decides *when* to synchronize.
@@ -29,7 +27,6 @@ spends at zero is recorded as ``engine.starved`` boundary spans
 
 from __future__ import annotations
 
-import os
 import threading
 from collections import deque
 from typing import Any, Iterator, List, Optional, Tuple
@@ -38,22 +35,9 @@ import numpy as np
 
 from sparkdl_tpu.obs.trace import tracer
 
-_DEPTH_ENV = "SPARKDL_DISPATCH_DEPTH"
+#: the in-flight window depth of a :class:`DispatchWindow` made without
+#: one: one batch computing, one transferring
 DEFAULT_DEPTH = 2
-
-
-def dispatch_depth() -> int:
-    """The configured in-flight window depth (``SPARKDL_DISPATCH_DEPTH``,
-    default 2 — one batch computing, one transferring)."""
-    spec = os.environ.get(_DEPTH_ENV, "").strip()
-    if not spec:
-        return DEFAULT_DEPTH
-    try:
-        return max(0, int(spec))
-    except ValueError:
-        raise ValueError(
-            f"{_DEPTH_ENV} must be a non-negative integer, got {spec!r}"
-        )
 
 
 def _start_host_copy(result: Any) -> None:
@@ -181,7 +165,7 @@ class DispatchWindow:
 
     def __init__(self, depth: Optional[int] = None,
                  capture_errors: bool = False):
-        self.depth = dispatch_depth() if depth is None else max(0, int(depth))
+        self.depth = DEFAULT_DEPTH if depth is None else max(0, int(depth))
         self.capture_errors = bool(capture_errors)
         self._inflight: "deque[Tuple[Any, Any]]" = deque()
         from sparkdl_tpu.utils.metrics import metrics

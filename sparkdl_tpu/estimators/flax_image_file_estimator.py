@@ -39,7 +39,6 @@ from sparkdl_tpu.estimators.losses import (
     get_per_sample_loss_fn,
 )
 from sparkdl_tpu.ml.base import Estimator, Transformer
-from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.param.base import Param, keyword_only
 from sparkdl_tpu.param.shared import (
     CanLoadImage,
@@ -57,7 +56,8 @@ from sparkdl_tpu.transformers.utils import (
     DEFAULT_BATCH_SIZE,
     make_loader_decode_plan,
     place_params,
-    run_batched_rows,
+    to_vectors,
+    transform_batched,
 )
 
 logger = logging.getLogger(__name__)
@@ -150,28 +150,14 @@ class FlaxImageFileTransformer(
         return self._jitted
 
     def _transform(self, dataset):
-        input_col = self.getInputCol()
-        output_col = self.getOutputCol()
         loader = self.getImageLoader()
-        fn = self._forward()
-
-        def process_partition(part):
-            uris = part[input_col]
-            out = dict(part)
-            if not uris:
-                out[output_col] = []
-                return out
-
-            # loader + forward pipelined (run_batched_rows), same contract
-            # as KerasImageFileTransformer: one fixed loader shape bound
-            # across chunks
-            decode = make_loader_decode_plan(loader)
-            result = run_batched_rows(fn, uris, decode, self.batchSize)
-            flat = result.reshape(result.shape[0], -1).astype(np.float64)
-            out[output_col] = [DenseVector(v) for v in flat]
-            return out
-
-        return dataset.mapPartitions(process_partition)
+        # same contract as KerasImageFileTransformer: one fixed loader
+        # shape bound across the chunks of a partition
+        return transform_batched(
+            dataset, self.getInputCol(), self.getOutputCol(), self._forward(),
+            lambda uris: make_loader_decode_plan(loader), to_vectors,
+            self.batchSize,
+        )
 
 
 class FlaxImageFileEstimator(

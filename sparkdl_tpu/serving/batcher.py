@@ -58,11 +58,7 @@ from sparkdl_tpu.serving.admission import (
 )
 from sparkdl_tpu.serving.cache import ProgramCache
 from sparkdl_tpu.serving.errors import DeadlineExceeded, ServerClosed
-from sparkdl_tpu.transformers.utils import (
-    _serial_inference,
-    pad_to_batch,
-    shape_bucket,
-)
+from sparkdl_tpu.transformers.utils import pad_to_batch, shape_bucket
 from sparkdl_tpu.utils.metrics import metrics
 
 logger = logging.getLogger(__name__)
@@ -200,9 +196,7 @@ class MicroBatcher:
         # batch i's device->host fetch streams while batch i+1 computes;
         # drained eagerly whenever the queue goes idle so a lone request
         # never waits on the window
-        self._window = DispatchWindow(
-            depth=0 if _serial_inference() else None, capture_errors=True
-        )
+        self._window = DispatchWindow(capture_errors=True)
         self._item_shape: Optional[Tuple[int, ...]] = (
             tuple(int(d) for d in item_shape) if item_shape is not None
             else None

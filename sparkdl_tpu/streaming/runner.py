@@ -96,7 +96,7 @@ class StreamConfig:
     poll_interval_ms: float = 10.0
     #: watermark bounded-lateness allowance
     allowed_lateness_ms: float = 0.0
-    #: dispatch-window depth (None → engine default / env)
+    #: dispatch-window depth (None → the engine's ``DEFAULT_DEPTH``)
     dispatch_depth: Optional[int] = None
     #: how long a blocked poller waits per offer attempt before
     #: re-checking for shutdown

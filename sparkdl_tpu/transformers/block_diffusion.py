@@ -43,10 +43,7 @@ import jax.numpy as jnp
 from sparkdl_tpu.ml.base import Transformer
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
-from sparkdl_tpu.transformers.utils import (
-    _serial_inference,
-    place_params_once,
-)
+from sparkdl_tpu.transformers.utils import place_params_once
 
 #: chunk lengths and the cache's span are multiples of this (one compile
 #: per distinct shape; the TPU tiles the span by it)
@@ -358,7 +355,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
         tokens, whole, first, known, later, unknown, where = (
             runner.place(a) for a in host)
     cache_k, cache_v = runner.cache(rows, plan.span)
-    window = DispatchWindow(depth=0 if _serial_inference() else None)
+    window = DispatchWindow()
     fetched: List[Any] = []
 
     def landed(pairs):

@@ -12,12 +12,9 @@ from __future__ import annotations
 
 from typing import Optional
 
-import numpy as np
-
 import jax
 
 from sparkdl_tpu.ml.base import Transformer
-from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import (
     CanLoadImage,
@@ -31,9 +28,10 @@ from sparkdl_tpu.transformers.utils import (
     load_keras_function,
     make_loader_decode_plan,
     place_params,
-    run_batched_rows,
+    to_image_structs,
+    to_vectors,
+    transform_batched,
 )
-from sparkdl_tpu.image import imageIO
 
 
 class KerasImageFileTransformer(
@@ -82,12 +80,7 @@ class KerasImageFileTransformer(
         return self._set(**kwargs)
 
     def _transform(self, dataset):
-        input_col = self.getInputCol()
-        output_col = self.getOutputCol()
         loader = self.getImageLoader()
-        mode = self.getOutputMode()
-        batch_size = self.getOrDefault(self.batchSize)
-
         fn = load_keras_function(
             self.getModelFile(),
             compute_dtype=self.getOrDefault(self.computeDtype),
@@ -98,25 +91,12 @@ class KerasImageFileTransformer(
         def jitted(x):
             return inner(params, x)[0]
 
-        def process_partition(part):
-            uris = part[input_col]
-            out = dict(part)
-            if not uris:
-                out[output_col] = []
-                return out
-            # loader + forward run pipelined (run_batched_rows): chunk
-            # i+1 loads on a prefetch thread while chunk i is on device;
-            # the one-fixed-shape loader contract binds across chunks
-            decode = make_loader_decode_plan(loader)
-            result = run_batched_rows(jitted, uris, decode, batch_size)
-            if mode == "vector":
-                flat = result.reshape(result.shape[0], -1).astype(np.float64)
-                out[output_col] = [DenseVector(v) for v in flat]
-            else:
-                out[output_col] = [
-                    imageIO.imageArrayToStruct(np.asarray(r, dtype=np.float32))
-                    for r in result
-                ]
-            return out
-
-        return dataset.mapPartitions(process_partition)
+        # the one-fixed-shape loader contract binds across the chunks of
+        # a partition
+        as_vectors = self.getOutputMode() == "vector"
+        return transform_batched(
+            dataset, self.getInputCol(), self.getOutputCol(), jitted,
+            lambda uris: make_loader_decode_plan(loader),
+            to_vectors if as_vectors else to_image_structs,
+            self.getOrDefault(self.batchSize),
+        )

@@ -26,10 +26,8 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.image import imageIO
 from sparkdl_tpu.ml.base import Transformer
-from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.models import get_keras_application_model
 from sparkdl_tpu.models.registry import SUPPORTED_MODELS, decode_predictions
-from sparkdl_tpu.obs.trace import tracer
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
 from sparkdl_tpu.sql.types import Row
@@ -38,7 +36,8 @@ from sparkdl_tpu.transformers.utils import (
     cast_and_resize_on_device,
     make_image_decode_plan,
     place_params,
-    run_batched_partitions,
+    to_vectors,
+    transform_batched,
 )
 
 logger = logging.getLogger(__name__)
@@ -263,9 +262,6 @@ class _NamedImageTransformer(Transformer, HasInputCol, HasOutputCol):
         return jitted, entry
 
     def _transform(self, dataset):
-        input_col = self.getInputCol()
-        output_col = self.getOutputCol()
-        batch_size = self.getOrDefault(self.batchSize)
         forward, entry = self._build_forward()
         height, width = entry.input_size
 
@@ -278,33 +274,12 @@ class _NamedImageTransformer(Transformer, HasInputCol, HasOutputCol):
         def plan(rows):
             return make_image_decode_plan(rows, 3, (height, width))
 
-        def process_partitions(parts):
-            # ONE pipeline over all partitions (run_batched_partitions):
-            # chunk i+1 packs on a prefetch thread and dispatches before
-            # chunk i's fetch, across the border between two partitions
-            # too, so a partition's rows are built while the device works
-            # on the next one's first batches.  The boundary spans name
-            # where a partition's time goes (obs.trace): the loop opens a
-            # root ``featurize.partition`` a non-empty partition and hangs
-            # the plan, pack, wait, place, dispatch and fetch spans under
-            # it; ``inflight`` says what the device had to do meanwhile.
-            outs = [{**part, output_col: []} for part in parts]
-
-            def postprocess(done):
-                with tracer.boundary(
-                    "featurize.postprocess", parent=done.span,
-                    rows=len(done.result), inflight=done.inflight,
-                ):
-                    outs[done.index][output_col] = self._postprocess(
-                        done.result)
-
-            run_batched_partitions(
-                forward, [part[input_col] for part in parts], plan,
-                postprocess, batch_size, span_name="featurize.partition",
-            )
-            return outs
-
-        return dataset.mapAllPartitions(process_partitions)
+        return transform_batched(
+            dataset, self.getInputCol(), self.getOutputCol(), forward, plan,
+            # looked up a call: a subclass's, or one put there meanwhile
+            lambda result: self._postprocess(result),
+            self.getOrDefault(self.batchSize),
+        )
 
 
 class DeepImageFeaturizer(_NamedImageTransformer):
@@ -346,7 +321,7 @@ class DeepImageFeaturizer(_NamedImageTransformer):
         return self._set(**kwargs)
 
     def _postprocess(self, result: np.ndarray):
-        return [DenseVector(v) for v in result.astype(np.float64)]
+        return to_vectors(result)
 
 
 class DeepImagePredictor(_NamedImageTransformer):
@@ -413,7 +388,7 @@ class DeepImagePredictor(_NamedImageTransformer):
         probs = np.exp(z)
         probs /= probs.sum(axis=1, keepdims=True)
         if not self.getOrDefault(self.decodePredictions):
-            return [DenseVector(p) for p in probs.astype(np.float64)]
+            return to_vectors(probs)
         top_k = self.getOrDefault(self.topK)
         decoded = decode_predictions(probs, top=top_k)
         return [

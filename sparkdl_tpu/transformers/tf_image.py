@@ -15,14 +15,10 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.image import imageIO
 from sparkdl_tpu.ml.base import Transformer
-from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.shared import (
@@ -35,7 +31,9 @@ from sparkdl_tpu.transformers.utils import (
     cast_and_resize_on_device,
     make_image_decode_plan,
     place_params,
-    run_batched_rows,
+    to_image_structs,
+    to_vectors,
+    transform_batched,
 )
 
 
@@ -115,13 +113,9 @@ class TFImageTransformer(Transformer, HasInputCol, HasOutputCol, HasOutputMode):
 
     # ------------------------------------------------------------------
     def _transform(self, dataset):
-        input_col = self.getInputCol()
-        output_col = self.getOutputCol()
         fn = self.getGraph()
         size = self.getOrDefault(self.inputShape)
         order = self.getOrDefault(self.channelOrder)
-        mode = self.getOutputMode()
-        batch_size = self.getOrDefault(self.batchSize)
 
         if len(fn.output_names) != 1:
             raise ValueError(
@@ -157,33 +151,20 @@ class TFImageTransformer(Transformer, HasInputCol, HasOutputCol, HasOutputMode):
             name=f"tf_image_{fn.name}",
         )
 
-        def process_partition(part):
-            rows = part[input_col]
-            if not rows:
-                out = dict(part)
-                out[output_col] = []
-                return out
-            n_channels = 1 if order == "L" else 3
-            # pipelined decode/dispatch (run_batched_rows); the decode plan
-            # (shape + dtype) is decided over the whole partition so one
-            # program compiles (raises MixedImageSizesError when sizes mix
-            # and no input size is set)
-            decode = make_image_decode_plan(rows, n_channels, size)
-            result = run_batched_rows(jitted, rows, decode, batch_size)
-            out = dict(part)
-            if mode == "vector":
-                flat = result.reshape(result.shape[0], -1).astype(np.float64)
-                out[output_col] = [DenseVector(v) for v in flat]
-            else:  # "image"
-                out[output_col] = [
-                    imageIO.imageArrayToStruct(
-                        np.asarray(img, dtype=np.float32), origin=""
-                    )
-                    for img in result
-                ]
-            return out
+        n_channels = 1 if order == "L" else 3
 
-        return dataset.mapPartitions(process_partition)
+        # the decode plan (shape + dtype) is decided over a whole partition
+        # so one program compiles (raises MixedImageSizesError when sizes
+        # mix and no input size is set)
+        def plan(rows):
+            return make_image_decode_plan(rows, n_channels, size)
+
+        as_vectors = self.getOutputMode() == "vector"
+        return transform_batched(
+            dataset, self.getInputCol(), self.getOutputCol(), jitted, plan,
+            to_vectors if as_vectors else to_image_structs,
+            self.getOrDefault(self.batchSize),
+        )
 
 
 # Native spelling.

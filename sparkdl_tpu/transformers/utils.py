@@ -17,14 +17,17 @@ rules applied here:
   analog of the reference fanning inference out across Spark executors
   (SURVEY.md §2 "Data-parallel inference").
 
-The load/decode side of the loop — chunking, background prefetch, clean
-shutdown — is :mod:`sparkdl_tpu.data` (see :func:`run_batched_partitions`);
-this module owns what happens once a batch is decoded.
+There is ONE row-batch loop, :func:`run_batched_partitions`, and ONE stage
+body over it, :func:`transform_batched`, which every batched DataFrame stage
+calls; :func:`run_batched_multi` (pre-decoded arrays) and
+:func:`run_batched_rows` (the UDF's one column) are one-partition calls of
+the loop.  Its load/decode side — chunking, background prefetch, clean
+shutdown — is :mod:`sparkdl_tpu.data`; this module owns what happens once a
+batch is decoded.
 """
 
 from __future__ import annotations
 
-import logging
 import os
 import threading
 import time
@@ -38,8 +41,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-logger = logging.getLogger(__name__)
 
 DEFAULT_BATCH_SIZE = 32
 
@@ -254,27 +255,15 @@ def _device_resize_timed(
         device_groups.append((idxs, batch))
 
     if device_groups:
-        # dispatch EVERY shape group before fetching any: a per-group
-        # host sync would serialize the groups (each resize waits for the
-        # previous fetch); the window keeps them in flight together and
-        # fetches as they land
-        from sparkdl_tpu.engine import DispatchWindow
-
+        # dispatch EVERY shape group (at most _MAX_DEVICE_RESIZE_SHAPES)
+        # before fetching any: a fetch between two dispatches would make
+        # each resize wait for the one before it
         resize_fn = _resize_cache[(height, width)]
-
-        def _scatter(host: np.ndarray, done_idxs: List[int]) -> None:
-            for j, i in enumerate(done_idxs):
+        results = [resize_fn(batch) for _, batch in device_groups]
+        for (idxs, _), result in zip(device_groups, results):
+            host = np.asarray(result)
+            for j, i in enumerate(idxs):
                 out[i] = host[j]
-
-        window = DispatchWindow(depth=0 if _serial_inference() else None)
-        try:
-            for idxs, batch in device_groups:
-                for host, done in window.submit(resize_fn(batch), meta=idxs):
-                    _scatter(host, done)
-            for host, done in window.drain():
-                _scatter(host, done)
-        finally:
-            window.abandon()
     return np.stack(out)  # type: ignore[arg-type]
 
 
@@ -387,7 +376,7 @@ def make_loader_decode_plan(
     load_one: Callable, what: str = "imageLoader"
 ) -> Callable[[Sequence], np.ndarray]:
     """Chunked decode plan for user-loader inputs (``load_one(uri) ->
-    ndarray``), for :func:`run_batched_rows`.
+    ndarray``), for :func:`run_batched_partitions`.
 
     Enforces the one-fixed-shape loader contract ACROSS chunks (the first
     chunk's shape binds the partition), so a chunk-aligned shape change
@@ -435,8 +424,8 @@ def make_image_decode_plan(
     programs — to the one jitted forward.
 
     Returns a ``decode(chunk) -> np.ndarray`` closure for
-    :func:`run_batched_rows`.  Raises :class:`MixedImageSizesError` when
-    the partition mixes sizes and ``size`` is None.
+    :func:`run_batched_partitions`.  Raises :class:`MixedImageSizesError`
+    when the partition mixes sizes and ``size`` is None.
     """
     from sparkdl_tpu.obs.trace import tracer
 
@@ -522,109 +511,38 @@ def run_batched_multi(
     batch_size: int = DEFAULT_BATCH_SIZE,
 ) -> Tuple[np.ndarray, ...]:
     """Run ``fn(*inputs)`` (jitted, device-params already bound) over row-
-    aligned input arrays in fixed-size chunks; the last chunk is padded up to
-    ``batch_size`` (and sliced back) so only one batch shape is ever compiled
-    — small partitions also pad up rather than compiling their own shape.
-
-    With a multi-device :func:`data_parallel_mesh`, every (padded, fixed
-    shape) chunk is placed with its leading dim sharded across the mesh, so
-    ``fn`` — whose params were replicated by :func:`place_params` — compiles
-    to one SPMD program spanning all local chips.  ``batch_size`` is rounded
-    up to the nearest mesh multiple in that case (equal-sized shards per
-    chip), so e.g. ``batchSize=10`` runs as 16-row chunks on 8 chips; row
-    count and output order are unaffected.
-
-    Fetches go through the engine's :class:`DispatchWindow`: chunk i's
-    device→host copy streams in the background while chunks i+1..i+N are
-    dispatched, so host transfer hides behind device compute (the same
-    discipline as :func:`run_batched_rows`; ``SPARKDL_SERIAL_INFERENCE=1``
-    collapses the window to strict dispatch→fetch).
+    aligned, pre-decoded input arrays: one partition of
+    :func:`run_batched_partitions`, whose batch is the tuple of the arrays'
+    row chunks and whose result is the tuple of ``fn``'s outputs.  The last
+    chunk is padded up to ``batch_size`` (and sliced back) so only one batch
+    shape is ever compiled — small partitions also pad up rather than
+    compiling their own shape; with a multi-device
+    :func:`data_parallel_mesh` ``batch_size`` is rounded up to a mesh
+    multiple, so e.g. ``batchSize=10`` runs as 16-row chunks on 8 chips.
+    Row count and output order are unaffected.
 
     Returns one concatenated array per function output.
     """
-    from sparkdl_tpu.obs.trace import tracer
-    from sparkdl_tpu.utils.metrics import metrics
-    from sparkdl_tpu.utils.profiler import maybe_trace
-
     n = arrays[0].shape[0]
     if n == 0:
         raise ValueError("run_batched requires non-empty inputs")
-    mesh = data_parallel_mesh()
-    if mesh is not None:
-        # padded chunks are always exactly batch_size rows; round the batch
-        # up to a mesh multiple so the shards are equal-sized
-        n_dev = int(mesh.devices.size)
-        rounded = -(-batch_size // n_dev) * n_dev
-        if rounded != batch_size:
-            logger.debug(
-                "run_batched: batch_size %d rounded up to %d (mesh multiple "
-                "of %d devices)",
-                batch_size,
-                rounded,
-                n_dev,
-            )
-        batch_size = rounded
-        # P("data") shards the leading dim; unmentioned trailing dims are
-        # replicated, so one sharding serves every input rank
-        sharding = NamedSharding(mesh, PartitionSpec("data"))
 
-        def _place(c):
-            return jax.device_put(c, sharding)
+    def call(inputs):
+        results = fn(*inputs)
+        if isinstance(results, (tuple, list)):
+            return tuple(results)
+        return (results,)
 
-    else:
-        _place = jnp.asarray
+    call.__name__ = _program_name(fn)
 
-    from sparkdl_tpu.engine import DispatchWindow
+    def cut(rows: range):
+        return tuple(a[rows.start : rows.stop] for a in arrays)
 
-    collected: Optional[List[List[np.ndarray]]] = None
-
-    def _collect(host: Tuple[np.ndarray, ...], k: int) -> None:
-        nonlocal collected
-        if collected is None:
-            collected = [[] for _ in host]
-        for acc, r in zip(collected, host):
-            acc.append(r[:k])
-
-    window = DispatchWindow(depth=0 if _serial_inference() else None)
-    # 'sparkdl.serve' is end-to-end loop wall time (the sustained-rate
-    # denominator); 'sparkdl.forward' is the HOST's time in place +
-    # dispatch + blocking on fetches, not the device's: with the window
-    # full it is mostly the wait for results, with it empty mostly the
-    # transfer in.  Here inputs are pre-decoded so the two coincide;
-    # run_batched_rows (lazy decode in the loop) is where they diverge.
-    # The engine.* boundary spans split it by layer.
-    serve_timer = metrics.timer("sparkdl.serve")
-    forward_timer = metrics.timer("sparkdl.forward")
-    program = _program_name(fn)
-    try:
-        with maybe_trace(), serve_timer.time(), forward_timer.time():
-            for lo in range(0, n, batch_size):
-                chunks = [a[lo : lo + batch_size] for a in arrays]
-                k = chunks[0].shape[0]
-                if k < batch_size:
-                    chunks = [pad_to_batch(c, batch_size) for c in chunks]
-                with tracer.boundary(
-                    "engine.place", bytes=sum(c.nbytes for c in chunks)
-                ):
-                    placed = [_place(c) for c in chunks]
-                with tracer.boundary("engine.dispatch", program=program):
-                    results = fn(*placed)
-                if not isinstance(results, (tuple, list)):
-                    results = (results,)
-                for host, k_done in window.submit(tuple(results), meta=k):
-                    _collect(host, k_done)
-            for host, k_done in window.drain():
-                _collect(host, k_done)
-    finally:
-        window.abandon()
-    metrics.counter("sparkdl.rows_processed").add(n)
-    if logger.isEnabledFor(logging.DEBUG):
-        # rows of every kind (not only images), over the whole process
-        logger.debug(
-            "run_batched: %d rows; %.1f rows/sec sustained by the process",
-            n, metrics.images_per_sec() or 0.0)
-    assert collected is not None
-    return tuple(np.concatenate(acc, axis=0) for acc in collected)
+    out: List[Tuple[np.ndarray, ...]] = []
+    run_batched_partitions(
+        call, [range(n)], lambda _: cut,
+        lambda done: out.append(done.result), batch_size)
+    return out[0]
 
 
 def run_batched(
@@ -645,22 +563,15 @@ def _program_name(fn: Callable) -> str:
     )
 
 
-def _serial_inference() -> bool:
-    """Kill switch for the pipelined serving path: SPARKDL_SERIAL_INFERENCE=1
-    restores strict decode-all -> dispatch -> fetch serialization."""
-    return os.environ.get("SPARKDL_SERIAL_INFERENCE", "").strip() in (
-        "1", "true", "yes", "on",
-    )
-
-
 class FinishedPartition(NamedTuple):
     """What :func:`run_batched_partitions` hands ``finish`` when the last
     result of a partition is back on the host."""
 
     #: the partition's place in the ``partitions`` it came from
     index: int
-    #: the outputs of its rows, in row order
-    result: np.ndarray
+    #: the outputs of its rows, in row order (a tuple of arrays where the
+    #: dispatched function returns one)
+    result: Any
     #: its boundary span, still open: what ``finish`` does nests under it
     #: by naming it as ``parent`` (None where the caller had none open)
     span: Any
@@ -672,7 +583,7 @@ class FinishedPartition(NamedTuple):
 def run_batched_partitions(
     fn: Callable,
     partitions: Sequence[Sequence],
-    plan: Callable[[Sequence], Callable[[Sequence], np.ndarray]],
+    plan: Callable[[Sequence], Callable[[Sequence], Any]],
     finish: Callable[[FinishedPartition], None],
     batch_size: int = DEFAULT_BATCH_SIZE,
     span_name: Optional[str] = None,
@@ -687,11 +598,10 @@ def run_batched_partitions(
       ``StreamingShardLoader``) — straight through a border: the packer
       turns to the next partition while the last chunks of this one are
       still on the device;
-    - dispatched results ride ONE depth-N
-      :class:`~sparkdl_tpu.engine.DispatchWindow`
-      (``SPARKDL_DISPATCH_DEPTH``, default 2): chunk i's device→host copy
-      streams asynchronously while chunks i+1..i+N compute, so the fetch
-      finds the bytes already on host;
+    - dispatched results ride ONE :class:`~sparkdl_tpu.engine.DispatchWindow`
+      of the engine's depth N (``DEFAULT_DEPTH``, 2): chunk i's device→host
+      copy streams asynchronously while chunks i+1..i+N compute, so the
+      fetch finds the bytes already on host;
     - when the LAST result of a partition falls out of the window,
       ``finish(FinishedPartition)`` runs on the dispatching thread — by
       then N chunks of the next partition are dispatched, so the device
@@ -703,20 +613,22 @@ def run_batched_partitions(
     A partition border is metadata: a chunk never spans two partitions.
     ``plan(rows) -> decode`` is called once a non-empty partition, when
     the packer reaches it (the decode policy — dtype, packed shape — is
-    decided over the partition's rows), and ``decode(chunk_rows) ->
-    np.ndarray`` must be row-aligned.  Chunks are ``batch_size`` rows
-    (mesh-rounded, as in :func:`run_batched_multi`); a partition's ragged
+    decided over the partition's rows), and ``decode(chunk_rows)`` gives
+    the batch: one row-aligned array, or a tuple of them (any pytree),
+    which ``fn`` takes whole and answers with one array, or a pytree of
+    them, a row each.  One array in must give one array out (a
+    ``TypeError`` otherwise).  Chunks are ``batch_size`` rows — rounded up
+    to a multiple of a multi-device :func:`data_parallel_mesh`, over which
+    every batch's leading dim is then sharded — and a partition's ragged
     final chunk pads by repeating its last row, so exactly one batch shape
     is ever compiled per decode shape, and every batch holds the rows it
     would hold were the partitions run one call each.  Empty partitions
     are passed over (no ``finish``).  Partitions finish in order.
 
     Host memory: at most 2 packed chunks ahead, N results in flight and
-    the fetched results of the one partition not yet finished.
-    ``SPARKDL_SERIAL_INFERENCE=1`` disables both overlaps (no prefetch
-    thread, depth 0).  An error — in ``plan``, ``decode``, ``fn`` or
-    ``finish`` — raises out of the call with the window abandoned and the
-    prefetch thread joined.
+    the fetched results of the one partition not yet finished.  An error —
+    in ``plan``, ``decode``, ``fn`` or ``finish`` — raises out of the call
+    with the window abandoned and the prefetch thread joined.
 
     The load/decode prefix is a :mod:`sparkdl_tpu.data` pipeline
     (``from_items(chunk indexes) → map(pack) → prefetch(2)``), so the
@@ -732,16 +644,22 @@ def run_batched_partitions(
     explicit parent.  Without, they hang under the caller's current span,
     which gets ``batches``.
     """
+    from sparkdl_tpu.data import Dataset
     from sparkdl_tpu.engine import DispatchWindow
     from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
     from sparkdl_tpu.utils.profiler import maybe_trace
 
+    tree_map, tree_leaves = jax.tree_util.tree_map, jax.tree_util.tree_leaves
     asked_batch_size = batch_size
     mesh = data_parallel_mesh()
     if mesh is not None:
+        # padded chunks are always exactly batch_size rows; round the batch
+        # up to a mesh multiple so the shards are equal-sized
         n_dev = int(mesh.devices.size)
         batch_size = -(-batch_size // n_dev) * n_dev
+        # P("data") shards the leading dim; unmentioned trailing dims are
+        # replicated, so one sharding serves every input rank
         sharding = NamedSharding(mesh, PartitionSpec("data"))
 
         def _place(c):
@@ -760,7 +678,6 @@ def run_batched_partitions(
     if not chunks:
         return
     last_chunk = {p: i for i, (p, _, _) in enumerate(chunks)}
-    serial = _serial_inference()
 
     # The packs run on the prefetch thread, which inherits no context, and
     # two partitions are open at once on the dispatching thread: every
@@ -789,40 +706,35 @@ def run_batched_partitions(
 
     def pack(i):
         p, lo, hi = chunks[i]
-        # a serial pack (and plan) nests in the wait that runs it
-        parent = tracer.current() if serial else span_of(p)
+        parent = span_of(p)
         if lo == 0:
             with tracer.use_span(parent):
                 decodes[p] = plan(partitions[p])
         with tracer.boundary("data.pack", parent=parent) as span:
-            batch = decodes[p](partitions[p][lo:hi])
-            k = batch.shape[0]
-            batch = pad_to_batch(batch, batch_size)
-            span.set_attribute("rows", k)
-            span.set_attribute("padded_rows", batch.shape[0])
-            span.set_attribute("bytes", batch.nbytes)
+            batch = tree_map(
+                lambda a: pad_to_batch(a, batch_size),
+                decodes[p](partitions[p][lo:hi]))
+            nbytes = sum(a.nbytes for a in tree_leaves(batch))
+            span.set_attribute("rows", hi - lo)
+            span.set_attribute("padded_rows", batch_size)
+            span.set_attribute("bytes", nbytes)
         if i == last_chunk[p]:
             del decodes[p]
-        return batch
+        return batch, nbytes
 
-    if serial:
-        packed = (pack(i) for i in range(len(chunks)))
-    else:
-        # prefetch(2) bounds host memory at ~2 extra decoded chunks; the
-        # pipeline's close protocol (cancel -> drain -> join) means a
-        # failed call can't leak the decode thread plus its chunks
-        from sparkdl_tpu.data import Dataset
-
-        packed = iter(
-            Dataset.from_items(range(len(chunks)), name="chunk_indexes")
-            .map(pack)
-            .prefetch(2)
-        )
+    # prefetch(2) bounds host memory at ~2 extra decoded chunks; the
+    # pipeline's close protocol (cancel -> drain -> join) means a failed
+    # call can't leak the decode thread plus its chunks
+    packed = iter(
+        Dataset.from_items(range(len(chunks)), name="chunk_indexes")
+        .map(pack)
+        .prefetch(2)
+    )
 
     # (images_processed is advanced by the decode layer — e.g.
     # decode_image_batch — not here, to avoid double counting)
-    window = DispatchWindow(depth=0 if serial else None)
-    collected: List[np.ndarray] = []  # of the one partition not yet finished
+    window = DispatchWindow()
+    collected: List[Any] = []  # of the one partition not yet finished
     fetched = 0  # results come back in order: the next is chunks[fetched]'s
     away_s = 0.0  # spent in ``finish``: the caller's time, not the loop's
     borders = metrics.counter("engine.borders")
@@ -837,11 +749,12 @@ def run_batched_partitions(
     def take(host):
         nonlocal fetched, away_s
         p, lo, hi = chunks[fetched]
-        collected.append(host[: hi - lo])
+        collected.append(tree_map(lambda a: a[: hi - lo], host))
         fetched += 1
         if fetched <= last_chunk[p]:
             return
-        result = np.concatenate(collected, axis=0)
+        result = tree_map(
+            lambda *parts: np.concatenate(parts, axis=0), *collected)
         collected.clear()
         inflight = len(window)
         if fetched < len(chunks):  # a border: a partition is still to come
@@ -860,9 +773,8 @@ def run_batched_partitions(
     # 'sparkdl.forward' is the HOST's time in place + dispatch + blocking
     # on fetches — not the device's: the device works on while the host
     # packs, and waits while the host is here placing.  The engine.*
-    # boundary spans split it by layer.  Pulling the next chunk (lazy
-    # decode in serial mode, queue wait in pipelined mode) advances
-    # 'sparkdl.load' inside the decode closure, so timing the whole loop
+    # boundary spans split it by layer.  The decode closure advances
+    # 'sparkdl.load' on the prefetch thread, so timing the whole loop
     # would double-count load under forward.  The whole loop — load waits
     # included, ``finish`` left out — runs under 'sparkdl.serve', the
     # sustained end-to-end rate images_per_sec() reports.
@@ -874,20 +786,21 @@ def run_batched_partitions(
             for p, _, _ in chunks:
                 span = span_of(p)
                 with tracer.boundary("engine.load_wait", parent=span):
-                    batch = next(packed)
+                    batch, nbytes = next(packed)
                 with forward_timer.time():
                     with tracer.boundary(
-                        "engine.place", parent=span, bytes=batch.nbytes
+                        "engine.place", parent=span, bytes=nbytes
                     ):
-                        placed = _place(batch)
+                        placed = tree_map(_place, batch)
                     with tracer.boundary(
                         "engine.dispatch", parent=span, program=program
                     ):
                         result = fn(placed)  # async dispatch
-                    if isinstance(result, (tuple, list)):
+                    if isinstance(result, (tuple, list)) and isinstance(
+                            batch, np.ndarray):
                         raise TypeError(
-                            "run_batched_rows requires a single-output fn "
-                            f"(got {len(result)} outputs); unwrap the "
+                            "a batch of one array requires a single-output "
+                            f"fn (got {len(result)} outputs); unwrap the "
                             "output in the forward, or use "
                             "run_batched_multi"
                         )
@@ -905,9 +818,7 @@ def run_batched_partitions(
                 take(host)
     finally:
         window.abandon()
-        close = getattr(packed, "close", None)
-        if close is not None:
-            close()
+        packed.close()
         for span in spans.values():  # an error left them open
             span.end()
         metrics.timer("sparkdl.serve").add_seconds(
@@ -922,11 +833,11 @@ def run_batched_rows(
     decode: Callable[[Sequence], np.ndarray],
     batch_size: int = DEFAULT_BATCH_SIZE,
 ) -> np.ndarray:
-    """:func:`run_batched_partitions` over ONE partition, for the stages
-    that still take a partition at a time: ``decode(chunk_rows) ->
-    np.ndarray`` is the partition's decode plan, made by the caller; the
-    outputs of all ``rows`` come back as one array.  The loop's spans hang
-    under the caller's current span."""
+    """:func:`run_batched_partitions` over ONE partition, for the UDF, which
+    the SQL engine hands one partition's column a call:
+    ``decode(chunk_rows) -> np.ndarray`` is the partition's decode plan,
+    made by the caller; the outputs of all ``rows`` come back as one array.
+    The loop's spans hang under the caller's current span."""
     if len(rows) == 0:
         raise ValueError("run_batched_rows requires non-empty rows")
     out: List[np.ndarray] = []
@@ -934,6 +845,71 @@ def run_batched_rows(
         fn, [rows], lambda _: decode, lambda done: out.append(done.result),
         batch_size)
     return out[0]
+
+
+def to_vectors(result: np.ndarray) -> List:
+    """One float64 ``DenseVector`` a row of ``result``, each row flattened:
+    what a vector-valued output column holds."""
+    from sparkdl_tpu.ml.linalg import DenseVector
+
+    flat = result.reshape(result.shape[0], -1).astype(np.float64)
+    return [DenseVector(v) for v in flat]
+
+
+def to_image_structs(result: np.ndarray) -> List:
+    """One float32 image struct a row of ``result``: what an image-valued
+    output column holds."""
+    from sparkdl_tpu.image import imageIO
+
+    return [
+        imageIO.imageArrayToStruct(np.asarray(img, dtype=np.float32))
+        for img in result
+    ]
+
+
+def transform_batched(
+    dataset,
+    input_col: str,
+    output_col: str,
+    fn: Callable,
+    plan: Callable[[Sequence], Callable[[Sequence], np.ndarray]],
+    to_column: Callable[[np.ndarray], List],
+    batch_size: int = DEFAULT_BATCH_SIZE,
+):
+    """The body of every batched DataFrame stage: ``dataset`` with
+    ``output_col`` added, made by ONE :func:`run_batched_partitions` over
+    the ``input_col`` of all partitions, so chunk i+1 packs on a prefetch
+    thread and dispatches before chunk i's fetch across the border between
+    two partitions too.  ``plan(rows) -> decode`` decides how a partition's
+    rows are packed, ``fn`` is dispatched a batch and ``to_column(result)``
+    builds a partition's output values from the outputs of its rows — while
+    the device works on the next partition's first batches.  An empty
+    partition gets an empty column.
+
+    The boundary spans name where a partition's time goes (obs.trace): a
+    root ``featurize.partition`` a non-empty partition, with the plan,
+    pack, wait, place, dispatch and fetch spans and ``to_column``'s
+    ``featurize.postprocess`` under it; ``inflight`` says what the device
+    had to do meanwhile."""
+    from sparkdl_tpu.obs.trace import tracer
+
+    def process_partitions(parts):
+        outs = [{**part, output_col: []} for part in parts]
+
+        def postprocess(done):
+            with tracer.boundary(
+                "featurize.postprocess", parent=done.span,
+                rows=len(done.result), inflight=done.inflight,
+            ):
+                outs[done.index][output_col] = to_column(done.result)
+
+        run_batched_partitions(
+            fn, [part[input_col] for part in parts], plan, postprocess,
+            batch_size, span_name="featurize.partition",
+        )
+        return outs
+
+    return dataset.mapAllPartitions(process_partitions)
 
 
 def normalize_channels(img: np.ndarray, n_channels: int) -> np.ndarray:

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.graph.function import XlaFunction
 from sparkdl_tpu.image import imageIO
-from sparkdl_tpu.ml.linalg import DenseVector
 from sparkdl_tpu.sql.functions import UserDefinedFunction
 from sparkdl_tpu.transformers.utils import (
     DEFAULT_BATCH_SIZE,
@@ -44,6 +43,7 @@ from sparkdl_tpu.transformers.utils import (
     make_loader_decode_plan,
     place_params,
     run_batched_rows,
+    to_vectors,
 )
 
 
@@ -109,7 +109,7 @@ def registerKerasImageUDF(
     def evaluate(values):
         # decode and forward run as a pipeline (run_batched_rows): host
         # decode of chunk i+1 on a prefetch thread while chunk i is on
-        # device, dispatch one chunk ahead of fetch — the serving-path
+        # device, dispatch ahead of fetch — the serving-path
         # transfer/compute overlap (previously the whole partition was
         # decoded before anything shipped)
         if not values:
@@ -134,9 +134,7 @@ def registerKerasImageUDF(
                     "preprocessor or use a fixed-input-size model"
                 ) from e
 
-        result = run_batched_rows(forward, values, decode, batchSize)
-        flat = result.reshape(result.shape[0], -1).astype(np.float64)
-        return [DenseVector(v) for v in flat]
+        return to_vectors(run_batched_rows(forward, values, decode, batchSize))
 
     udf = UserDefinedFunction(evaluate, name=udfName, vectorized=True)
     # online-serving hook: the raw (un-jitted) fused forward plus its item

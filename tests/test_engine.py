@@ -30,7 +30,6 @@ from sparkdl_tpu.engine import (
     PersistentCompileCache,
     cache_key,
     default_cache_dir,
-    dispatch_depth,
 )
 from sparkdl_tpu.engine.cache import _runtime_descriptor
 from sparkdl_tpu.obs import JsonlTraceSink, tracer
@@ -342,13 +341,14 @@ class TestDispatchWindow:
         assert metrics.gauge("engine.inflight").value == 0
         assert list(window.drain()) == []
 
-    def test_env_depth(self, monkeypatch):
-        monkeypatch.setenv("SPARKDL_DISPATCH_DEPTH", "5")
-        assert dispatch_depth() == 5
-        assert DispatchWindow().depth == 5
-        monkeypatch.setenv("SPARKDL_DISPATCH_DEPTH", "bogus")
-        with pytest.raises(ValueError):
-            dispatch_depth()
+    @pytest.mark.parametrize("asked,depth", [(None, 2), (5, 5), (-1, 0)])
+    def test_depth_is_the_constant_unless_the_caller_names_one(
+            self, asked, depth):
+        from sparkdl_tpu.engine.executor import DEFAULT_DEPTH
+
+        assert DEFAULT_DEPTH == 2
+        assert DispatchWindow().depth == DEFAULT_DEPTH
+        assert DispatchWindow(depth=asked).depth == depth
 
     def test_capture_errors_delivers_fetch_failure_with_meta(self):
         class Boom:

@@ -304,6 +304,53 @@ def test_place_bytes_are_exact_for_a_padded_last_batch():
     assert partition.attributes == {"rows": 20, "batches": 3}
 
 
+def test_two_inputs_and_two_outputs_ride_the_one_loop():
+    """``run_batched_multi`` is a call of the one loop: a batch is the
+    tuple of its arrays' row chunks, packed on the prefetch thread, padded
+    and placed together (``engine.place``'s ``bytes`` is their sum), and
+    the ragged last chunk is sliced back in every output."""
+    from sparkdl_tpu.transformers.utils import run_batched_multi
+
+    a = np.arange(20 * 3, dtype=np.float32).reshape(20, 3)
+    b = np.arange(20 * 4, dtype=np.int32).reshape(20, 2, 2)
+    mark = tracer.clock_ns()
+    with tracer.boundary("caller") as caller:
+        total, doubled = run_batched_multi(
+            lambda x, y: (x.sum(axis=1) + y.sum(axis=(1, 2)), y * 2),
+            [a, b], batch_size=8)
+    np.testing.assert_array_equal(total, a.sum(axis=1) + b.sum(axis=(1, 2)))
+    np.testing.assert_array_equal(doubled, b * 2)
+    assert doubled.dtype == np.int32
+    by_name = {}
+    for r in since(mark):
+        by_name.setdefault(r.name, []).append(r)
+    in_row, out_row = 3 * 4 + 4 * 4, 4 + 4 * 4
+    assert [r.attributes["bytes"] for r in by_name["engine.place"]] == [
+        8 * in_row] * 3
+    packs = sorted(by_name["data.pack"], key=lambda r: r.start_ns)
+    assert [(p.attributes["rows"], p.attributes["padded_rows"],
+             p.attributes["bytes"]) for p in packs] == [
+        (8, 8, 8 * in_row), (8, 8, 8 * in_row), (4, 8, 8 * in_row)]
+    assert all(p.parent_id == caller.span_id for p in packs)
+    assert all(p.thread_id != threading.get_ident() for p in packs)
+    assert [r.attributes["program"] for r in by_name["engine.dispatch"]] == [
+        "<lambda>"] * 3
+    assert sum(r.attributes["bytes"] for r in by_name["engine.fetch_wait"]) \
+        == 3 * 8 * out_row
+    (root,) = by_name["caller"]
+    assert root.attributes == {"batches": 3}
+
+
+def test_one_array_in_must_give_one_array_out(monkeypatch):
+    from sparkdl_tpu.transformers.utils import run_batched_rows
+
+    monkeypatch.setattr(executor, "_outstanding", executor._Outstanding())
+    rows = [np.full((2,), i, np.float32) for i in range(20)]
+    with pytest.raises(TypeError, match="single-output fn"):
+        run_batched_rows(lambda x: (x, x), rows, np.stack, batch_size=8)
+    assert executor._outstanding._count == 0  # nothing left dispatched
+
+
 def test_start_boundary_is_a_root_ended_by_hand_and_never_current():
     own = Tracer()
     with own.boundary("outer") as outer:
@@ -376,7 +423,7 @@ def test_the_device_is_fed_across_partition_borders(three_partition_transform):
     from sparkdl_tpu.utils.metrics import metrics
 
     sizes, want, transform = three_partition_transform
-    depth = executor.dispatch_depth()
+    depth = executor.DEFAULT_DEPTH
     assert depth == 2
     borders = metrics.counter("engine.borders").value
     fed = metrics.counter("engine.borders_fed").value
@@ -442,7 +489,7 @@ def test_long_partitions_find_the_window_full_at_their_border(monkeypatch):
     from chipbench.readers import span_self_time
 
     monkeypatch.setattr(executor, "_outstanding", executor._Outstanding())
-    depth = executor.dispatch_depth()
+    depth = executor.DEFAULT_DEPTH
     data = np.arange(200, dtype=np.float32).reshape(100, 2)
     partitions = [list(range(0, 30)), list(range(30, 64)), list(range(64, 100))]
     seen = []
@@ -473,34 +520,6 @@ def test_long_partitions_find_the_window_full_at_their_border(monkeypatch):
     assert span_self_time.self_ns(
         tracer.recent(), {"engine.load_wait"}, lo, hi) == sum(
             r.end_ns - r.start_ns for r in waits)
-
-
-def test_serial_inference_keeps_its_meaning_over_partitions(
-        three_partition_transform, monkeypatch):
-    """Depth 0 and no prefetch thread: every pack and plan nests in the
-    wait that runs it, on the dispatching thread, and no border is fed."""
-    from sparkdl_tpu.utils.metrics import metrics
-
-    sizes, want, transform = three_partition_transform
-    monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
-    fed = metrics.counter("engine.borders_fed").value
-    mark = tracer.clock_ns()
-    out = transform()
-    recs = since(mark)
-    got = np.stack([np.asarray(r.features.toArray()) for r in out.collect()])
-    np.testing.assert_array_equal(got, want.astype(np.float64))
-    assert {r.thread_id for r in recs} == {threading.get_ident()}
-    waits = {r.span_id for r in recs if r.name == "engine.load_wait"}
-    assert all(r.parent_id in waits for r in recs
-               if r.name in ("data.pack", "featurize.plan"))
-    assert [r.attributes["inflight"] for r in recs
-            if r.name == "featurize.postprocess"] == [0, 0, 0]
-    assert metrics.counter("engine.borders_fed").value == fed
-    partitions = sorted((r for r in recs if r.name == "featurize.partition"),
-                        key=lambda r: r.start_ns)
-    assert [r.attributes["rows"] for r in partitions] == sizes
-    for a, b in zip(partitions, partitions[1:]):
-        assert a.end_ns <= b.start_ns  # one after the other
 
 
 # ----------------------------------------------------------------------

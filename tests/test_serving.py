@@ -702,26 +702,44 @@ class TestRaggedDispatch:
     def test_ragged_and_padded_outputs_byte_identical(self, monkeypatch):
         """THE equivalence matrix: the same 20 inputs through plain,
         plain+prologue, compiled-fingerprinted, and compiled+prologue
-        endpoints, ragged on then off — every output byte-identical.
-        Dispatch shape (mask vs pad) must never leak into results."""
+        endpoints, ragged on then off.  Dispatch shape (mask vs pad) must
+        never leak into results: byte-identical wherever the arithmetic
+        is the same — the host endpoints across the two modes, and a
+        compiled endpoint across two passes of the one slot-block shape,
+        however the rows fell into blocks.  A compiled endpoint's padded
+        ladder runs OTHER programs (one a bucket), and XLA may tile a
+        (1..8, 4) product differently from the (8, 4) block's: across the
+        modes those agree to float32's last bits, not to the bit."""
         rng = np.random.default_rng(7)
         xs = [rng.standard_normal(self.DIM).astype(np.float32)
               for _ in range(20)]
+        endpoints = ("plain", "plain_pro", "jit", "jit_pro")
+
+        def run(server, ep):
+            futs = [server.submit(x, model_id=ep) for x in xs]
+            return np.stack([
+                np.asarray(f.result(timeout=30.0)) for f in futs])
+
         outs = {}
         for mode in ("1", "0"):
             monkeypatch.setenv("SPARKDL_RAGGED", mode)
             server = self._matrix_server()
             try:
-                per_ep = {}
-                for ep in ("plain", "plain_pro", "jit", "jit_pro"):
-                    futs = [server.submit(x, model_id=ep) for x in xs]
-                    per_ep[ep] = np.stack([
-                        np.asarray(f.result(timeout=30.0)) for f in futs
-                    ]).tobytes()
-                outs[mode] = per_ep
+                outs[mode] = {ep: run(server, ep) for ep in endpoints}
+                if mode == "1":
+                    again = {ep: run(server, ep) for ep in endpoints}
             finally:
                 server.close()
-        assert outs["1"] == outs["0"]
+        for ep in endpoints:
+            assert outs["1"][ep].tobytes() == again[ep].tobytes(), ep
+            assert outs["1"][ep].dtype == outs["0"][ep].dtype
+            if ep.startswith("plain"):
+                assert outs["1"][ep].tobytes() == outs["0"][ep].tobytes(), ep
+            else:
+                # tanh's outputs lie in [-1, 1]: a few float32 ulps of 1
+                np.testing.assert_allclose(
+                    outs["1"][ep], outs["0"][ep], rtol=1e-6, atol=1e-6,
+                    err_msg=ep)
 
     def test_ragged_active_and_fallback_rules(self, monkeypatch):
         """Plain and fingerprinted-compiled endpoints serve ragged;

@@ -518,14 +518,15 @@ def test_lru_cache_eviction_is_fifo_without_touches():
 
 
 # ---------------------------------------------------------------------------
-# mixed-shape device resize through the dispatch window
+# mixed-shape device resize
 # (regression for the host-sync finding sparkdl_check surfaced:
 # _device_resize_timed used to np.asarray each shape group's result
-# before dispatching the next, serializing the groups)
+# before dispatching the next, serializing the groups; it now dispatches
+# every group, then fetches them in order)
 # ---------------------------------------------------------------------------
 
 
-def test_mixed_shape_resize_correct_per_image_through_window():
+def test_mixed_shape_resize_correct_per_image():
     from sparkdl_tpu.transformers.utils import device_resize as _resize_images
 
     rng = np.random.default_rng(7)
@@ -552,19 +553,151 @@ def test_mixed_shape_resize_correct_per_image_through_window():
         )
 
 
-def test_mixed_shape_resize_window_survives_serial_mode(monkeypatch):
-    # SPARKDL_SERIAL_INFERENCE=1 collapses the window to depth 0 —
-    # results must be identical either way
-    from sparkdl_tpu.transformers import utils as tutils
+# ---------------------------------------------------------------------------
+# the one stage seam: every batched DataFrame stage hands ALL partitions of
+# a transform to one loop (transformers.utils.transform_batched)
+# ---------------------------------------------------------------------------
 
-    monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
-    rng = np.random.default_rng(11)
-    images = [rng.uniform(0, 255, (8, 6, 3)).astype(np.float32),
-              rng.uniform(0, 255, (6, 8, 3)).astype(np.float32)]
-    out = tutils.device_resize(images, (4, 4))
-    assert out.shape == (2, 4, 4, 3)
-    for i, img in enumerate(images):
-        want = np.asarray(jax.image.resize(
-            jnp.asarray(img)[None], (1, 4, 4, 3), method="bilinear"
-        ))[0]
-        np.testing.assert_allclose(out[i], want, rtol=1e-5, atol=1e-4)
+# three unequal partitions, one of them empty; in batches of 8 the first
+# has three chunks (the last ragged) and the last has one
+_STAGE_PARTITIONS = [20, 0, 7]
+
+
+def _stage_loader(uri):
+    return np.random.RandomState(int(uri)).rand(6, 6, 3).astype(np.float32)
+
+
+@pytest.fixture
+def stage_case(request, tpu_session, tmp_path, monkeypatch):
+    """``(stage, frame, partitions)`` for the stage named by the parameter:
+    the stage at batch size 8, what makes a DataFrame of given partitions,
+    and the partitions (column dicts) of ``_STAGE_PARTITIONS`` rows."""
+    import types
+
+    name = request.param
+    rng = np.random.RandomState(5)
+    n = sum(_STAGE_PARTITIONS)
+    if name in ("keras-file", "flax-file"):
+        col, values = "uri", [str(i) for i in range(n)]
+    else:
+        col, values = "image", [
+            imageIO.imageArrayToStruct(
+                rng.randint(0, 255, (12, 12, 3)).astype(np.uint8),
+                origin=f"o{i}")
+            for i in range(n)
+        ]
+    parts, lo = [], 0
+    for k in _STAGE_PARTITIONS:
+        parts.append({col: values[lo:lo + k]})
+        lo += k
+
+    if name in ("tf-vector", "tf-image"):
+        from sparkdl_tpu.transformers.tf_image import TFImageTransformer
+
+        stage = TFImageTransformer(
+            inputCol=col, outputCol="out", inputShape=(8, 8), batchSize=8,
+            outputMode=name[3:],
+            graph=XlaFunction.from_callable(
+                lambda x: jnp.tanh(x / 255.0), name="squash"),
+        )
+    elif name == "keras-file":
+        from sparkdl_tpu.transformers.keras_image import (
+            KerasImageFileTransformer,
+        )
+
+        model = keras.Sequential([
+            keras.layers.Input(shape=(6, 6, 3)),
+            keras.layers.Flatten(),
+            keras.layers.Dense(4),
+        ])
+        path = str(tmp_path / "stage_model.keras")
+        model.save(path)
+        stage = KerasImageFileTransformer(
+            inputCol=col, outputCol="out", modelFile=path,
+            imageLoader=_stage_loader, batchSize=8)
+    elif name == "flax-file":
+        import flax.linen as nn
+
+        from sparkdl_tpu.estimators import FlaxImageFileTransformer
+
+        class Head(nn.Module):
+            @nn.compact
+            def __call__(self, x, features_only=False):
+                return nn.Dense(3)(x.reshape(x.shape[0], -1))
+
+        module = Head()
+        variables = module.init(
+            jax.random.PRNGKey(0), np.zeros((1, 6, 6, 3), np.float32))
+        stage = FlaxImageFileTransformer(
+            inputCol=col, outputCol="out", imageLoader=_stage_loader,
+            module=module, variables=variables, batchSize=8)
+    else:
+        from sparkdl_tpu.transformers.named_image import DeepImagePredictor
+        from sparkdl_tpu.transformers.utils import cast_and_resize_on_device
+
+        @jax.jit
+        def forward(x):  # a small program in the CNN's place
+            x = cast_and_resize_on_device(x, (12, 12))
+            return jnp.tanh(x / 255.0).mean(axis=1).reshape(x.shape[0], -1)
+
+        stage = DeepImagePredictor(
+            inputCol=col, outputCol="out", modelName="InceptionV3",
+            batchSize=8)
+        monkeypatch.setattr(
+            stage, "_build_forward",
+            lambda: (forward, types.SimpleNamespace(input_size=(12, 12))))
+    df = tpu_session.createDataFrame([(v,) for v in values[:1]], [col])
+    return stage, df._with_partitions, parts
+
+
+def _cell_bytes(value):
+    """The bytes of one output value: a vector's, or an image struct's."""
+    if hasattr(value, "toArray"):
+        return value.toArray().tobytes()
+    return (value["height"], value["width"], value["mode"],
+            bytes(value["data"]))
+
+
+@pytest.mark.parametrize(
+    "stage_case",
+    ["tf-vector", "tf-image", "keras-file", "flax-file", "predictor"],
+    indirect=True)
+def test_stage_over_partitions_equals_a_call_a_partition(stage_case):
+    """One ``transform`` over three unequal partitions gives, byte for
+    byte and partition for partition, what one ``transform`` a partition
+    gives: a border is metadata, every batch holds the rows it held."""
+    stage, frame, parts = stage_case
+    out = stage.transform(frame(parts))._partitions
+    assert [len(p["out"]) for p in out] == _STAGE_PARTITIONS
+    for part, got in zip(parts, out):
+        (alone,) = stage.transform(frame([part]))._partitions
+        assert list(got) == list(alone)  # the columns, in order
+        assert [_cell_bytes(v) for v in got["out"]] == [
+            _cell_bytes(v) for v in alone["out"]]
+        assert all(got[c] == part[c] for c in part)  # inputs carried over
+
+
+@pytest.mark.parametrize(
+    "stage_case", ["tf-vector", "keras-file", "flax-file"], indirect=True)
+def test_stage_feeds_the_device_across_a_border(stage_case):
+    """The stages that used to build and drain a pipeline a partition: the
+    last partition's batch is dispatched before the first one's rows are
+    built (``engine.borders_fed``), under ``featurize.partition`` roots."""
+    from sparkdl_tpu.obs.trace import tracer
+    from sparkdl_tpu.utils.metrics import metrics
+
+    stage, frame, parts = stage_case
+    borders = metrics.counter("engine.borders").value
+    fed = metrics.counter("engine.borders_fed").value
+    mark = tracer.clock_ns()
+    stage.transform(frame(parts))
+    assert metrics.counter("engine.borders").value - borders == 1
+    assert metrics.counter("engine.borders_fed").value - fed == 1
+    recs = [r for r in tracer.recent() if r.start_ns >= mark]
+    roots = sorted((r for r in recs if r.name == "featurize.partition"),
+                   key=lambda r: r.start_ns)
+    assert [r.attributes["rows"] for r in roots] == [20, 7]
+    post = sorted((r for r in recs if r.name == "featurize.postprocess"),
+                  key=lambda r: r.start_ns)
+    assert [(r.parent_id, r.attributes["inflight"]) for r in post] == [
+        (roots[0].span_id, 1), (roots[1].span_id, 0)]

@@ -217,12 +217,12 @@ def test_package_export_resolves():
 
 class TestServingPipeline:
     """The decode/compute overlap in the serving path (VERDICT r2 weak #2):
-    run_batched_rows pipelines prefetch-thread decode + one-ahead dispatch;
-    results must be identical to the strict serial path."""
+    the one loop pipelines prefetch-thread decode + dispatch ahead of the
+    fetch; results must be the model's, and the same however the rows are
+    cut into calls."""
 
-    def test_pipelined_equals_serial_udf(
+    def test_pipelined_udf_equals_the_keras_oracle(
         self, tpu_session, image_df, keras_model_file, keras_model,
-        monkeypatch,
     ):
         from sparkdl_tpu.udf.keras_image_model import registerKerasImageUDF
 
@@ -233,12 +233,6 @@ class TestServingPipeline:
         image_df.createOrReplaceTempView("pipe_images")
         got = tpu_session.sql("SELECT pipe_udf(image) AS f FROM pipe_images")
         pipelined = np.stack([np.asarray(r.f.toArray()) for r in got.collect()])
-
-        monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
-        got2 = tpu_session.sql("SELECT pipe_udf(image) AS f FROM pipe_images")
-        serial = np.stack([np.asarray(r.f.toArray()) for r in got2.collect()])
-        np.testing.assert_array_equal(pipelined, serial)
-
         want = _oracle(keras_model, rows)
         np.testing.assert_allclose(pipelined, want, rtol=1e-4, atol=1e-5)
 
@@ -279,9 +273,9 @@ class TestServingPipeline:
             )
 
     @pytest.mark.parametrize("case", [
-        "unequal-ragged", "empty-between", "two-plans", "serial"])
+        "unequal-ragged", "empty-between", "two-plans"])
     def test_one_pipeline_over_partitions_equals_a_call_a_partition(
-        self, case, monkeypatch
+        self, case
     ):
         """run_batched_partitions over all partitions gives, bit for bit,
         what one run_batched_rows call a partition gives: a border is
@@ -316,8 +310,6 @@ class TestServingPipeline:
         else:
             partitions = [images(19, [(16, 16)]), images(3, [(16, 16)]),
                           images(9, [(16, 16)])]
-        if case == "serial":
-            monkeypatch.setenv("SPARKDL_SERIAL_INFERENCE", "1")
         shapes = []
 
         @jax.jit
