@@ -41,7 +41,10 @@ def main():
         inputCol="prompt", outputCol="generated", recordCol="record",
         model=model, maskTokenId=255, batchSize=4,
         genLength=8, blockLength=4,
-        denoisingSteps=2,   # quality against steps: a block costs steps + 1 forwards
+        # quality against steps: a block costs `denoisingSteps` passes over
+        # the weights; a finished block enters the cache inside the next
+        # block's first forward, and a row's last block is never committed
+        denoisingSteps=2,
     )
     rows = stage.transform(df).collect()
     for row in rows:

@@ -22,9 +22,12 @@ Three entry points, all with fixed shapes:
 - :func:`prefill` — the keys and values (after norm and RoPE) of a chunk of
   prompts, for the cache; no head;
 - :func:`block_step` — one block of generation against the cache: ``steps``
-  denoising forwards that do not write the cache and fix the most confident
-  masked positions, then one commit forward that writes the block's keys and
-  values.
+  denoising forwards that fix the most confident masked positions.  The
+  first of them also carries the block BEFORE, whose tokens are final,
+  through the layers and writes its keys and values into the cache: a
+  block's commit rides the next block's first forward, so a block costs
+  ``steps`` passes over the weights and a row's last block is never
+  committed (nobody reads it).
 
 Softmaxes, the router and the norms' statistics are float32; everything else
 runs in the weights' dtype (``computeDtype``, bfloat16 on the chip).
@@ -243,23 +246,37 @@ def write_block(cache, new, slot):
 
 
 def _block_forward(params, cfg, cache_k, cache_v, prefix, start, where,
-                   tokens, want_logits: bool):
-    """One forward of a block ``tokens`` [r, B] at positions
+                   tokens, pending=None):
+    """One denoising forward of a block ``tokens`` [r, B] at positions
     ``start + arange(B)``: every position sees the row's cache and the whole
-    block.  Returns (logits or None, the block's k and v [L, r, KV, B, dh],
-    counts [L, E]).
+    block.  Returns (logits [r, B, V], counts [L, E], k, v).
+
+    With ``pending`` [r, B] — the block before, fixed but not yet in the
+    cache — the forward runs over both blocks at ``start - B + arange(2 B)``,
+    one pass over the weights for the two: the pending positions see the
+    cache and their own block (never the new one), the new positions see
+    those and themselves.  ``k`` and ``v`` [L, r, KV, B, dh] are then the
+    pending block's cache entries (else None); the head runs on the new
+    block alone.
 
     A cache entry carries its position in its rotation, so where it lies is
     free: row c's prompt fills slots ``[0, prefix[c])`` and every row's
-    generated blocks follow each other from slot ``where[0]``, up to
-    ``where[1]`` so far — the same slots in every row, so that a commit is
-    one contiguous write."""
+    generated blocks follow each other from slot ``where[0]``; those before
+    the pending one (or before this one) are in the cache, up to slot
+    ``where[1] - B`` (or ``where[1]``) — the same slots in every row, so that
+    a commit is one contiguous write."""
     r, b = tokens.shape
+    lead = 0 if pending is None else b
+    n = lead + b
+    if lead:
+        tokens = jnp.concatenate([pending.astype(tokens.dtype), tokens], 1)
+        of_block = jnp.arange(n, dtype=jnp.int32) // b
+        seen = of_block[None, :] <= of_block[:, None]  # [n, n]: block-causal
     span = cache_k.shape[3]
-    positions = start[:, None] + jnp.arange(b, dtype=jnp.int32)
+    positions = start[:, None] - lead + jnp.arange(n, dtype=jnp.int32)
     slots = jnp.arange(span, dtype=jnp.int32)[None, :]
     cached = (slots < prefix[:, None]) | (
-        (slots >= where[0]) & (slots < where[1]))
+        (slots >= where[0]) & (slots < where[1] - lead))
     layers, experts = _split_layers(params)
 
     def layer(x, scanned):
@@ -268,13 +285,15 @@ def _block_forward(params, cfg, cache_k, cache_v, prefix, start, where,
         ck = jax.lax.dynamic_index_in_dim(cache_k, index, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(cache_v, index, 0, keepdims=False)
         dh = cfg.head_dim
-        # the softmax runs over the cache and the block together, in two
+        # the softmax runs over the cache and the block(s) together, in two
         # parts that share their maximum and their denominator
         past = jnp.einsum("rnkgd,rkmd->rkgnm", q, ck,
                           preferred_element_type=jnp.float32) * (dh ** -0.5)
         past = jnp.where(cached[:, None, None, None, :], past, NEG)
         own = jnp.einsum("rnkgd,rmkd->rkgnm", q, k,
                          preferred_element_type=jnp.float32) * (dh ** -0.5)
+        if lead:
+            own = jnp.where(seen, own, NEG)
         top = jnp.maximum(past.max(-1), own.max(-1))[..., None]
         p_past, p_own = jnp.exp(past - top), jnp.exp(own - top)
         total = p_past.sum(-1) + p_own.sum(-1)
@@ -284,25 +303,28 @@ def _block_forward(params, cfg, cache_k, cache_v, prefix, start, where,
             + jnp.einsum("rkgnm,rmkd->rkgnd", p_own.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
         ) / total[..., None]
-        out = out.transpose(0, 3, 1, 2, 4).reshape(r, b, -1).astype(x.dtype)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(r, n, -1).astype(x.dtype)
         h = x + jnp.dot(out, lp["wo"])
         y, counts = _ffn(cfg, lp, experts, index, h)
-        return y, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), counts)
+        entries = (k[:, :lead].transpose(0, 2, 1, 3),
+                   v[:, :lead].transpose(0, 2, 1, 3)) if lead else ()
+        return y, (counts, *entries)
 
     x = jnp.take(params["embed"], tokens, axis=0)
     index = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
-    x, (k, v, counts) = jax.lax.scan(layer, x, (layers, index))
-    logits = _logits(params, cfg, x) if want_logits else None
-    return logits, k, v, counts
+    x, (counts, *entries) = jax.lax.scan(layer, x, (layers, index))
+    k, v = entries or (None, None)
+    return _logits(params, cfg, x[:, lead:]), counts, k, v
 
 
-def fix_most_confident(logits, tokens, masked, steps_left: int, mask_id: int):
+def fix_most_confident(logits, tokens, masked, steps_left, mask_id: int):
     """One denoising step's decision.  Of the still-masked positions of each
     row, the ``ceil(masked / steps_left)`` whose greedy token has the highest
     softmax probability are fixed, ties to the lower position; the mask
     token itself is never predicted.
 
-    ``logits`` [r, B, V] float32, ``tokens`` [r, B], ``masked`` [r, B] bool.
+    ``logits`` [r, B, V] float32, ``tokens`` [r, B], ``masked`` [r, B] bool;
+    ``steps_left`` an int or an int32 scalar (the step loop's counter).
     Returns (tokens, masked, fixed-now [r, B] bool, log-probability [r, B]
     of each position's greedy token)."""
     b = tokens.shape[1]
@@ -323,42 +345,66 @@ def fix_most_confident(logits, tokens, masked, steps_left: int, mask_id: int):
 
 
 def block_step(params, cfg: SdarMoeConfig, cache_k, cache_v, prefix, start,
-               where, tokens, known, *, steps: int, mask_id: int):
-    """Generate one block for every row against the cache.
+               where, tokens, known, pending=None, *, steps: int,
+               mask_id: int):
+    """Generate one block for every row against the cache, and commit the
+    block before it on the way.
 
     ``prefix`` [r]: the slots each row's prompt fills; ``start`` [r]: the
     block's first position; ``where`` [2]: the slot the generated blocks
-    begin at and the slot this one goes to (see :func:`_block_forward`).
+    begin at and the slot this one will go to (see :func:`_block_forward`).
     ``tokens`` [r, B] holds the known positions' tokens (a prompt's last
     ``P mod B`` open its first block), ``known`` [r, B] says which; the rest
-    start as ``mask_id``.  ``steps`` denoising forwards, then the commit.
+    start as ``mask_id``.  ``pending`` [r, B] holds the final tokens of the
+    block before (the ``record[0]`` of its step), which are not in the cache
+    yet; None for a row's first block.
+
+    ``steps`` denoising forwards and nothing after them.  The first runs
+    over the pending block and this one together and writes the pending
+    block's keys and values into the cache at slot ``where[1] - B``: the
+    entries a forward of that block alone would give, at one pass over the
+    weights for the two.  The others run against the cache, which now holds
+    the pending block.  This block's own tokens are committed by the next
+    step — or never, when no block follows: nobody would read them.
 
     Returns ``(cache_k, cache_v, start + B, where + (0, B), record)`` with
     ``record`` = (tokens [r, B], the step each position was fixed at [r, B]
     (-1: known), the log-probability it was fixed with [r, B] float32,
-    routing counts [L, E] summed over the block's ``steps + 1`` forwards).
+    routing counts [L, E] summed over the ``steps`` forwards, the pending
+    block's tokens among the first's).
     """
     b = tokens.shape[1]
     tokens = jnp.where(known, tokens, mask_id).astype(jnp.int32)
-    masked = ~known
-    fixed_at = jnp.full(tokens.shape, -1, jnp.int32)
-    fixed_lp = jnp.zeros(tokens.shape, jnp.float32)
-    routed = 0
-    for step in range(steps):
-        logits, _, _, counts = _block_forward(
-            params, cfg, cache_k, cache_v, prefix, start, where, tokens, True)
+    state = (tokens, ~known, jnp.full(tokens.shape, -1, jnp.int32),
+             jnp.zeros(tokens.shape, jnp.float32),
+             jnp.zeros((cfg.num_hidden_layers, cfg.num_experts), jnp.int32))
+
+    def denoise(step, state, cache_k, cache_v, pending=None):
+        tokens, masked, fixed_at, fixed_lp, routed = state
+        logits, counts, k, v = _block_forward(
+            params, cfg, cache_k, cache_v, prefix, start, where, tokens,
+            pending)
         tokens, masked, fixed, logprob = fix_most_confident(
             logits, tokens, masked, steps - step, mask_id)
-        fixed_at = jnp.where(fixed, step, fixed_at)
-        fixed_lp = jnp.where(fixed, logprob, fixed_lp)
-        routed = routed + counts
-    _, k, v, counts = _block_forward(
-        params, cfg, cache_k, cache_v, prefix, start, where, tokens, False)
-    cache_k = write_block(cache_k, k, where[1])
-    cache_v = write_block(cache_v, v, where[1])
+        return (tokens, masked, jnp.where(fixed, step, fixed_at),
+                jnp.where(fixed, logprob, fixed_lp), routed + counts), k, v
+
+    first = 0
+    if pending is not None:
+        state, k, v = denoise(0, state, cache_k, cache_v, pending)
+        cache_k = write_block(cache_k, k, where[1] - b)
+        cache_v = write_block(cache_v, v, where[1] - b)
+        first = 1
+    # the forwards of one shape are ONE loop body, not a copy a step: the
+    # executable holds each layer scan (and its kernels) once, and loads
+    # and compiles in that much less time
+    state = jax.lax.fori_loop(
+        first, steps,
+        lambda step, state: denoise(step, state, cache_k, cache_v)[0], state)
+    tokens, _, fixed_at, fixed_lp, routed = state
     return (cache_k, cache_v, start + b,
             where + jnp.array([0, b], where.dtype),
-            (tokens, fixed_at, fixed_lp, routed + counts))
+            (tokens, fixed_at, fixed_lp, routed))
 
 
 # -- what a stage takes -----------------------------------------------------
@@ -408,7 +454,7 @@ class SdarMoeModel:
         return prefill(params, self.config, tokens, lengths, block_length)
 
     def block_step(self, params, cache_k, cache_v, prefix, start, where,
-                   tokens, known, steps: int, mask_id: int):
+                   tokens, known, pending, steps: int, mask_id: int):
         return block_step(params, self.config, cache_k, cache_v, prefix,
-                          start, where, tokens, known, steps=steps,
+                          start, where, tokens, known, pending, steps=steps,
                           mask_id=mask_id)

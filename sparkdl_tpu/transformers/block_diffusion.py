@@ -4,12 +4,18 @@ blocks, over a DataFrame column of prompts (arrays of token ids).
 Every other stage is one dispatch a batch.  This one is one prefill (in
 chunks) and then a chain of block steps over a key/value cache that stays on
 the device, donated from dispatch to dispatch; a step yields up to
-``blockLength`` tokens a row, not one.  Between the batch's placement and
-its last fetch only token ids, the per-block record and scalars cross to the
-host.  The model's weights are program ARGUMENTS, placed once per model
-object (:func:`~sparkdl_tpu.transformers.utils.place_params_once`): an
-executable holds no weight constants, and two models of one config share
-their executables.
+``blockLength`` tokens a row, not one.  A step is ``denoisingSteps``
+forwards and no more: a finished block's tokens stay on the device and ride
+the NEXT step's first forward into the cache (one pass over the weights for
+the commit and the denoising forward together), and a batch's last block is
+never committed, since nobody reads it.  So the block step has two shapes,
+chosen by the block's index: block 0 has nothing pending, every later one
+does.  Between the batch's placement and its last fetch only token ids, the
+per-block record and scalars cross to the host.  The model's weights are
+program ARGUMENTS, placed once per model object
+(:func:`~sparkdl_tpu.transformers.utils.place_params_once`): an executable
+holds no weight constants, and two models of one config share their
+executables.
 
 How a batch is laid out: its rows are sorted by prompt length, longest
 first.  The prompt's whole blocks are prefilled in chunks of about
@@ -24,8 +30,16 @@ one-block dummy rows.
 Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``generate.partition`` (root) > ``generate.plan``, ``engine.place``,
 ``generate.prefill``, ``generate.block``, ``engine.fetch_wait``,
-``generate.postprocess``.  Counters: ``generate.denoise_forwards``,
-``generate.commit_forwards``, ``generate.tokens_fixed`` (per real row),
+``generate.postprocess``; ``generate.block`` carries ``denoise_forwards``,
+``commit_forwards`` (1 where the step committed the block before it, 0 on
+block 0), ``fused`` (the same: that commit shared a forward) and
+``weight_passes``.  Counters, per real row-block:
+``generate.denoise_forwards``,
+``generate.commit_forwards`` (blocks whose final tokens went through the
+layers for the cache: all but a row's last), ``generate.commits_fused``
+(those of them that shared a pass over the weights with a denoising
+forward: all), ``generate.weight_passes`` (passes over the layers' weights:
+``denoisingSteps`` a block), ``generate.tokens_fixed``; and
 ``moe.tokens_routed``, ``moe.tokens_dropped``, ``moe.expert_load_max``,
 ``moe.expert_load_mean`` (from the routing counts that come back with every
 program's result).
@@ -176,22 +190,26 @@ class _Runner:
         return self._program(key, make, args, "sdar_prefill")(*args)
 
     def block_step(self, cache_k, cache_v, prefix, start, where, tokens,
-                   known):
+                   known, pending):
+        """One block for every row.  ``pending``: the block before's final
+        tokens (still on the device), which this step commits inside its
+        first forward; None for a batch's first block, which is the
+        program's other shape."""
         model, steps, mask_id = self.model, self.steps, self.mask_id
 
         def make():
             def sdar_block(params, cache_k, cache_v, prefix, start, where,
-                           tokens, known):
+                           tokens, known, pending):
                 return model.block_step(
                     params, cache_k, cache_v, prefix, start, where, tokens,
-                    known, steps, mask_id)
+                    known, pending, steps, mask_id)
 
             return sdar_block
 
         args = (self.params, cache_k, cache_v, prefix, start, where, tokens,
-                known)
+                known, pending)
         key = ("block", steps, mask_id, tuple(cache_k.shape),
-               tuple(tokens.shape))
+               tuple(tokens.shape), pending is not None)
         return self._program(key, make, args, "sdar_block")(*args)
 
 
@@ -208,10 +226,11 @@ def _runner(model, block: int, steps: int, mask_id: int) -> _Runner:
 class BlockDiffusionTransformer(Transformer, HasInputCol, HasOutputCol):
     """Generates ``genLength`` tokens after every prompt of ``inputCol`` by
     diffusion over blocks of ``blockLength`` positions: each block starts as
-    ``maskTokenId`` at its unknown positions, ``denoisingSteps`` forwards fix
-    the most confident ones (greedy, static low-confidence remasking), one
-    more commits the block to the cache.  ``denoisingSteps`` is the trade of
-    quality against steps: a block costs ``denoisingSteps + 1`` forwards.
+    ``maskTokenId`` at its unknown positions and ``denoisingSteps`` forwards
+    fix the most confident ones (greedy, static low-confidence remasking);
+    the finished block enters the cache inside the next block's first
+    forward.  ``denoisingSteps`` is the trade of quality against steps: a
+    block costs ``denoisingSteps`` passes over the model's weights.
 
     ``outputCol`` gets an int32 array of ``genLength`` tokens a row.
     ``recordCol`` (optional) gets a float64 array [positions, 3] a row —
@@ -375,17 +394,24 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
                     (counts,), meta=("prefill", count * length)))
         start = whole
         fixed = [plan.fixed_in_block(index) for index in range(plan.blocks)]
+        # a block's final tokens stay on the device and ride the next
+        # block's first forward into the cache; the last block's ride
+        # nowhere, nobody would read them
+        pending = None
         for index in range(plan.blocks):
+            chained = int(pending is not None)
             with tracer.boundary(
                 "generate.block", index=index, denoise_forwards=steps,
-                commit_forwards=1, fixed=fixed[index],
+                commit_forwards=chained, fused=chained, weight_passes=steps,
+                fixed=fixed[index],
             ):
                 cache_k, cache_v, start, where, record = runner.block_step(
                     cache_k, cache_v, whole, start, where,
                     first if index == 0 else later,
-                    known if index == 0 else unknown)
+                    known if index == 0 else unknown, pending)
+            pending = record[0]
             landed(window.submit(
-                record, meta=("block", (steps + 1) * rows * block)))
+                record, meta=("block", (steps + chained) * rows * block)))
         landed(window.drain())
     finally:
         window.abandon()
@@ -407,8 +433,13 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
             tokens_out.append(kept[rest:rest + gen, 0].astype(np.int32))
             records_out.append(kept)
     needed = int(plan.blocks_of_row.sum())
+    # a row's last block is never read again, so never committed; every
+    # other commit shared its pass over the weights with a denoising forward
+    committed = needed - len(prompts)
     metrics.counter("generate.denoise_forwards").add(needed * steps)
-    metrics.counter("generate.commit_forwards").add(needed)
+    metrics.counter("generate.commit_forwards").add(committed)
+    metrics.counter("generate.commits_fused").add(committed)
+    metrics.counter("generate.weight_passes").add(needed * steps)
     metrics.counter("generate.tokens_fixed").add(sum(fixed))
     return tokens_out, records_out
 

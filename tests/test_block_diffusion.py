@@ -53,18 +53,19 @@ def _frame(session, prompts, partitions=1):
         numPartitions=partitions)
 
 
-def _teacher_forced(params, prompt, row, steps):
+def _teacher_forced(params, prompt, row, steps, gen=GEN):
     """Every step of every block of a row is what the reference, put in the
-    same state, would have done; returns the forwards that takes."""
+    same state, would have done; returns the forwards that takes: ``steps``
+    a block, and a commit for every block but the last."""
     record = np.asarray(row["record"])
     rest = len(prompt) % BLOCK
-    blocks = -(-(rest + GEN) // BLOCK)
+    blocks = -(-(rest + gen) // BLOCK)
     assert record.shape == (blocks * BLOCK, 3)
     np.testing.assert_array_equal(record[:rest, 1], -1)
     np.testing.assert_array_equal(record[:rest, 0], prompt[len(prompt) - rest:])
     np.testing.assert_array_equal(
-        row["generated"], record[rest:rest + GEN, 0].astype(np.int32))
-    assert len(row["generated"]) == GEN and MASK not in row["generated"]
+        row["generated"], record[rest:rest + gen, 0].astype(np.int32))
+    assert len(row["generated"]) == gen and MASK not in row["generated"]
     for block in range(blocks):
         at = record[block * BLOCK:(block + 1) * BLOCK]
         for step in range(steps):
@@ -81,7 +82,7 @@ def _teacher_forced(params, prompt, row, steps):
                 at[fixed, 2], [logp[i, t] for i, t in zip(fixed, tokens)],
                 atol=1e-5)
         assert (at[:, 1] < steps).all()
-    return blocks * (steps + 1)
+    return blocks * (steps + 1) - 1
 
 
 @pytest.mark.parametrize("steps", [1, 2, 4])
@@ -97,7 +98,7 @@ def test_generation_is_the_references_at_every_step(
         _teacher_forced(params, prompt, row, steps)
         for prompt, row in zip(prompts, rows))
     counted = sum(metrics.counter(c).value - before[c] for c in before)
-    assert counted == forwards  # blocks x (steps + 1), a row
+    assert counted == forwards  # blocks x (steps + 1) - 1, a row
 
 
 def test_mixed_lengths_a_padded_last_batch_and_rows_in_order(
@@ -181,6 +182,7 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert not tracer.enabled
     counters = (
         "generate.denoise_forwards", "generate.commit_forwards",
+        "generate.commits_fused", "generate.weight_passes",
         "generate.tokens_fixed", "moe.tokens_routed", "moe.tokens_dropped",
         "moe.expert_load_max", "moe.expert_load_mean")
     before = {c: metrics.counter(c).value for c in counters}
@@ -200,20 +202,59 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
         assert name in names, name
     blocks = [r for r in inside if r.name == "generate.block"]
     assert [b.attributes["index"] for b in blocks] == [0, 1, 2]
-    assert blocks[0].attributes["denoise_forwards"] == 4
-    assert blocks[0].attributes["commit_forwards"] == 1
+    # block 0 has nothing to commit; each later step commits the block
+    # before it inside its first forward; nothing follows the last
+    assert [b.attributes["commit_forwards"] for b in blocks] == [0, 1, 1]
+    assert [b.attributes["fused"] for b in blocks] == [0, 1, 1]
+    assert all(b.attributes["denoise_forwards"] == 4
+               and b.attributes["weight_passes"] == 4 for b in blocks)
     assert sum(b.attributes["fixed"] for b in blocks) == 9 * BLOCK - 4
     prefill = [r for r in inside if r.name == "generate.prefill"][-1]
     assert prefill.attributes == {"tokens": 8 + 4 + 12, "chunks": 1}
     delta = {c: metrics.counter(c).value - before[c] for c in counters}
-    assert delta["generate.commit_forwards"] == 9  # 3 rows x 3 blocks
+    # 3 rows x 3 blocks, a row's last never committed
+    assert delta["generate.commit_forwards"] == 3 * (3 - 1)
+    assert delta["generate.commits_fused"] == delta["generate.commit_forwards"]
     assert delta["generate.denoise_forwards"] == 9 * 4
+    assert delta["generate.weight_passes"] == 9 * 4
     assert delta["generate.tokens_fixed"] == 9 * BLOCK - 4
-    # prefill 4 rows x 128 padded tokens, 3 blocks x 5 forwards x 4 x 4,
-    # through 2 layers with 2 experts a token — and nothing dropped
-    assert delta["moe.tokens_routed"] == (512 + 3 * 5 * 16) * 2 * 2
+    # prefill 4 rows x 128 padded tokens; block 0 four forwards of 4 x 4
+    # tokens, blocks 1 and 2 five each (the first forward carries the block
+    # before as well); through 2 layers with 2 experts a token — and
+    # nothing dropped
+    assert delta["moe.tokens_routed"] == (512 + (4 + 5 + 5) * 16) * 2 * 2
     assert delta["moe.tokens_dropped"] == 0
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
+
+
+def test_a_batch_of_one_block_dispatches_the_plain_shape_once(
+        tpu_session, params):
+    """A prompt of whole blocks and ``genLength`` <= ``blockLength``: one
+    block, nothing pending before it and nothing after it to commit it."""
+    own = SdarMoeModel(CONFIG, params)  # a runner, and so a program table, of its own
+    prompts = _prompts([8, 4], seed=6)
+    before = {c: metrics.counter(c).value for c in (
+        "generate.commit_forwards", "generate.commits_fused",
+        "generate.weight_passes")}
+    stage = BlockDiffusionTransformer(
+        inputCol="prompt", outputCol="generated", recordCol="record",
+        model=own, genLength=BLOCK, blockLength=BLOCK, denoisingSteps=4,
+        maskTokenId=MASK, batchSize=2)
+    rows = stage.transform(_frame(tpu_session, prompts)).collect()
+    for prompt, row in zip(prompts, rows):
+        assert _teacher_forced(params, prompt, row, 4, gen=BLOCK) == 4
+    root = [r for r in tracer.recent() if r.name == "generate.partition"][-1]
+    blocks = [r for r in tracer.recent()
+              if r.name == "generate.block" and r.parent_id == root.span_id]
+    assert [(b.attributes["index"], b.attributes["commit_forwards"])
+            for b in blocks] == [(0, 0)]
+    delta = {c: metrics.counter(c).value - before[c] for c in before}
+    assert delta == {"generate.commit_forwards": 0,
+                     "generate.commits_fused": 0,
+                     "generate.weight_passes": 2 * 4}
+    (runner,) = vars(own)["_block_diffusion_runners"].values()
+    # the last element of a block program's key: whether a block is pending
+    assert [key[-1] for key in runner.programs if key[0] == "block"] == [False]
 
 
 def test_settings_are_checked(tpu_session, model):

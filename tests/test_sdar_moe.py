@@ -160,22 +160,29 @@ def test_whole_model_logits_bfloat16(params):
         assert gap < 0.05, gap
 
 
+def _prefilled(params, tokens, whole, length, span=32):
+    """The cache [L, r, KV, span, dh] with the prompts' whole blocks in."""
+    k, v, _ = sdar_moe.prefill(
+        params, CFG, tokens[:, :length], jnp.asarray(whole), BLOCK)
+    shape = (2, len(whole), 2, span, 16)
+    return (jnp.zeros(shape).at[:, :, :, :length].set(k),
+            jnp.zeros(shape).at[:, :, :, :length].set(v))
+
+
 @pytest.mark.parametrize("rest", [0, 1, 3])
 def test_block_causal_attention_through_the_cache(params, rest):
     """Prefill of the whole blocks, then one block forward against the
     cache, give the reference's full no-cache forward at the block."""
     tokens, _ = _rows(2)
     whole = np.array([12, 12, 4], np.int32)
-    k, v, _ = sdar_moe.prefill(params, CFG, tokens[:, :12], whole, BLOCK)
-    span, base = 32, 16
-    cache_k = jnp.zeros((2, 3, 2, span, 16)).at[:, :, :, :12].set(k)
-    cache_v = jnp.zeros((2, 3, 2, span, 16)).at[:, :, :, :12].set(v)
+    cache_k, cache_v = _prefilled(params, tokens, whole, 12)
+    base = 16
     block = np.full((3, BLOCK), MASK, np.int32)
     for row in range(3):
         block[row, :rest] = tokens[row, whole[row]:whole[row] + rest]
     logits, *_ = sdar_moe._block_forward(
         params, CFG, cache_k, cache_v, jnp.asarray(whole), jnp.asarray(whole),
-        jnp.array([base, base], jnp.int32), jnp.asarray(block), True)
+        jnp.array([base, base], jnp.int32), jnp.asarray(block))
     for row in range(3):
         sequence = list(tokens[row, :whole[row]]) + list(block[row])
         want = reference.forward(
@@ -185,30 +192,109 @@ def test_block_causal_attention_through_the_cache(params, rest):
 
 
 def test_a_committed_block_is_seen_by_the_next(params):
+    """A plain step, then a chained one that commits the first block on its
+    way, then a forward of a third block with the second pending: what that
+    sees through the cache (block 0) and beside itself (block 1) is the
+    reference's whole sequence."""
     tokens, _ = _rows(5)
     whole = jnp.array([8, 8, 8], jnp.int32)
-    k, v, _ = sdar_moe.prefill(params, CFG, tokens[:, :8], whole, BLOCK)
-    cache_k = jnp.zeros((2, 3, 2, 24, 16)).at[:, :, :, :8].set(k)
-    cache_v = jnp.zeros((2, 3, 2, 24, 16)).at[:, :, :, :8].set(v)
+    cache_k, cache_v = _prefilled(params, tokens, whole, 8, span=24)
     where = jnp.array([8, 8], jnp.int32)
     unknown = jnp.zeros((3, BLOCK), bool)
-    first = sdar_moe.block_step(
-        params, CFG, cache_k, cache_v, whole, whole, where,
-        jnp.zeros((3, BLOCK), jnp.int32), unknown, steps=2, mask_id=MASK)
-    cache_k, cache_v, start, where, (fixed, at, _, counts) = first
-    assert (np.asarray(at) >= 0).all() and not (np.asarray(fixed) == MASK).any()
-    assert int(counts.sum()) == 3 * 2 * 3 * BLOCK * 2  # forwards x layers x pairs
+    blank = jnp.zeros((3, BLOCK), jnp.int32)
+    step = dict(steps=2, mask_id=MASK)
+    cache_k, cache_v, start, where, (first, at, _, counts) = sdar_moe.block_step(
+        params, CFG, cache_k, cache_v, whole, whole, where, blank, unknown,
+        **step)
+    assert (np.asarray(at) >= 0).all() and not (np.asarray(first) == MASK).any()
+    pairs = 3 * BLOCK * 2 * 2  # of one forward: tokens x layers x experts each
+    assert int(counts.sum()) == 2 * pairs
     np.testing.assert_array_equal(where, [8, 12])
+    cache_k, cache_v, start, where, (second, _, _, counts) = sdar_moe.block_step(
+        params, CFG, cache_k, cache_v, whole, start, where, blank, unknown,
+        first, **step)
+    assert int(counts.sum()) == 3 * pairs  # the first forward carried two blocks
+    np.testing.assert_array_equal(where, [8, 16])
+    np.testing.assert_array_equal(start, whole + 2 * BLOCK)
     probe = jnp.full((3, BLOCK), MASK, jnp.int32)
-    logits, *_ = sdar_moe._block_forward(
-        params, CFG, cache_k, cache_v, whole, start, where, probe, True)
+    logits, _, k, _ = sdar_moe._block_forward(
+        params, CFG, cache_k, cache_v, whole, start, where, probe, second)
+    assert logits.shape == (3, BLOCK, 256) and k.shape == (2, 3, 2, BLOCK, 16)
     for row in range(3):
         n = int(whole[row])
-        sequence = list(tokens[row, :n]) + list(np.asarray(fixed[row])) \
-            + [MASK] * BLOCK
+        sequence = list(tokens[row, :n]) + list(np.asarray(first[row])) \
+            + list(np.asarray(second[row])) + [MASK] * BLOCK
         want = reference.forward(params, CONFIG, sequence, BLOCK,
-                                 range(n + BLOCK, n + 2 * BLOCK))
+                                 range(n + 2 * BLOCK, n + 3 * BLOCK))
         np.testing.assert_allclose(logits[row], want, atol=1e-5)
+
+
+@pytest.mark.parametrize("rest", [0, 1, 2, 3])
+def test_a_chained_step_is_the_commit_and_then_the_step(params, rest):
+    """The commit rides the next block's first forward: the entries it
+    writes are a separate forward's of the finished block (here the
+    whole-sequence prefill's), what the step then fixes is what it fixes
+    against a cache that was committed beforehand, and the pending block
+    never sees the new one.  The last row is a batch's padding: no prompt,
+    one block of known zeros."""
+    tokens = np.concatenate([_rows(6)[0], np.zeros((1, 16), np.int32)])
+    whole = np.array([8, 8, 4, 0], np.int32)
+    cache_k, cache_v = _prefilled(params, tokens, whole, 8)
+    first = np.zeros((4, BLOCK), np.int32)
+    known = np.zeros((4, BLOCK), bool)
+    for row in range(3):
+        first[row, :rest] = tokens[row, whole[row]:whole[row] + rest]
+        known[row, :rest] = True
+    known[3] = True
+    base = 16
+    step = dict(steps=2, mask_id=MASK)
+    args = (jnp.asarray(whole), jnp.asarray(whole),
+            jnp.array([base, base], jnp.int32))
+    plain_k, plain_v, start, where, (pending, *_) = sdar_moe.block_step(
+        params, CFG, cache_k, cache_v, *args, jnp.asarray(first),
+        jnp.asarray(known), **step)
+    np.testing.assert_array_equal(plain_k, cache_k)  # block 0 writes nothing
+    np.testing.assert_array_equal(pending[3], 0)
+    blank, unknown = jnp.zeros_like(first), jnp.zeros_like(known)
+    got_k, got_v, _, _, got = sdar_moe.block_step(
+        params, CFG, plain_k, plain_v, args[0], start, where, blank, unknown,
+        pending, **step)
+
+    # the separate commit: the finished block's entries in a forward of
+    # the whole sequence so far, row by row at its own position
+    sequence = np.zeros((4, 12), np.int32)
+    for row in range(4):
+        sequence[row, :whole[row]] = tokens[row, :whole[row]]
+        sequence[row, whole[row]:whole[row] + BLOCK] = pending[row]
+    k, v, _ = sdar_moe.prefill(
+        params, CFG, jnp.asarray(sequence), jnp.asarray(whole + BLOCK), BLOCK)
+    want_k, want_v = (jnp.stack(
+        [e[:, row, :, whole[row]:whole[row] + BLOCK] for row in range(4)], 1)
+        for e in (k, v))
+    for got_cache, want, before in ((got_k, want_k, plain_k),
+                                    (got_v, want_v, plain_v)):
+        np.testing.assert_allclose(
+            got_cache[:, :, :, base:base + BLOCK], want, atol=1e-6)
+        kept = np.ones(32, bool)
+        kept[base:base + BLOCK] = False
+        np.testing.assert_array_equal(
+            got_cache[:, :, :, kept], before[:, :, :, kept])
+    committed = (sdar_moe.write_block(plain_k, want_k, base),
+                 sdar_moe.write_block(plain_v, want_v, base))
+    *_, want = sdar_moe.block_step(
+        params, CFG, *committed, args[0], start, where, blank, unknown, **step)
+    np.testing.assert_array_equal(got[0], want[0])  # tokens
+    np.testing.assert_array_equal(got[1], want[1])  # the step each was fixed at
+    np.testing.assert_allclose(got[2], want[2], atol=1e-5)
+    assert int(got[3].sum()) == int(want[3].sum()) * 3 // 2
+
+    # other known tokens in the NEW block: the pending block's entries stay
+    other = np.random.default_rng(rest).integers(0, MASK, (4, BLOCK))
+    other_k, other_v, *_ = sdar_moe.block_step(
+        params, CFG, plain_k, plain_v, args[0], start, where,
+        jnp.asarray(other, jnp.int32), jnp.ones_like(known), pending, **step)
+    np.testing.assert_allclose(other_k, got_k, atol=1e-6)
+    np.testing.assert_allclose(other_v, got_v, atol=1e-6)
 
 
 @pytest.mark.parametrize("steps_left", [1, 2, 3, 4])

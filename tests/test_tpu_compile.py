@@ -268,33 +268,65 @@ def test_grouped_expert_product_compiles(
         _fits(compiled)
 
 
-def test_sdar_block_step_compiles_and_fits(
-        one_chip, no_compile_cache, as_on_the_chip):
-    """The cell's block step (256 rows, 640 slots, 4 + 1 forwards) at the
+@pytest.fixture(scope="module")
+def sdar_block_programs():
+    """The block step's compiled shapes, kept for the module: the chained
+    case compares itself with the plain one."""
+    return {}
+
+
+def _sdar_block_program(kept, chained, one_chip):
+    """The cell's block step (256 rows, 640 slots, 4 forwards) at the
     published widths, two layers deep — the layers are scanned, so depth
-    changes the arguments' bytes and not the program — and the bytes of the
-    six-layer cell reckoned from it."""
+    changes the arguments' bytes and not the program."""
     from sparkdl_tpu.models import sdar_moe
 
+    if chained in kept:
+        return kept[chained]
     cfg = sdar_moe.SdarMoeConfig(num_hidden_layers=2, **SDAR_WIDTHS)
 
     def spec(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     cache = spec((2, 256, 4, 640, 128), jnp.bfloat16)
-    compiled = jax.jit(
-        lambda p, ck, cv, prefix, start, where, tokens, known:
+    kept[chained] = jax.jit(
+        lambda p, ck, cv, prefix, start, where, tokens, known, pending:
         sdar_moe.block_step(p, cfg, ck, cv, prefix, start, where, tokens,
-                            known, steps=4, mask_id=151669),
+                            known, pending, steps=4, mask_id=151669),
         donate_argnums=(1, 2),
     ).lower(
         _sdar_shapes(cfg, one_chip), cache, cache, spec((256,)),
         spec((256,)), spec((2,)), spec((256, 4)), spec((256, 4), jnp.bool_),
+        spec((256, 4)) if chained else None,
     ).compile()
+    return kept[chained]
+
+
+@pytest.mark.parametrize("chained", [False, True], ids=["plain", "chained"])
+def test_sdar_block_step_compiles_and_fits(
+        chained, sdar_block_programs, one_chip, no_compile_cache,
+        as_on_the_chip):
+    """Both shapes of the cell's block step: a batch's first block, and
+    every later one, whose first forward carries the block before it as well
+    (2,048 tokens) and writes it into the cache.  The bytes of the six-layer
+    cell are reckoned from the two-layer compile."""
+    compiled = _sdar_block_program(sdar_block_programs, chained, one_chip)
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and text.count("while(") >= 5
+    # a scan over the layers (its carry is the residual stream [rows,
+    # positions, 2048]) with its three grouped products exists once a SHAPE
+    # of forward, inside the loop over the denoising steps, and not once a
+    # step: the plain forward's, and a chained step's first, which carries
+    # both blocks' positions.  No scan for a commit.
+    scans = re.findall(
+        r"= \(s32\[\]\S* bf16\[256,([48]),2048\]\S* [^\n]* while\(", text)
+    assert sorted(scans) == ["4", "8"][:1 + int(chained)]
+    assert text.count("tpu_custom_call") == 3 * len(scans)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 2 * 2 * 256 * 4 * 640 * 128 * 2  # donated
     layer = 2 * 623_087_872 + 2 * 256 * 4 * 640 * 128 * 2 * 2  # weights, cache
     six = m.argument_size_in_bytes + 4 * layer + m.temp_size_in_bytes
     assert 0.25 * V5E_HBM_BYTES < six < 0.85 * V5E_HBM_BYTES, six
+    if chained:  # the wider forward's room
+        plain = _sdar_block_program(sdar_block_programs, False, one_chip)
+        assert (m.temp_size_in_bytes
+                - plain.memory_analysis().temp_size_in_bytes) <= 0.3e9
