@@ -47,11 +47,16 @@ from ci.sparkdl_check.core import FileContext, Rule, rule
 #: "generate" (denoising and commit forwards, tokens fixed) and "moe"
 #: (tokens routed and dropped, expert load) joined with the ISSUE-31
 #: block-diffusion stage over a sparse-expert decoder.
+#: "ar_generate" (prefill tokens and pads, decode steps, dispatches and
+#: expert reads, tokens generated) and "ssm" (bytes of recurrent state
+#: a batch holds) joined with the ISSUE-35 autoregressive stage over a
+#: state-space / attention hybrid.
 ALLOWED_PREFIXES = (
     "sparkdl", "data", "serving", "resilience", "estimator", "engine",
     "streaming", "slo", "ts", "supervisor", "router", "wire",
     "rollout", "tenant", "fleet", "replica", "faultnet", "diag",
     "profile", "cache", "decode", "batcher", "csql", "generate", "moe",
+    "ar_generate", "ssm",
 )
 
 METRIC_FACTORIES = {"counter", "timer", "gauge", "histogram"}

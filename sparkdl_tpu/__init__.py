@@ -39,6 +39,7 @@ _EXPORTS = {
     "TFTransformer": "sparkdl_tpu.transformers.tf_tensor",
     "KerasTransformer": "sparkdl_tpu.transformers.keras_tensor",
     "BlockDiffusionTransformer": "sparkdl_tpu.transformers.block_diffusion",
+    "AutoregressiveTransformer": "sparkdl_tpu.transformers.ar_generate",
     "KerasImageFileEstimator": "sparkdl_tpu.estimators.keras_image_file_estimator",
     "registerKerasImageUDF": "sparkdl_tpu.udf.keras_image_model",
     "makeGraphUDF": "sparkdl_tpu.graph.tensorframes_udf",

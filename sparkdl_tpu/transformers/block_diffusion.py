@@ -47,17 +47,20 @@ program's result).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, List, Optional
 
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from sparkdl_tpu.ml.base import Transformer
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
-from sparkdl_tpu.transformers.utils import place_params_once
+from sparkdl_tpu.transformers.generation import (
+    ProgramRunner,
+    count_routing,
+    runner_for,
+)
 
 #: chunk lengths and the cache's span are multiples of this (one compile
 #: per distinct shape; the TPU tiles the span by it)
@@ -126,45 +129,21 @@ class BatchPlan:
         return int(unknown[live].sum())
 
 
-class _Runner:
-    """One model's placed params, programs and spare caches."""
+class _Runner(ProgramRunner):
+    """One model's placed params, programs and spare caches
+    (:class:`~sparkdl_tpu.transformers.generation.ProgramRunner`) at one
+    generation setting."""
 
     def __init__(self, model, block: int, steps: int, mask_id: int):
-        self.model, self.block, self.steps, self.mask_id = (
-            model, block, steps, mask_id)
-        self.device = jax.local_devices()[0]
-        self.params = place_params_once(model, model.params, self.device)
-        self.programs: Dict[Any, Any] = {}
-        self.caches: Dict[Any, Any] = {}
-
-    def place(self, array):
-        return jax.device_put(array, self.device)
+        super().__init__(model)
+        self.block, self.steps, self.mask_id = block, steps, mask_id
 
     def cache(self, rows: int, span: int):
+        """The (key, value) cache pair; what a cache holds past a row's
+        length is never read."""
         shape, dtype = self.model.cache_spec(rows, span)
-        held = self.caches.pop(shape, None)
-        if held is None:
-            # what a cache holds past a row's length is never read
-            held = (jnp.zeros(shape, dtype, device=self.device),
-                    jnp.zeros(shape, dtype, device=self.device))
-        return held
-
-    def keep(self, cache_k, cache_v):
-        self.caches[tuple(cache_k.shape)] = (cache_k, cache_v)
-
-    def _program(self, key, make_fn, example, name):
-        """The engine's executable for ``key``; ``make_fn`` builds the
-        function only when this runner has not resolved it yet.  The cache
-        (arguments 1 and 2) is donated, the weights are not."""
-        from sparkdl_tpu.engine import engine
-
-        handle = self.programs.get(key)
-        if handle is None:
-            handle = self.programs[key] = engine.program(
-                make_fn(), example, donate=(1, 2), name=name,
-                fingerprint=f"{self.model.fingerprint}:{name}:{key}",
-            )
-        return handle
+        spec = jax.ShapeDtypeStruct(shape, dtype)
+        return self.take_state((spec, spec))
 
     def prefill(self, cache_k, cache_v, tokens, whole, first_row, count,
                 length):
@@ -187,7 +166,8 @@ class _Runner:
                 np.int32(first_row))
         key = ("prefill", count, length, block, tuple(cache_k.shape),
                tuple(tokens.shape))
-        return self._program(key, make, args, "sdar_prefill")(*args)
+        return self.program(
+            key, make, args, "sdar_prefill", donate=(1, 2))(*args)
 
     def block_step(self, cache_k, cache_v, prefix, start, where, tokens,
                    known, pending):
@@ -210,17 +190,15 @@ class _Runner:
                 known, pending)
         key = ("block", steps, mask_id, tuple(cache_k.shape),
                tuple(tokens.shape), pending is not None)
-        return self._program(key, make, args, "sdar_block")(*args)
+        return self.program(
+            key, make, args, "sdar_block", donate=(1, 2))(*args)
 
 
 def _runner(model, block: int, steps: int, mask_id: int) -> _Runner:
-    """One runner per generation setting, kept ON the model object: the
-    placed weights, the programs and the spare cache live and die with it."""
-    held = vars(model).setdefault("_block_diffusion_runners", {})
-    key = (block, steps, mask_id)
-    if key not in held:
-        held[key] = _Runner(model, block, steps, mask_id)
-    return held[key]
+    """One runner per generation setting, kept ON the model object."""
+    return runner_for(
+        model, "_block_diffusion_runners", (block, steps, mask_id),
+        lambda: _Runner(model, block, steps, mask_id))
 
 
 class BlockDiffusionTransformer(Transformer, HasInputCol, HasOutputCol):
@@ -379,8 +357,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
 
     def landed(pairs):
         for result, (kind, routed_tokens) in pairs:
-            _count_routing(result[-1], routed_tokens,
-                           runner.model.experts_per_token)
+            count_routing(result[-1], routed_tokens,
+                          runner.model.experts_per_token)
             if kind == "block":
                 fetched.append(result)
 
@@ -415,7 +393,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
         landed(window.drain())
     finally:
         window.abandon()
-    runner.keep(cache_k, cache_v)
+    runner.keep_state((cache_k, cache_v))
 
     with tracer.boundary("generate.postprocess", rows=len(prompts)):
         # [rows, blocks * B] in the plan's order, then back to the input's
@@ -442,18 +420,3 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
     metrics.counter("generate.weight_passes").add(needed * steps)
     metrics.counter("generate.tokens_fixed").add(sum(fixed))
     return tokens_out, records_out
-
-
-def _count_routing(counts, tokens: int, per_token: int) -> None:
-    """``counts`` [L, E]: the (token, expert) pairs each expert of each
-    layer got in one program's forwards, through each layer of which
-    ``tokens`` tokens went with ``per_token`` experts each."""
-    from sparkdl_tpu.utils.metrics import metrics
-
-    counts = np.asarray(counts)
-    routed = int(counts.sum())
-    metrics.counter("moe.tokens_routed").add(routed)
-    metrics.counter("moe.tokens_dropped").add(
-        tokens * counts.shape[0] * per_token - routed)
-    metrics.counter("moe.expert_load_max").add(float(counts.max()))
-    metrics.counter("moe.expert_load_mean").add(float(counts.mean()))

@@ -1,0 +1,274 @@
+"""``AutoregressiveTransformer`` over a DataFrame of prompts, at a tiny size
+on the CPU: every generated position teacher-forced against the plain
+reference (``chipbench/reference/granite_hybrid.py``), the layout of a
+batch's prefill, the state's reuse, the spans and the counters.
+
+float32 weights, and the programs compiled at ``highest`` precision: program
+and reference differ by rounding order only, so a log-probability (near
+-4.57: an ulp of 4.8e-7) agrees to 2e-6.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+
+from chipbench.reference import granite_hybrid as reference
+from sparkdl_tpu import AutoregressiveTransformer
+from sparkdl_tpu.models.granite_hybrid import GraniteHybridModel
+from sparkdl_tpu.obs.trace import tracer
+from sparkdl_tpu.transformers import ar_generate
+from sparkdl_tpu.transformers.ar_generate import SegmentPlan
+from sparkdl_tpu.utils.metrics import metrics
+
+CONFIG = dict(
+    vocab_size=96, hidden_size=32, num_hidden_layers=4,
+    layer_types=["mamba", "attention", "mamba", "mamba"],
+    num_attention_heads=4, num_key_value_heads=2, num_local_experts=8,
+    num_experts_per_tok=2, intermediate_size=16, shared_intermediate_size=24,
+    mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16, mamba_d_conv=4,
+    mamba_expand=2, mamba_n_groups=1, mamba_chunk_size=8,
+    attention_multiplier=0.125, embedding_multiplier=12,
+    residual_multiplier=0.22, logits_scaling=16, rms_norm_eps=1e-5,
+)
+GEN = 6
+COUNTERS = (
+    "ar_generate.prefill_tokens", "ar_generate.prefill_pad_tokens",
+    "ar_generate.decode_steps", "ar_generate.decode_dispatches",
+    "ar_generate.decode_expert_reads", "ar_generate.tokens_generated", "ssm.state_bytes", "moe.tokens_routed",
+    "moe.tokens_dropped", "moe.expert_load_max", "moe.expert_load_mean")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return reference.make_params(CONFIG, 41, "float32")
+
+
+@pytest.fixture(scope="module")
+def model(params):
+    return GraniteHybridModel(CONFIG, params)
+
+
+@pytest.fixture(autouse=True)
+def exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(autouse=True)
+def small_dispatches(monkeypatch):
+    """The stage's one prefill shape and its decode dispatch are constants
+    (128 x 16, 8 steps); at this size 8 x 2 and 2 steps put several
+    segments, several dispatches and a left-over decode shape into prompts
+    of a few tokens."""
+    monkeypatch.setattr(ar_generate, "SEGMENT_LENGTH", 8)
+    monkeypatch.setattr(ar_generate, "SEGMENT_ROWS", 2)
+    monkeypatch.setattr(ar_generate, "DECODE_STEPS", 2)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 96, n).astype(np.int32) for n in lengths]
+
+
+def _stage(model, batch=4, gen=GEN):
+    return AutoregressiveTransformer(
+        inputCol="prompt", outputCol="generated", recordCol="record",
+        model=model, genLength=gen, batchSize=batch)
+
+
+def _frame(session, prompts, partitions=1):
+    return session.createDataFrame(
+        list(enumerate(prompts)), ["rowId", "prompt"],
+        numPartitions=partitions)
+
+
+def _teacher_forced(params, prompt, row, gen=GEN):
+    """Every generated token is the reference's likeliest, given the prompt
+    and the tokens before it, with the reference's log-probability."""
+    record = np.asarray(row["record"])
+    assert record.shape == (gen, 2) and record.dtype == np.float64
+    tokens = np.asarray(row["generated"])
+    assert tokens.dtype == np.int32 and tokens.shape == (gen,)
+    np.testing.assert_array_equal(record[:, 0], tokens)
+    want = reference.teacher_forced(params, CONFIG, prompt, tokens)
+    np.testing.assert_array_equal(want.argmax(axis=-1), tokens)
+    np.testing.assert_allclose(record[:, 1], want.max(axis=-1), atol=2e-6)
+
+
+def test_generation_is_the_references_at_every_position(
+        tpu_session, params, model):
+    """Rows of different lengths in one batch: one shorter than a segment of
+    8, one longer than three, one a whole number of segments."""
+    prompts = _prompts([5, 30, 16, 9], seed=1)
+    rows = _stage(model).transform(_frame(tpu_session, prompts)).collect()
+    assert [r["rowId"] for r in rows] == [0, 1, 2, 3]
+    for prompt, row in zip(prompts, rows):
+        np.testing.assert_array_equal(row["prompt"], prompt)
+        _teacher_forced(params, prompt, row)
+
+
+def test_a_short_last_batch_and_a_second_batch_untouched_by_the_first(
+        tpu_session, params, model):
+    lengths = [5, 18, 3, 12, 9, 16, 2]  # batches of 4 and 3 (+1 dummy row)
+    prompts = _prompts(lengths, seed=7)
+    rows = _stage(model).transform(
+        _frame(tpu_session, prompts, partitions=1)).collect()
+    assert [r["rowId"] for r in rows] == list(range(len(prompts)))
+    for prompt, row in zip(prompts, rows):
+        _teacher_forced(params, prompt, row)
+    # the second batch took the first's state from the pool: a row's result
+    # does not depend on the batch it sat in, nor on what sat there before
+    (runner,) = vars(model)["_ar_generate_runners"].values()
+    assert len(runner.states) == 1
+    alone = _stage(model, batch=1).transform(
+        _frame(tpu_session, prompts[5:6])).collect()[0]
+    np.testing.assert_array_equal(alone["generated"], rows[5]["generated"])
+    np.testing.assert_allclose(alone["record"], rows[5]["record"], atol=2e-6)
+
+
+def test_two_partitions_and_no_record_column(tpu_session, params, model):
+    prompts = _prompts([4, 11, 7], seed=3)
+    plain = AutoregressiveTransformer(
+        inputCol="prompt", outputCol="generated", model=model, genLength=3,
+        batchSize=2)
+    rows = plain.transform(_frame(tpu_session, prompts, partitions=2)).collect()
+    assert sorted(r["rowId"] for r in rows) == [0, 1, 2]
+    for row in rows:
+        assert "record" not in row.asDict() and len(row["generated"]) == 3
+        want = reference.teacher_forced(
+            params, CONFIG, prompts[row["rowId"]], row["generated"])
+        np.testing.assert_array_equal(want.argmax(-1), row["generated"])
+
+
+def test_one_token_a_row_needs_no_decode(tpu_session, params, model):
+    prompts = _prompts([9, 3], seed=4)
+    before = metrics.counter("ar_generate.decode_dispatches").value
+    rows = _stage(model, batch=2, gen=1).transform(
+        _frame(tpu_session, prompts)).collect()
+    for prompt, row in zip(prompts, rows):
+        _teacher_forced(params, prompt, row, gen=1)
+    assert metrics.counter("ar_generate.decode_dispatches").value == before
+
+
+def test_the_plan_of_a_batch():
+    prompts = _prompts([5, 30, 16, 9])
+    plan = SegmentPlan(prompts, rows=6, segment=8, count=3, gen=GEN)
+    assert plan.real_tokens == 60
+    # rows 4 and 5 pad the batch: one token each
+    pairs = [[(int(r), int(s), int(n)) for r, s, n in zip(*arrays[1:])]
+             for arrays, _ in plan.dispatches]
+    assert pairs == [
+        # the rows with most still to go first: 1 (4 segments), 2 (2), 3 (2)
+        [(1, 0, 8), (2, 0, 8), (3, 0, 8)],
+        [(1, 8, 8), (0, 0, 5), (2, 8, 8)],
+        [(1, 16, 8), (3, 8, 1), (4, 0, 1)],
+        # a row's segments follow each other, never two in one dispatch:
+        # the spare pair names row 6, which nobody is
+        [(1, 24, 6), (5, 0, 1), (6, 0, 0)],
+    ]
+    assert [last for _, last in plan.dispatches] == [
+        [], [(1, 0), (2, 2)], [(1, 3), (2, 4)], [(0, 1), (1, 5)]]
+    assert plan.pad_tokens == 4 * 3 * 8 - 60
+    tokens = plan.dispatches[2][0][0]
+    np.testing.assert_array_equal(tokens[0], prompts[1][16:24])
+    np.testing.assert_array_equal(tokens[1], [prompts[3][8]] + [0] * 7)
+    # as many dispatches as the pairs fill (13 + 7 x 2, two a dispatch) or
+    # as the longest row has segments (13), whichever is more
+    long = SegmentPlan(_prompts([100] + [9] * 7), 8, 8, 2, GEN)
+    assert len(long.dispatches) == 14
+    assert len(SegmentPlan(_prompts([100] + [9] * 3), 4, 8, 2, GEN)
+               .dispatches) == 13
+    # the prompts' part of the span in steps of 512, the generated in 128
+    assert plan.span == 512 + 128
+    assert SegmentPlan(_prompts([513]), 1, 8, 1, gen=130).span == 1024 + 256
+    with pytest.raises(ValueError, match="empty prompt"):
+        SegmentPlan(_prompts([4, 0]), 2, 8, 2, GEN)
+
+
+def test_spans_and_counters_exist_without_tracing(tpu_session, model):
+    assert not tracer.enabled
+    before = {c: metrics.counter(c).value for c in COUNTERS}
+    prompts = _prompts([9, 6, 13], seed=2)
+    _stage(model, batch=4).transform(_frame(tpu_session, prompts)).collect()
+    mine = tracer.recent()
+    root = [r for r in mine if r.name == "ar_generate.partition"][-1]
+    assert root.parent_id is None
+    assert root.attributes == {
+        "rows": 3, "batches": 1, "prompt_tokens": 28,
+        "generated_tokens": 3 * GEN}
+    inside = [r for r in mine if r.parent_id == root.span_id]
+    names = [r.name for r in inside]
+    for name in ("ar_generate.plan", "engine.place", "ar_generate.prefill",
+                 "ar_generate.decode", "engine.fetch_wait",
+                 "ar_generate.postprocess"):
+        assert name in names, name
+    # pairs: two segments of rows 0 and 2, one of row 1 and of the dummy
+    # row (its one token): three dispatches of 2 x 8 positions
+    prefill = [r for r in inside if r.name == "ar_generate.prefill"][-1]
+    assert prefill.attributes == {
+        "tokens": 28, "pad_tokens": 3 * 16 - 28, "segments": 3}
+    decodes = [r for r in inside if r.name == "ar_generate.decode"]
+    assert [d.attributes for d in decodes] == [
+        {"steps": 2, "rows": 4}, {"steps": 2, "rows": 4},
+        {"steps": 1, "rows": 4}]
+    delta = {c: metrics.counter(c).value - before[c] for c in COUNTERS}
+    assert delta["ar_generate.prefill_tokens"] == 28
+    assert delta["ar_generate.prefill_pad_tokens"] == 20
+    assert delta["ar_generate.decode_steps"] == GEN - 1
+    assert delta["ar_generate.decode_dispatches"] == 3
+    assert delta["ar_generate.tokens_generated"] == 3 * GEN
+    # 4 rows x 2 experts a token: 2 to 8 of a layer's 8 experts a step
+    assert 5 * 4 * 2 <= delta["ar_generate.decode_expert_reads"] <= 5 * 4 * 8
+    # 3 Mamba layers x 4 rows of a float32 [8, 8, 16] state and a float32
+    # [3, 96] conv window (the weights' dtype)
+    assert delta["ssm.state_bytes"] == 3 * 4 * (8 * 8 * 16 * 4 + 3 * 96 * 4)
+    # 48 prefill positions and 5 steps of 4 rows through 4 layers with 2
+    # experts a token, pads and the dummy row routed like any other — and
+    # nothing dropped
+    assert delta["moe.tokens_routed"] == (48 + 5 * 4) * 4 * 2
+    assert delta["moe.tokens_dropped"] == 0
+    assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
+
+
+def test_programs_and_state_are_shared_across_models_of_one_config(
+        tpu_session, params):
+    from sparkdl_tpu.engine import engine
+
+    prompts = _prompts([6, 9], seed=5)
+    frame = _frame(tpu_session, prompts)
+    first = GraniteHybridModel(CONFIG, params)
+    _stage(first, batch=2).transform(frame).collect()
+    compiled = metrics.counter("engine.cache_miss").value
+    other = reference.make_params(CONFIG, 43, "float32")
+    second = GraniteHybridModel(CONFIG, other)
+    rows = _stage(second, batch=2).transform(frame).collect()
+    # weights are arguments: other weights of the same config compile nothing
+    assert metrics.counter("engine.cache_miss").value == compiled
+    for prompt, row in zip(prompts, rows):
+        _teacher_forced(other, prompt, row)
+    (runner,) = vars(second)["_ar_generate_runners"].values()
+    assert sorted(key[0] for key in runner.programs) == [
+        "decode", "decode", "prefill"]  # steps 2 and the 1 left over
+    assert engine is not None
+
+
+def test_settings_are_checked(tpu_session, model):
+    frame = _frame(tpu_session, _prompts([5]))
+    with pytest.raises(ValueError, match="at least 1"):
+        _stage(model, gen=0).transform(frame)
+    with pytest.raises(ValueError, match="at least 1"):
+        _stage(model, batch=0).transform(frame)
+    with pytest.raises(ValueError, match="empty prompt"):
+        _stage(model).transform(
+            _frame(tpu_session, _prompts([5, 0]))).collect()
+
+
+def test_in_a_pipeline_after_a_cached_frame(tpu_session, params, model):
+    from sparkdl_tpu.ml.pipeline import Pipeline
+
+    prompts = _prompts([7, 10, 4], seed=9)
+    frame = _frame(tpu_session, prompts).cache()
+    fitted = Pipeline(stages=[_stage(model)]).fit(frame)
+    for prompt, row in zip(prompts, fitted.transform(frame).collect()):
+        _teacher_forced(params, prompt, row)

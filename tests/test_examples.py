@@ -90,6 +90,13 @@ def test_block_diffusion_example():
     assert out.count("mean confidence") == 6
 
 
+def test_ar_generate_example():
+    """Tiny and quick (one process, ~20 s): not marked slow."""
+    out = _run_example("ar_generate.py", timeout=300)
+    assert "generated 48 tokens for 6 prompts" in out
+    assert out.count("mean confidence") == 6
+
+
 @pytest.mark.slow
 def test_sql_analytics_example():
     out = _run_example("sql_analytics.py")

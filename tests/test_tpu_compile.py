@@ -330,3 +330,76 @@ def test_sdar_block_step_compiles_and_fits(
         plain = _sdar_block_program(sdar_block_programs, False, one_chip)
         assert (m.temp_size_in_bytes
                 - plain.memory_analysis().temp_size_in_bytes) <= 0.3e9
+
+
+# -- Granite 4.0-H at its published widths (PR 35) ----------------------------
+
+GRANITE_WIDTHS = dict(
+    vocab_size=50176, hidden_size=4096, num_attention_heads=32,
+    num_key_value_heads=8, num_local_experts=36, routed_experts=72,
+    experts_held=(0, 36), num_experts_per_tok=10, intermediate_size=768,
+    shared_intermediate_size=1536, mamba_n_heads=128, mamba_d_head=64,
+    mamba_d_state=128, attention_multiplier=0.0078125,
+    embedding_multiplier=12, residual_multiplier=0.22, logits_scaling=16,
+)
+
+
+def _granite(one_chip, rows=64, span=2176):
+    """The cell's config three layers deep (Mamba, attention, Mamba: both
+    scan bodies' shapes and the attention layer; depth changes the
+    arguments' bytes, hardly the program), its params and state as shapes on
+    the described chip, and what the seven layers left out would add."""
+    from sparkdl_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig(
+        num_hidden_layers=3, layer_types=("mamba", "attention", "mamba"),
+        **GRANITE_WIDTHS)
+
+    def on_chip(spec):
+        return jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s, jnp.float32 if path[-1].key in ("dt_bias", "a_log", "d")
+            else jnp.bfloat16, sharding=one_chip),
+        gh.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    state = jax.tree_util.tree_map(
+        on_chip, gh.state_spec(cfg, rows, span, jnp.bfloat16))
+    mamba_layer = 2 * 461_242_368 + rows * (4 * 1_048_576 + 2 * 3 * 8448)
+    return gh, cfg, params, state, 7 * mamba_layer
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_granite_programs_compile_and_fit(
+        program, one_chip, no_compile_cache, as_on_the_chip):
+    """The cell's two programs: a prefill segment of 16 pairs x 128
+    positions against the state of 64 rows, and 8 decode steps.  The bytes
+    of the ten-layer cell are reckoned from the three-layer compile."""
+    gh, cfg, params, state, left_out = _granite(one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        compiled = jax.jit(
+            lambda p, s, *rest: gh.prefill(p, cfg, s, *rest),
+            donate_argnums=(1,),
+        ).lower(params, state, ints(16, 128), ints(16), ints(16), ints(16)
+                ).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, s: gh.decode(p, cfg, s, 8), donate_argnums=(1,)
+        ).lower(params, state).compile()
+    text = compiled.as_text()
+    # the grouped expert product is the Pallas kernel: gate, up and down of
+    # each of the two scan bodies and of the attention layer
+    assert text.count("tpu_custom_call") == 9
+    m = compiled.memory_analysis()
+    recurrent = 2 * 64 * (4 * 1_048_576 + 2 * 3 * 8448)
+    cache = 2 * 64 * 8 * 2176 * 128 * 2
+    assert m.alias_size_in_bytes >= recurrent + cache  # the state is donated
+    ten = m.argument_size_in_bytes + left_out + m.temp_size_in_bytes
+    assert 0.25 * V5E_HBM_BYTES < ten < 0.92 * V5E_HBM_BYTES, ten
+    # no copy of the whole recurrent state beside the donated one: a layer
+    # reads and writes its own rows only
+    assert m.temp_size_in_bytes < (1.8e9 if program == "prefill" else 1.0e9)
