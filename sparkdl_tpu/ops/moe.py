@@ -16,6 +16,27 @@ of its expert, so nothing is dropped and nothing is padded to a capacity.
 Pairs routed to experts held elsewhere sort behind the last group, where the
 product leaves rows that are never read.
 
+Around the three products a pair's row crosses the chip's memory once on the
+way in and once on the way out (PERF.md section 6, PR 36):
+
+- **Pairs are numbered k-major**: pair ``j * n + t`` is token ``t``'s
+  ``j``-th choice, so the token of a pair is ``pair % n`` and a token's k
+  parts are k slabs of ``[n, D]``.  Numbered token-major, the k parts are the
+  rows of an ``[n, k, D]`` array, and where k is no multiple of a tile's 8
+  rows (Granite's 10) laying that out is a copy of every row, in float32.
+- **Both gathers promise that their indices are in bounds**
+  (:func:`_rows_at`).  They are by construction, ``order % n < n`` and a
+  permutation's inverse; ``jnp.take``'s default mode does not know it and
+  puts a select over all ``[n * k, D]`` rows behind the gather, to fill the
+  rows of indices that are out of bounds.
+- **The k parts are combined in one pass**: each slab is read in the
+  weights' dtype, widened to float32, multiplied by the pair's float32
+  weight, masked and added; no float32 ``[n * k, D]`` exists in memory.
+- **The mask on the rows behind the last group stays**: the grouped product
+  leaves them undefined, and NaN times a zero weight is NaN.
+- The index work beside them uses no scatter, which runs pair by pair on the
+  chip: the counts are a compare-and-sum, the way back is a second sort.
+
 Which grouped product, from a trace (PERF.md section 6, PR 31): on the TPU
 the Pallas grouped matmul that ships with JAX (``megablox.gmm``) with row
 tiles of 128 and an expert's whole matrix as one tile; elsewhere
@@ -83,6 +104,22 @@ def route(x, router, top_k: int, norm_topk: bool = True):
     return weights, experts.astype(jnp.int32)
 
 
+#: From this many tokens on, the k parts come back by one gather a slab (the
+#: compiler then keeps slabs in fast memory between the gather and the sum:
+#: 0.10 ms where one gather of all the pairs and a sum over its slabs take
+#: 0.22 ms, at 2,048 tokens of width 4,096); under it by ONE gather, where
+#: ten gathers of 64 rows cost ten starts (46 us against 9).  Equal at 1,024
+#: tokens of width 2,048.  PERF.md section 6, PR 36.
+_SLAB_ROWS = 1024
+
+
+def _rows_at(table, index):
+    """``table[index]`` for row numbers that are in bounds by construction:
+    told so, the gather has no pass behind it that fills the rows of
+    out-of-bounds indices (``jnp.take``'s default mode)."""
+    return table.at[index].get(mode="promise_in_bounds")
+
+
 def moe_ffn(
     x,
     router,
@@ -120,13 +157,16 @@ def moe_ffn(
             f"hold {experts['w_gate'].shape[1 if stacked else 0]}"
         )
     weights, chosen = route(x, router, top_k, norm_topk)
-    pair_expert = chosen.reshape(-1)
-    counts = jnp.zeros((n_experts,), jnp.int32).at[pair_expert].add(1)
+    pair_expert = chosen.T.reshape(-1)  # k-major: pair j * n + t
+    # counted by comparison: a scatter-add of n * k ones runs pair by pair
+    counts = jnp.sum(
+        pair_expert[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
+        axis=0, dtype=jnp.int32)
 
     held = (pair_expert >= lo) & (pair_expert < hi)
     group = jnp.where(held, pair_expert - lo, n_held)  # elsewhere: last
     order = jnp.argsort(group, stable=True)
-    rows = jnp.take(x, order // top_k, axis=0)  # [n*k, D], by expert
+    rows = _rows_at(x, order % n)  # [n*k, D], by expert
     sizes = jax.lax.dynamic_slice_in_dim(counts, lo, n_held)
     w_gate, w_up, w_down = (
         experts[name] for name in ("w_gate", "w_up", "w_down"))
@@ -145,12 +185,20 @@ def moe_ffn(
               * up.astype(jnp.float32)).astype(x.dtype)
     down = grouped_dot(hidden, w_down, sizes)
 
-    pair_weight = weights.reshape(-1)
-    # back to (token, k) order by a gather, then the k parts of a token add
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    parts = jnp.take(down, back, axis=0).astype(jnp.float32)
-    # rows behind the last group are whatever the product left there
-    parts = jnp.where(held[:, None], parts * pair_weight[:, None], 0.0)
-    out = jnp.sum(parts.reshape(n, top_k, d), axis=1)
+    # back to pair order by a gather (``order`` is a permutation: sorting it
+    # gives its inverse); a token's k parts are then k slabs of [n, D], each
+    # read once, weighted, masked and added in float32
+    back = jnp.argsort(order)
+    if n >= _SLAB_ROWS:
+        parts = [_rows_at(down, slab) for slab in back.reshape(top_k, n)]
+    else:
+        parts = _rows_at(down, back).reshape(top_k, n, d)
+    pair_weight = weights.T  # [k, n]
+    held = held.reshape(top_k, n)
+    out = jnp.zeros((n, d), jnp.float32)
+    for j in range(top_k):
+        # rows behind the last group are whatever the product left there
+        out = out + jnp.where(
+            held[j][:, None],
+            parts[j].astype(jnp.float32) * pair_weight[j][:, None], 0.0)
     return out.astype(x.dtype), counts

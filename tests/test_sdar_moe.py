@@ -10,6 +10,7 @@ import pytest
 
 from chipbench.reference import sdar_moe as reference
 from sparkdl_tpu.models import sdar_moe
+from sparkdl_tpu.ops import moe
 from sparkdl_tpu.ops.moe import gmm_grouped_dot, moe_ffn, route
 
 CONFIG = dict(
@@ -20,11 +21,27 @@ CONFIG = dict(
 )
 CFG = sdar_moe.SdarMoeConfig.from_dict(CONFIG)
 BLOCK, MASK = 4, 255
+# granite's operating point in small: k = 10, no multiple of a tile's 8 rows,
+# and half of the experts held elsewhere
+WIDE = dict(CONFIG, num_experts=20, num_experts_per_tok=10)
 
 
 @pytest.fixture(scope="module")
 def params():
     return reference.make_params(CONFIG, 2**31 + 77, "float32")
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return reference.make_params(WIDE, 2**31 + 78, "float32")
+
+
+@pytest.fixture(params=[False, True], ids=["one-gather", "slabs"])
+def slabs(request, monkeypatch):
+    """Both ways the k parts come back: by one gather under
+    ``moe._SLAB_ROWS`` tokens, by one gather a slab from there on."""
+    if request.param:
+        monkeypatch.setattr(moe, "_SLAB_ROWS", 1)
 
 
 def _layer(params, index=0):
@@ -50,15 +67,49 @@ def _experts(lp, lo=0, hi=8):
     return {k: lp[k][lo:hi] for k in ("w_gate", "w_up", "w_down")}
 
 
-@pytest.mark.parametrize("top_k", [1, 2, 4])
-def test_moe_layer_equals_the_loop_over_experts(params, top_k):
-    lp, x = _skewed(_layer(params)), _tokens()
-    config = dict(CONFIG, num_experts_per_tok=top_k)
-    want = reference.moe(config, lp, x)
-    got, counts = moe_ffn(x, lp["router"], _experts(lp), top_k=top_k)
+def _held_part(config, lp, x, held, stack=None, index=0):
+    """(``moe_ffn``'s part, its counts, the reference's part) for the experts
+    of ``held``; with ``stack`` through the stacked weights and the index, as
+    a scan over layers calls it."""
+    lo, hi = held
+    want = reference.moe(config, dict(lp, **_experts(lp, lo, hi)), x,
+                         experts_held=held)
+    k = config["num_experts_per_tok"]
+    if stack is None:
+        got, counts = moe_ffn(x, lp["router"], _experts(lp, lo, hi), top_k=k,
+                              experts_held=held)
+    else:
+        share = {name: w[:, lo:hi] for name, w in stack.items()}
+        got, counts = jax.jit(lambda i: moe_ffn(
+            x, lp["router"], share, top_k=k, experts_held=held,
+            stack_index=i))(jnp.int32(index))
+    return got, counts, want
+
+
+def _stack(params):
+    return {k: params["layers"][k] for k in ("w_gate", "w_up", "w_down")}
+
+
+@pytest.mark.parametrize("top_k, held, tokens, stacked", [
+    (1, None, 24, False), (2, None, 24, False), (4, None, 24, False),
+    (10, (0, 10), 21, False), (10, (10, 20), 21, True), (10, None, 24, True),
+], ids=["1", "2", "4", "10-low-half-21-tokens", "10-high-half-stacked",
+        "10-all-stacked"])
+def test_moe_layer_equals_the_loop_over_experts(
+        params, wide_params, slabs, top_k, held, tokens, stacked):
+    wide = top_k == 10
+    config = WIDE if wide else dict(CONFIG, num_experts_per_tok=top_k)
+    source = wide_params if wide else params
+    lp, x = _skewed(_layer(source, 1 if stacked else 0)), _tokens(tokens)
+    got, counts, want = _held_part(
+        config, lp, x, held or (0, config["num_experts"]),
+        _stack(source) if stacked else None, 1)
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert int(counts.sum()) == x.shape[0] * top_k  # nothing dropped
-    assert int(counts[5]) == 0 and int(counts[1]) == counts.max()
+    # uneven loads and an expert with no token at all (ten of twenty
+    # experts a token leave the favoured one short of the maximum)
+    assert int(counts[5]) == 0
+    assert int(counts[1]) == counts.max() or wide and counts[1] > counts.mean()
     _, ref_counts = reference.route(config, lp, x, None)
     np.testing.assert_array_equal(counts, ref_counts)
 
@@ -73,29 +124,81 @@ def test_router_renormalises_its_top_k_and_breaks_ties_low(params):
     assert float(weights.sum(-1).max()) < 1.0
 
 
-@pytest.mark.parametrize("cut", [4, 2, 7])
-def test_the_shares_add_up_to_the_uncut_layer(params, cut):
-    """``experts_held`` = (0, cut) and (cut, 8), summed, equal the reference's
+@pytest.mark.parametrize("cut, n_experts, stacked", [
+    (4, 8, False), (2, 8, False), (7, 8, False),
+    (10, 20, False), (10, 20, True), (3, 20, True),
+], ids=["4", "2", "7", "10-of-20", "10-of-20-stacked", "3-of-20-stacked"])
+def test_the_shares_add_up_to_the_uncut_layer(
+        params, wide_params, slabs, cut, n_experts, stacked):
+    """``experts_held`` = (0, cut) and (cut, E), summed, equal the reference's
     whole layer: what expert parallelism asks of the layer."""
-    lp, x = _skewed(_layer(params, 1)), _tokens(seed=4)
-    whole = reference.moe(CONFIG, lp, x)
+    config, source, tokens = (
+        (CONFIG, params, 24) if n_experts == 8 else (WIDE, wide_params, 21))
+    top_k = config["num_experts_per_tok"]
+    lp, x = _skewed(_layer(source, 1)), _tokens(tokens, seed=4)
+    whole = reference.moe(config, lp, x)
     parts = []
-    for lo, hi in ((0, cut), (cut, 8)):
-        part, counts = moe_ffn(
-            x, lp["router"], _experts(lp, lo, hi), top_k=2,
-            experts_held=(lo, hi))
-        assert int(counts.sum()) == 2 * x.shape[0]  # routed over ALL experts
-        np.testing.assert_allclose(
-            part, reference.moe(CONFIG, dict(lp, **_experts(lp, lo, hi)), x,
-                                experts_held=(lo, hi)), atol=2e-6)
+    for held in ((0, cut), (cut, n_experts)):
+        part, counts, want = _held_part(
+            config, lp, x, held, _stack(source) if stacked else None, 1)
+        assert int(counts.sum()) == top_k * tokens  # routed over ALL experts
+        np.testing.assert_allclose(part, want, atol=2e-6)
         parts.append(part)
     np.testing.assert_allclose(parts[0] + parts[1], whole, atol=2e-6)
 
 
+def test_rows_behind_the_last_group_never_reach_the_result(
+        wide_params, slabs, monkeypatch):
+    """The grouped product leaves the rows behind its last group undefined
+    (the pairs of experts held elsewhere sort there): NaN planted in them
+    must not show, and NaN times a zero weight is NaN, so they are masked."""
+    lp, x = _skewed(_layer(wide_params)), _tokens(21)
+    held = (5, 15)
+    want, want_counts, _ = _held_part(WIDE, lp, x, held)
+    poisoned = []
+
+    def grouped_dot(rows, weights, sizes):
+        out = jax.lax.ragged_dot(rows, weights, sizes)
+        behind = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
+        poisoned.append(int(behind.sum()))
+        return jnp.where(behind[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(moe, "grouped_dot", grouped_dot)
+    got, counts, _ = _held_part(WIDE, lp, x, held)
+    assert len(poisoned) == 3 and min(poisoned) > 21  # gate, up and down
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(counts, want_counts)
+
+
+@pytest.mark.parametrize("shuffle", ["reversed", "by-token"])
+def test_counts_and_result_do_not_depend_on_the_pair_order(
+        wide_params, slabs, shuffle, monkeypatch):
+    """A token's k choices in another order number the pairs differently
+    and add the k parts in another order; the counts are the same and the
+    result is the dense loop over the experts', to float32's last bits."""
+    lp, x = _skewed(_layer(wide_params, 1)), _tokens(21, seed=9)
+    held = (0, 10)
+    rng = np.random.default_rng(5)
+    columns = (np.tile(np.arange(10)[::-1], (21, 1)) if shuffle == "reversed"
+               else np.stack([rng.permutation(10) for _ in range(21)]))
+
+    def shuffled_route(*args, **kwargs):
+        weights, experts = route(*args, **kwargs)
+        return (jnp.take_along_axis(weights, columns, axis=1),
+                jnp.take_along_axis(experts, columns, axis=1))
+
+    plain, plain_counts, want = _held_part(WIDE, lp, x, held)
+    monkeypatch.setattr(moe, "route", shuffled_route)
+    got, counts, _ = _held_part(WIDE, lp, x, held, _stack(wide_params), 1)
+    np.testing.assert_array_equal(counts, plain_counts)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    np.testing.assert_allclose(got, plain, atol=1e-6)
+
+
 def test_a_stack_of_layers_is_used_through_its_index(params):
     """The scan hands ``moe_ffn`` every layer's experts and an index."""
-    x = _tokens()
-    stack = {k: params["layers"][k] for k in ("w_gate", "w_up", "w_down")}
+    x, stack = _tokens(), _stack(params)
     for index in (0, 1):
         lp = _layer(params, index)
         want, _ = moe_ffn(x, lp["router"], _experts(lp), top_k=2)

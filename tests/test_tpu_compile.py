@@ -268,6 +268,64 @@ def test_grouped_expert_product_compiles(
         _fits(compiled)
 
 
+def _entry_ops(text):
+    """(result shape, opcode, op_name) of the entry computation's own
+    instructions: what is a pass of its own over memory."""
+    entry = text[text.index("\nENTRY "):]
+    ops = []
+    for line in entry.splitlines():
+        head = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)\{\S* ([\w-]+)\(", line)
+        if head:
+            name = re.search(r'op_name="([^"]*)"', line)
+            ops.append((*head.groups(), name.group(1) if name else ""))
+    return ops
+
+
+@pytest.mark.parametrize("tokens, width, experts, held, top_k, temp_limit", [
+    (2048, 4096, 72, (0, 36), 10, 0.35e9),
+    (1024, 2048, 128, None, 8, 0.03e9),
+], ids=["granite-prefill", "sdar-block"])
+def test_expert_layer_moves_each_pairs_row_once(
+        tokens, width, experts, held, top_k, temp_limit, one_chip,
+        no_compile_cache, as_on_the_chip):
+    """``moe_ffn`` alone over a stack of two layers, at granite's prefill
+    dispatch (k = 10 is no multiple of a tile's 8 rows, half of the experts
+    held elsewhere) and at SDAR's block step: around the three grouped
+    products the pairs' rows [n * k, d] are written by the forward gather and
+    by the last product and by nothing else; no pass fills the rows of
+    out-of-bounds indices behind a gather; the k parts of a token are never
+    laid out as [n, k, d] nor widened to float32 in memory (PERF.md
+    section 6, PR 36: 437.6 MB of temporaries before, 270.8 MB now)."""
+    from sparkdl_tpu.ops.moe import moe_ffn
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n_held = experts if held is None else held[1] - held[0]
+    stack = {
+        "w_gate": spec((2, n_held, width, 768)),
+        "w_up": spec((2, n_held, width, 768)),
+        "w_down": spec((2, n_held, 768, width)),
+    }
+    compiled = jax.jit(
+        lambda x, router, stack, index: moe_ffn(
+            x, router, stack, top_k=top_k, experts_held=held,
+            stack_index=index)
+    ).lower(spec((tokens, width)), spec((width, experts)), stack,
+            spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    pairs = tokens * top_k
+    assert f"[{tokens},{top_k},{width}]" not in text
+    assert f"f32[{pairs},{width}]" not in text
+    rows = [(opcode, name) for shape, opcode, name in _entry_ops(text)
+            if shape == f"bf16[{pairs},{width}]"]
+    assert sorted(opcode for opcode, _ in rows) == ["custom-call", "fusion"]
+    assert all(name.endswith("/gather") for opcode, name in rows
+               if opcode == "fusion"), rows
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
 @pytest.fixture(scope="module")
 def sdar_block_programs():
     """The block step's compiled shapes, kept for the module: the chained
@@ -402,4 +460,6 @@ def test_granite_programs_compile_and_fit(
     assert 0.25 * V5E_HBM_BYTES < ten < 0.92 * V5E_HBM_BYTES, ten
     # no copy of the whole recurrent state beside the donated one: a layer
     # reads and writes its own rows only
-    assert m.temp_size_in_bytes < (1.8e9 if program == "prefill" else 1.0e9)
+    # (prefill: 754,259,968 bytes and a tenth; a float32 copy of the expert
+    # layer's pairs [tokens * k, d] alone would add 336 MB)
+    assert m.temp_size_in_bytes < (0.83e9 if program == "prefill" else 1.0e9)
