@@ -63,19 +63,25 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import hashlib
 import inspect
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from sparkdl_tpu.models.hybrid import (
+    HybridModel,
+    attention_segment,
+    attention_token,
+    feed_forward,
+    grouped_qkv,
+    layer_runs,
+    rms_norm,
+    run_layers,
+    source_digest,
+)
 from sparkdl_tpu.ops import ssm
-from sparkdl_tpu.ops.moe import moe_ffn
-
-#: stands for "not visible" in a score; finite, so that a row that sees
-#: nothing softmaxes to a uniform garbage and not to NaN
-NEG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,14 +171,7 @@ class GraniteHybridConfig:
     def runs(self):
         """``(kind, first layer, first of its kind, count)`` of every run of
         consecutive layers of one kind."""
-        out, seen = [], {"mamba": 0, "attention": 0}
-        for index, kind in enumerate(self.layer_types):
-            if out and out[-1][0] == kind:
-                out[-1][3] += 1
-            else:
-                out.append([kind, index, seen[kind], 1])
-            seen[kind] += 1
-        return [tuple(run) for run in out]
+        return layer_runs(self.layer_types)
 
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
@@ -267,34 +266,13 @@ def empty_state(cfg: GraniteHybridConfig, rows: int, span: int, dtype):
 
 # -- the pieces -------------------------------------------------------------
 
-def rms_norm(x, gain, eps: float):
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale * gain.astype(jnp.float32)).astype(x.dtype)
-
-
-def _at(tree, index):
-    """Layer ``index`` (a traced scalar) of every stacked leaf."""
-    return jax.tree_util.tree_map(
-        lambda w: jax.lax.dynamic_index_in_dim(w, index, 0, keepdims=False),
-        tree)
-
-
-def _read(stack, layer, rows):
-    """Layer ``layer`` of a state leaf [layers, rows, ...]: whole, or the
-    rows ``rows`` of it (an index past the last row reads the last)."""
-    if rows is None:
-        return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
-    return stack.at[layer, rows].get(mode="clip")
-
-
-def _write(stack, value, layer, rows):
-    """The counterpart of :func:`_read`, in place where the state is
-    donated (an index past the last row writes nothing)."""
-    value = value.astype(stack.dtype)
-    if rows is None:
-        return jax.lax.dynamic_update_index_in_dim(stack, value, layer, 0)
-    return stack.at[layer, rows].set(value, mode="drop")
+def _feed_forward(cfg, fp, experts, layer, h):
+    """``h + r * (MoE(u) + Shared(u))`` with ``u = RMSNorm_post(h)``, and
+    the layer's routing counts; ``fp`` is the layer's own slice."""
+    return feed_forward(
+        fp, experts, layer, h, eps=cfg.rms_norm_eps,
+        top_k=cfg.num_experts_per_tok, held=cfg.held,
+        residual=cfg.residual_multiplier)
 
 
 def _mamba_inputs(cfg, lp, u):
@@ -349,130 +327,35 @@ def _mamba_token(cfg, lp, u, window, state):
     return _mamba_out(cfg, lp, y, x, z), window, state
 
 
-def _qkv(cfg, lp, u):
-    """q [..., KV, G, dh], k and v [..., KV, dh]; G query heads read each
-    key/value head."""
-    dh, kv = cfg.attention_head_dim, cfg.num_key_value_heads
-    group = cfg.num_attention_heads // kv
-    lead = u.shape[:-1]
-    return (jnp.dot(u, lp["wq"]).reshape(*lead, kv, group, dh),
-            jnp.dot(u, lp["wk"]).reshape(*lead, kv, dh),
-            jnp.dot(u, lp["wv"]).reshape(*lead, kv, dh))
-
-
 def _attention_segment(cfg, lp, u, cache_k, cache_v, start):
     """The mixer over a segment ``u`` [c, n, D] at positions ``start[c] +
-    arange(n)``: the segment's keys and values go into the rows' cache
-    ([c, KV, span, dh]) first, then every position sees the cache up to
-    itself.  One row at a time (``lax.map``): a row's float32 scores are
-    [heads, n, span]."""
-    c, n, _ = u.shape
-    q, k, v = _qkv(cfg, lp, u)
-    write = jax.vmap(lambda cache, new, at: jax.lax.dynamic_update_slice(
-        cache, new.transpose(1, 0, 2), (0, at, 0)))
-    cache_k, cache_v = write(cache_k, k, start), write(cache_v, v, start)
-    slots = jnp.arange(cache_k.shape[2], dtype=jnp.int32)
-
-    def one_row(row):
-        q, keys, values, start = row
-        scores = jnp.einsum("nkgd,kmd->kgnm", q, keys,
-                            preferred_element_type=jnp.float32)
-        scores = scores * cfg.attention_multiplier
-        visible = slots[None, :] <= start + jnp.arange(n)[:, None]
-        probs = jax.nn.softmax(jnp.where(visible, scores, NEG), axis=-1)
-        out = jnp.einsum("kgnm,kmd->nkgd", probs.astype(values.dtype), values)
-        return out.reshape(n, -1)
-
-    out = jax.lax.map(one_row, (q, cache_k, cache_v, start))
+    arange(n)`` (:func:`~sparkdl_tpu.models.hybrid.attention_segment`)."""
+    out, cache_k, cache_v = attention_segment(
+        *grouped_qkv(lp, u, cfg.num_key_value_heads, cfg.attention_head_dim),
+        cache_k, cache_v, start, cfg.attention_multiplier)
     return jnp.dot(out, lp["wo"]), cache_k, cache_v
 
 
 def _attention_token(cfg, lp, u, cache_k, cache_v, position):
     """The mixer on one position a row, ``u`` [r, D] at ``position[r]``."""
-    r = u.shape[0]
-    q, k, v = _qkv(cfg, lp, u)
-    rows = jnp.arange(r)
-    cache_k = cache_k.at[rows, :, position].set(k)
-    cache_v = cache_v.at[rows, :, position].set(v)
-    scores = jnp.einsum("rkgd,rkmd->rkgm", q, cache_k,
-                        preferred_element_type=jnp.float32)
-    scores = scores * cfg.attention_multiplier
-    visible = jnp.arange(cache_k.shape[2])[None, :] <= position[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(visible[:, None, None], scores, NEG), axis=-1)
-    out = jnp.einsum("rkgm,rkmd->rkgd", probs.astype(cache_v.dtype), cache_v)
-    return jnp.dot(out.reshape(r, -1), lp["wo"]), cache_k, cache_v
-
-
-def _split_ffn(params):
-    """(what a layer reads at its index, the experts' stacks, left whole)."""
-    ffn = dict(params["ffn"])
-    experts = {k: ffn.pop(k) for k in ("w_gate", "w_up", "w_down")}
-    return ffn, experts
-
-
-def _feed_forward(cfg, fp, experts, layer, h):
-    """``h + r * (MoE(u) + Shared(u))`` with ``u = RMSNorm_post(h)``, and
-    the layer's routing counts; ``fp`` is the layer's own slice."""
-    lead, d = h.shape[:-1], h.shape[-1]
-    u = rms_norm(h, fp["post_norm"], cfg.rms_norm_eps).reshape(-1, d)
-    routed, counts = moe_ffn(
-        u, fp["router"], experts, top_k=cfg.num_experts_per_tok,
-        experts_held=cfg.held, norm_topk=True, stack_index=layer)
-    gate = jnp.dot(u, fp["shared_gate"], preferred_element_type=jnp.float32)
-    up = jnp.dot(u, fp["shared_up"], preferred_element_type=jnp.float32)
-    shared = jnp.dot((jax.nn.silu(gate) * up).astype(u.dtype),
-                     fp["shared_down"])
-    out = (routed + shared).reshape(*lead, d)
-    return h + (cfg.residual_multiplier * out).astype(h.dtype), counts
+    out, cache_k, cache_v = attention_token(
+        *grouped_qkv(lp, u, cfg.num_key_value_heads, cfg.attention_head_dim),
+        cache_k, cache_v, position, cfg.attention_multiplier)
+    return jnp.dot(out, lp["wo"]), cache_k, cache_v
 
 
 def _layers(params, cfg, x, state, rows, mamba, attention):
-    """Every layer over ``x``: ``mamba(lp, u, window, state)`` and
-    ``attention(lp, u, cache_k, cache_v)`` are the two mixers at the caller's
-    shape (a segment or a token).  ``x`` is about the rows ``rows`` of
-    ``state`` (None: all of them, in order); a layer reads and writes only
-    its own slice of the state, so no copy of more than one layer's rows is
-    ever alive.  Returns (x, state, counts [L, E])."""
-    ffn, experts = _split_ffn(params)
-    r = cfg.residual_multiplier
-    conv, rec, cache_k, cache_v = (
-        state["conv"], state["ssm"], state["k"], state["v"])
-    counts = []
-
-    def mamba_layer(carry, index):
-        x, conv, rec = carry
-        of_kind, layer = index
-        lp = _at(params["mamba"], of_kind)
-        out, window, new = mamba(
-            lp, rms_norm(x, lp["in_norm"], cfg.rms_norm_eps),
-            _read(conv, of_kind, rows), _read(rec, of_kind, rows))
-        h = x + (r * out).astype(x.dtype)
-        y, routed = _feed_forward(cfg, _at(ffn, layer), experts, layer, h)
-        return (y, _write(conv, window, of_kind, rows),
-                _write(rec, new, of_kind, rows)), routed
-
-    for kind, first, of_kind, count in cfg.runs:
-        if kind == "mamba":
-            (x, conv, rec), routed = jax.lax.scan(
-                mamba_layer, (x, conv, rec),
-                (jnp.arange(of_kind, of_kind + count, dtype=jnp.int32),
-                 jnp.arange(first, first + count, dtype=jnp.int32)))
-            counts.append(routed)
-            continue
-        for offset in range(count):
-            at, layer = of_kind + offset, jnp.int32(first + offset)
-            lp = jax.tree_util.tree_map(lambda w: w[at], params["attention"])
-            out, new_k, new_v = attention(
-                lp, rms_norm(x, lp["in_norm"], cfg.rms_norm_eps),
-                _read(cache_k, at, rows), _read(cache_v, at, rows))
-            cache_k = _write(cache_k, new_k, at, rows)
-            cache_v = _write(cache_v, new_v, at, rows)
-            h = x + (r * out).astype(x.dtype)
-            x, routed = _feed_forward(cfg, _at(ffn, layer), experts, layer, h)
-            counts.append(routed[None])
-    state = dict(state, conv=conv, ssm=rec, k=cache_k, v=cache_v)
-    return x, state, jnp.concatenate(counts, axis=0)
+    """Every layer over ``x``
+    (:func:`~sparkdl_tpu.models.hybrid.run_layers`): ``mamba(lp, u, window,
+    state)`` and ``attention(lp, u, cache_k, cache_v)`` are the two mixers at
+    the caller's shape (a segment or a token).  Returns (x, state, counts [L,
+    E])."""
+    return run_layers(
+        params, cfg.layer_types, x, state, rows,
+        {"mamba": (("conv", "ssm"), mamba),
+         "attention": (("k", "v"), attention)},
+        functools.partial(_feed_forward, cfg), eps=cfg.rms_norm_eps,
+        residual=cfg.residual_multiplier)
 
 
 def _embed(params, cfg, tokens):
@@ -582,58 +465,20 @@ def decode(params, cfg: GraniteHybridConfig, state, steps: int):
 
 @functools.lru_cache(maxsize=1)
 def _source_digest() -> str:
-    """Identifies this mathematics in a program's fingerprint: an executable
-    kept on disk must not outlive a change to any module it compiled."""
-    from sparkdl_tpu.ops import moe
-
-    text = "".join(inspect.getsource(module) for module in (
-        moe, ssm, inspect.getmodule(prefill)))
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    """Identifies this mathematics in a program's fingerprint
+    (:func:`~sparkdl_tpu.models.hybrid.source_digest`)."""
+    return source_digest(ssm, inspect.getmodule(prefill))
 
 
-class GraniteHybridModel:
-    """The ``model`` of an
-    :class:`~sparkdl_tpu.transformers.ar_generate.AutoregressiveTransformer`:
-    the decoder's functions bound to a config, and the params they run on.
-    The params are arguments of every program, never constants in one, so
-    two models of one config share their executables."""
+class GraniteHybridModel(HybridModel):
+    """Granite 4.0-H for an
+    :class:`~sparkdl_tpu.transformers.ar_generate.AutoregressiveTransformer`
+    (:class:`~sparkdl_tpu.models.hybrid.HybridModel`): programs
+    ``granite_prefill`` and ``granite_decode``; the recurrent state is the
+    conv windows and the SSM states."""
 
-    #: its programs are ``<name>_prefill`` and ``<name>_decode``
     name = "granite"
-
-    def __init__(self, config, params):
-        self.config = (
-            config if isinstance(config, GraniteHybridConfig)
-            else GraniteHybridConfig.from_dict(config)
-        )
-        self.params = params
-
-    @property
-    def fingerprint(self) -> str:
-        return f"granite_hybrid:{_source_digest()}:{self.config}"
-
-    @property
-    def experts_per_token(self) -> int:
-        return self.config.num_experts_per_tok
-
-    @property
-    def experts_held(self):
-        return self.config.held
-
-    def state_spec(self, rows: int, span: int):
-        return state_spec(self.config, rows, span, self.params["embed"].dtype)
-
-    def recurrent_bytes(self, rows: int) -> int:
-        """Bytes of recurrent state (conv windows and SSM states) that
-        ``rows`` rows hold on the device."""
-        spec = self.state_spec(rows, 1)
-        return sum(
-            spec[name].size * spec[name].dtype.itemsize
-            for name in ("conv", "ssm"))
-
-    def prefill(self, params, state, tokens, rows, start, lengths):
-        return prefill(params, self.config, state, tokens, rows, start,
-                       lengths)
-
-    def decode(self, params, state, steps: int):
-        return decode(params, self.config, state, steps)
+    family = "granite_hybrid"
+    config_class = GraniteHybridConfig
+    recurrent_leaves = ("conv", "ssm")
+    functions = sys.modules[__name__]

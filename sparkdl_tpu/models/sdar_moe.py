@@ -441,6 +441,10 @@ class SdarMoeModel:
     def experts_per_token(self) -> int:
         return self.config.num_experts_per_tok
 
+    @property
+    def experts_held(self):
+        return self.config.held
+
     def cache_spec(self, rows: int, span: int):
         """Shape and dtype of the key (and of the value) cache."""
         cfg = self.config

@@ -91,12 +91,32 @@ def grouped_dot(rows, weights, sizes):
     return jax.lax.ragged_dot(rows, weights, sizes)
 
 
-def route(x, router, top_k: int, norm_topk: bool = True):
-    """``(weights [n, k] float32, experts [n, k] int32)``: softmax over ALL
-    the router's outputs in float32, the ``top_k`` largest (ties to the lower
-    expert, as ``jax.lax.top_k`` breaks them), renormalised to sum to one
-    where ``norm_topk``."""
+def route(x, router, top_k: int, norm_topk: bool = True,
+          scoring: str = "softmax", select_bias=None):
+    """``(weights [n, k] float32, experts [n, k] int32)``: every one of the
+    router's outputs scored in float32, the ``top_k`` largest (ties to the
+    lower expert, as ``jax.lax.top_k`` breaks them), renormalised to sum to
+    one where ``norm_topk``.
+
+    ``scoring="softmax"``: the scores are the softmax over ALL the outputs.
+    ``scoring="sigmoid"``: each output's own sigmoid; the experts are chosen
+    by ``score + select_bias`` ([E] float32, the router's selection bias)
+    and weighted by the score alone."""
     logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        biased = scores if select_bias is None else (
+            scores + select_bias.astype(jnp.float32))
+        _, experts = jax.lax.top_k(biased, top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights, experts.astype(jnp.int32)
+    if scoring != "softmax" or select_bias is not None:
+        raise ValueError(
+            f"scoring={scoring!r} with select_bias "
+            f"{'set' if select_bias is not None else 'unset'}: softmax (no "
+            "selection bias) or sigmoid")
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     weights, experts = jax.lax.top_k(probs, top_k)
     if norm_topk:
@@ -129,6 +149,8 @@ def moe_ffn(
     experts_held: Optional[Tuple[int, int]] = None,
     norm_topk: bool = True,
     stack_index=None,
+    scoring: str = "softmax",
+    select_bias=None,
 ):
     """The held experts' part of ``sum_e w_e * down_e(silu(gate_e x) * up_e x)``.
 
@@ -144,7 +166,8 @@ def moe_ffn(
 
     Returns ``(out [n, D] in x's dtype, counts [E] int32)``; ``counts[e]`` is
     the number of tokens routed to expert e (held here or not), so
-    ``counts.sum() == n * top_k`` and nothing was dropped.
+    ``counts.sum() == n * top_k`` and nothing was dropped.  ``scoring`` and
+    ``select_bias`` are :func:`route`'s.
     """
     n, d = x.shape
     n_experts = router.shape[-1]
@@ -156,7 +179,8 @@ def moe_ffn(
             f"experts_held={lo, hi} names {n_held} experts, the weights "
             f"hold {experts['w_gate'].shape[1 if stacked else 0]}"
         )
-    weights, chosen = route(x, router, top_k, norm_topk)
+    weights, chosen = route(
+        x, router, top_k, norm_topk, scoring, select_bias)
     pair_expert = chosen.T.reshape(-1)  # k-major: pair j * n + t
     # counted by comparison: a scatter-add of n * k ones runs pair by pair
     counts = jnp.sum(

@@ -2,7 +2,8 @@
 a decode step, over a DataFrame column of prompts (arrays of token ids), for
 a model whose per-row state is a pytree that stays on the device: recurrent
 states beside a growing key/value cache
-(:class:`~sparkdl_tpu.models.granite_hybrid.GraniteHybridModel`).
+(:class:`~sparkdl_tpu.models.granite_hybrid.GraniteHybridModel`,
+:class:`~sparkdl_tpu.models.solar_open2.SolarOpen2Model`).
 
 A batch is one state, donated from dispatch to dispatch, and two programs:
 
@@ -26,8 +27,8 @@ and scalars cross to the host.  The weights are program ARGUMENTS, placed
 once per model object; an executable holds no weight constants.
 
 Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
-``ar_generate.partition`` (root; ``rows``, ``batches``, ``prompt_tokens``,
-``generated_tokens``) > ``ar_generate.plan``, ``engine.place``,
+``ar_generate.partition`` (root; ``model``: the model's ``.name``, ``rows``,
+``batches``, ``prompt_tokens``, ``generated_tokens``) > ``ar_generate.plan``, ``engine.place``,
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``),
 ``engine.fetch_wait``, ``ar_generate.postprocess``.  Counters:
@@ -38,9 +39,10 @@ were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
 ``ar_generate.decode_expert_reads`` (the (step, layer, held expert) triples
 that got a token: the expert matrices a batch's decode steps had to read),
 ``ar_generate.tokens_generated`` (real rows), ``ssm.state_bytes`` (bytes of recurrent state a batch holds on
-the device), and ``moe.tokens_routed``, ``moe.tokens_dropped``,
-``moe.expert_load_max``, ``moe.expert_load_mean`` (from the routing counts
-that come back with every program's result).
+the device), and ``moe.tokens_routed``, ``moe.pairs_held`` (the routed pairs
+whose expert is held here), ``moe.tokens_dropped``, ``moe.expert_load_max``,
+``moe.expert_load_mean`` (from the routing counts that come back with every
+program's result).
 """
 
 from __future__ import annotations
@@ -193,8 +195,9 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
         "the model's functions and params: an object with .params, .name, "
         ".fingerprint, .experts_held, .state_spec(rows, span), "
         ".recurrent_bytes(rows), "
-        ".prefill(...) and .decode(...) "
-        "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel)",
+        ".experts_per_token, .prefill(...) and .decode(...) "
+        "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel, "
+        "sparkdl_tpu.models.solar_open2.SolarOpen2Model)",
     )
     recordCol = Param(
         "undefined", "recordCol",
@@ -262,8 +265,8 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
                 return out
             bounds = range(0, len(prompts), rows)
             with tracer.boundary(
-                "ar_generate.partition", rows=len(prompts),
-                batches=len(bounds),
+                "ar_generate.partition", model=model.name,
+                rows=len(prompts), batches=len(bounds),
                 prompt_tokens=int(sum(len(p) for p in prompts)),
                 generated_tokens=len(prompts) * gen,
             ):
@@ -317,7 +320,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
                 later.append(result)
             for slot, row in last or ():
                 first[row] = result[0][slot], result[1][slot]
-            count_routing(counts, routed_tokens, model.experts_per_token)
+            count_routing(counts, routed_tokens, model.experts_per_token,
+                          (lo, hi))
 
     try:
         with tracer.boundary(

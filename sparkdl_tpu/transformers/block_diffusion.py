@@ -40,9 +40,9 @@ layers for the cache: all but a row's last), ``generate.commits_fused``
 (those of them that shared a pass over the weights with a denoising
 forward: all), ``generate.weight_passes`` (passes over the layers' weights:
 ``denoisingSteps`` a block), ``generate.tokens_fixed``; and
-``moe.tokens_routed``, ``moe.tokens_dropped``, ``moe.expert_load_max``,
-``moe.expert_load_mean`` (from the routing counts that come back with every
-program's result).
+``moe.tokens_routed``, ``moe.pairs_held``, ``moe.tokens_dropped``,
+``moe.expert_load_max``, ``moe.expert_load_mean`` (from the routing counts
+that come back with every program's result).
 """
 
 from __future__ import annotations
@@ -358,7 +358,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
     def landed(pairs):
         for result, (kind, routed_tokens) in pairs:
             count_routing(result[-1], routed_tokens,
-                          runner.model.experts_per_token)
+                          runner.model.experts_per_token,
+                          runner.model.experts_held)
             if kind == "block":
                 fetched.append(result)
 

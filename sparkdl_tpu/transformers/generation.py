@@ -83,15 +83,19 @@ def runner_for(model, attribute: str, key, make: Callable):
     return held[key]
 
 
-def count_routing(counts, tokens: int, per_token: int) -> None:
+def count_routing(counts, tokens: int, per_token: int, held) -> None:
     """``counts`` [L, E]: the (token, expert) pairs each expert of each
     layer got in one program's forwards, through each layer of which
-    ``tokens`` tokens went with ``per_token`` experts each."""
+    ``tokens`` tokens went with ``per_token`` experts each; ``held`` = (lo,
+    hi): the experts whose part is computed here (``moe.pairs_held`` beside
+    ``moe.tokens_routed`` is the share of the routed work this chip does)."""
     from sparkdl_tpu.utils.metrics import metrics
 
     counts = np.asarray(counts)
     routed = int(counts.sum())
     metrics.counter("moe.tokens_routed").add(routed)
+    metrics.counter("moe.pairs_held").add(
+        int(counts[..., held[0]:held[1]].sum()))
     metrics.counter("moe.tokens_dropped").add(
         tokens * counts.shape[0] * per_token - routed)
     metrics.counter("moe.expert_load_max").add(float(counts.max()))

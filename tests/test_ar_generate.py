@@ -1,7 +1,10 @@
 """``AutoregressiveTransformer`` over a DataFrame of prompts, at a tiny size
 on the CPU: every generated position teacher-forced against the plain
 reference (``chipbench/reference/granite_hybrid.py``), the layout of a
-batch's prefill, the state's reuse, the spans and the counters.
+batch's prefill, the state's reuse, the spans and the counters; and the
+stage's second model (``SolarOpen2Model`` against
+``chipbench/reference/solar_open2.py``), alone and after the first in one
+process.
 
 float32 weights, and the programs compiled at ``highest`` precision: program
 and reference differ by rounding order only, so a log-probability (near
@@ -36,7 +39,8 @@ COUNTERS = (
     "ar_generate.prefill_tokens", "ar_generate.prefill_pad_tokens",
     "ar_generate.decode_steps", "ar_generate.decode_dispatches",
     "ar_generate.decode_expert_reads", "ar_generate.tokens_generated", "ssm.state_bytes", "moe.tokens_routed",
-    "moe.tokens_dropped", "moe.expert_load_max", "moe.expert_load_mean")
+    "moe.pairs_held", "moe.tokens_dropped", "moe.expert_load_max",
+    "moe.expert_load_mean")
 
 
 @pytest.fixture(scope="module")
@@ -195,7 +199,7 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     root = [r for r in mine if r.name == "ar_generate.partition"][-1]
     assert root.parent_id is None
     assert root.attributes == {
-        "rows": 3, "batches": 1, "prompt_tokens": 28,
+        "model": "granite", "rows": 3, "batches": 1, "prompt_tokens": 28,
         "generated_tokens": 3 * GEN}
     inside = [r for r in mine if r.parent_id == root.span_id]
     names = [r.name for r in inside]
@@ -227,6 +231,8 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     # experts a token, pads and the dummy row routed like any other — and
     # nothing dropped
     assert delta["moe.tokens_routed"] == (48 + 5 * 4) * 4 * 2
+    # all 8 experts are held here: the whole of the routed work
+    assert delta["moe.pairs_held"] == delta["moe.tokens_routed"]
     assert delta["moe.tokens_dropped"] == 0
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
 
@@ -272,3 +278,103 @@ def test_in_a_pipeline_after_a_cached_frame(tpu_session, params, model):
     fitted = Pipeline(stages=[_stage(model)]).fit(frame)
     for prompt, row in zip(prompts, fitted.transform(frame).collect()):
         _teacher_forced(params, prompt, row)
+
+
+# -- the stage's second model (PR 37) ------------------------------------------
+
+SOLAR = dict(
+    model_type="solar_open2", vocab_size=96, hidden_size=32,
+    num_hidden_layers=4, gqa_layers=[0, 4], num_attention_heads=4,
+    num_key_value_heads=2, head_dim=8,
+    linear_attn_config=dict(short_conv_kernel_size=4, head_dim=8,
+                            num_heads=4, num_kv_heads=None),
+    n_routed_experts=2, experts_held=[2, 4], published={"n_routed_experts": 8},
+    n_shared_experts=1, num_experts_per_tok=2, moe_intermediate_size=16,
+    norm_topk_prob=True, routed_scaling_factor=1, rms_norm_eps=1e-5,
+    use_rope=False, use_gqa_gate=True, kda_use_full_proj=False,
+    kda_allow_neg_eigval=True, first_k_dense_replace=0, kda_chunk_size=8,
+)
+
+
+@pytest.fixture(scope="module")
+def solar():
+    from chipbench.reference import solar_open2
+    from sparkdl_tpu.models.solar_open2 import SolarOpen2Model
+
+    params = solar_open2.make_params(SOLAR, 47, "float32")
+    return SolarOpen2Model(SOLAR, params), params
+
+
+def _solar_teacher_forced(params, prompt, row, gen=GEN):
+    from chipbench.reference import solar_open2
+
+    tokens = np.asarray(row["generated"])
+    record = np.asarray(row["record"])
+    assert tokens.shape == (gen,) and record.shape == (gen, 2)
+    np.testing.assert_array_equal(record[:, 0], tokens)
+    want = solar_open2.teacher_forced(params, SOLAR, prompt, tokens)
+    np.testing.assert_array_equal(want.argmax(axis=-1), tokens)
+    # as ``_teacher_forced``, with the chunked rule's triangular solve
+    # between the two: a few ulps more
+    np.testing.assert_allclose(record[:, 1], want.max(axis=-1), atol=4e-6)
+
+
+def test_the_second_model_generates_through_the_same_stage(
+        tpu_session, solar):
+    """A quarter of the experts held (2 of 8): the counters read the share,
+    the root span names the model, ``ssm.state_bytes`` counts the KDA states
+    and the three conv windows."""
+    model, params = solar
+    prompts = _prompts([5, 30, 16, 9], seed=1)
+    before = {c: metrics.counter(c).value for c in COUNTERS}
+    rows = _stage(model).transform(_frame(tpu_session, prompts)).collect()
+    assert [r["rowId"] for r in rows] == [0, 1, 2, 3]
+    for prompt, row in zip(prompts, rows):
+        _solar_teacher_forced(params, prompt, row)
+    root = [r for r in tracer.recent()
+            if r.name == "ar_generate.partition"][-1]
+    assert root.attributes["model"] == "solar"
+    delta = {c: metrics.counter(c).value - before[c] for c in COUNTERS}
+    # 2 + 4 + 2 + 2 segments of 8 in five dispatches of two pairs, then 5
+    # decode steps of 4 rows, through 4 layers with 2 experts a token
+    assert delta["moe.tokens_routed"] == (5 * 16 + 5 * 4) * 4 * 2
+    assert delta["moe.tokens_dropped"] == 0
+    # experts 2 and 3 of 8: about a quarter of the pairs, never all of them
+    assert 0 < delta["moe.pairs_held"] < 0.6 * delta["moe.tokens_routed"]
+    # 3 KDA layers x 4 rows of a float32 [4, 8, 8] state and three float32
+    # [3, 32] conv windows
+    assert delta["ssm.state_bytes"] == 3 * 4 * (4 * 8 * 8 * 4 + 3 * 3 * 32 * 4)
+    (runner,) = vars(model)["_ar_generate_runners"].values()
+    assert sorted(key[0] for key in runner.programs) == [
+        "decode", "decode", "prefill"]
+
+
+def test_two_models_one_after_the_other_in_one_process(
+        tpu_session, params, solar):
+    """Neither's state or programs reach the other: each model object keeps
+    its own runner, its programs carry its own name and fingerprint, and a
+    row's result is what it was before the other model ran."""
+    solar_model, solar_params = solar
+    granite = GraniteHybridModel(CONFIG, params)
+    prompts = _prompts([7, 12, 3], seed=6)
+    frame = _frame(tpu_session, prompts)
+    first = _stage(granite).transform(frame).collect()
+    between = _stage(solar_model).transform(frame).collect()
+    again = _stage(granite).transform(frame).collect()
+    for prompt, a, b, c in zip(prompts, first, between, again):
+        _teacher_forced(params, prompt, a)
+        _solar_teacher_forced(solar_params, prompt, b)
+        np.testing.assert_array_equal(a["generated"], c["generated"])
+        np.testing.assert_array_equal(a["record"], c["record"])
+    (granite_runner,) = vars(granite)["_ar_generate_runners"].values()
+    (solar_runner,) = vars(solar_model)["_ar_generate_runners"].values()
+    assert granite_runner is not solar_runner
+    assert granite_runner.model is granite
+    assert solar_runner.model is solar_model
+    # a spare state of one model's shapes is no state of the other's
+    assert not set(granite_runner.states) & set(solar_runner.states)
+    names = [r.attributes["model"] for r in tracer.recent()
+             if r.name == "ar_generate.partition"][-3:]
+    assert names == ["granite", "solar", "granite"]
+    assert granite.fingerprint.split(":")[0] != (
+        solar_model.fingerprint.split(":")[0])

@@ -183,7 +183,8 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     counters = (
         "generate.denoise_forwards", "generate.commit_forwards",
         "generate.commits_fused", "generate.weight_passes",
-        "generate.tokens_fixed", "moe.tokens_routed", "moe.tokens_dropped",
+        "generate.tokens_fixed", "moe.tokens_routed", "moe.pairs_held",
+        "moe.tokens_dropped",
         "moe.expert_load_max", "moe.expert_load_mean")
     before = {c: metrics.counter(c).value for c in counters}
     prompts = _prompts([9, 6, 13], seed=2)
@@ -223,6 +224,8 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     # before as well); through 2 layers with 2 experts a token — and
     # nothing dropped
     assert delta["moe.tokens_routed"] == (512 + (4 + 5 + 5) * 16) * 2 * 2
+    # every expert is held here: the whole of the routed work
+    assert delta["moe.pairs_held"] == delta["moe.tokens_routed"]
     assert delta["moe.tokens_dropped"] == 0
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from chipbench.reference import granite_hybrid as reference
 from sparkdl_tpu.models import granite_hybrid as gh
+from sparkdl_tpu.models import hybrid
 from sparkdl_tpu.transformers.ar_generate import SegmentPlan
 
 CONFIG = dict(
@@ -187,14 +188,14 @@ def test_the_shares_add_up_to_the_uncut_layer(params, cfg):
     alike = np.asarray(reference._mm(
         jax.nn.silu(reference._mm(u, fp["shared_gate"], None))
         * reference._mm(u, fp["shared_up"], None), fp["shared_down"], None))
-    ffn, experts = gh._split_ffn(params)
+    ffn, experts = hybrid.split_ffn(params)
     parts = []
     for lo, hi in ((0, 4), (4, 8)):
         share = dataclasses.replace(
             cfg, num_local_experts=4, routed_experts=8, experts_held=(lo, hi))
         held = {k: v[:, lo:hi] for k, v in experts.items()}
         y, counts = gh._feed_forward(
-            share, gh._at(ffn, layer), held, jnp.int32(layer), x)
+            share, hybrid.at(ffn, layer), held, jnp.int32(layer), x)
         assert int(counts.sum()) == 7 * 2  # routed over all 8 experts
         # y = x + r * (routed part + shared): the routed part alone
         parts.append((np.asarray(y - x) / 0.22) - alike)
@@ -251,7 +252,7 @@ def test_the_fingerprint_covers_every_module_the_programs_compile(
     assert gh.GraniteHybridModel(
         dict(CONFIG, residual_multiplier=1.0), params).fingerprint != before
     sound = inspect.getsource
-    for module in (moe, ssm, gh):
+    for module in (moe, ssm, hybrid, gh):
         gh._source_digest.cache_clear()
         monkeypatch.setattr(
             inspect, "getsource",

@@ -440,3 +440,94 @@ def test_config_from_the_published_keys_and_param_shapes():
     tiny = sdar_moe.init_params(CFG, 1, jnp.float32)
     assert jax.tree_util.tree_map(lambda a: a.shape, tiny) == \
         sdar_moe.param_shapes(CFG)
+
+
+# -- sigmoid scoring with a selection bias (PR 37) --------------------------
+
+def _softmax_route_as_it_was(x, router, top_k, norm_topk=True):
+    """``ops/moe.route`` as PR 36 left it, to the letter."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("norm_topk", [True, False])
+def test_the_softmax_path_is_what_it_was_to_the_bit(norm_topk):
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(33, 16)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(16, 12)), jnp.float32)
+    was = _softmax_route_as_it_was(x, router, 3, norm_topk)
+    for got in (route(x, router, 3, norm_topk),
+                route(x, router, 3, norm_topk, scoring="softmax")):
+        np.testing.assert_array_equal(got[0], was[0])
+        np.testing.assert_array_equal(got[1], was[1])
+    # and the same program: SDAR's and Granite's executables do not change
+    assert str(jax.make_jaxpr(lambda a, b: route(a, b, 3, norm_topk))(
+        x, router)) == str(jax.make_jaxpr(
+            lambda a, b: _softmax_route_as_it_was(a, b, 3, norm_topk))(
+                x, router))
+    with pytest.raises(ValueError, match="softmax"):
+        route(x, router, 3, select_bias=jnp.zeros((12,)))
+    with pytest.raises(ValueError, match="scoring"):
+        route(x, router, 3, scoring="tanh")
+
+
+def test_sigmoid_routing_selects_by_score_plus_bias_and_weighs_by_score():
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(40, 16)).astype(np.float32)
+    router = rng.normal(size=(16, 12)).astype(np.float32)
+    # a bias large enough to change who is chosen for most tokens
+    bias = rng.normal(size=(12,)).astype(np.float32)
+    scores = 1 / (1 + np.exp(-(x.astype(np.float64) @ router)))
+    weights, experts = route(
+        jnp.asarray(x), jnp.asarray(router), 3, scoring="sigmoid",
+        select_bias=jnp.asarray(bias))
+    chosen = np.argsort(-(scores + bias), axis=-1, kind="stable")[:, :3]
+    np.testing.assert_array_equal(experts, chosen)
+    picked = np.take_along_axis(scores, chosen, axis=-1)
+    # float32 sigmoids against float64: a few ulps of values under one
+    np.testing.assert_allclose(
+        weights, picked / picked.sum(-1, keepdims=True), atol=1e-6)
+    plain = np.argsort(-scores, axis=-1, kind="stable")[:, :3]
+    assert (np.sort(plain, -1) != np.sort(chosen, -1)).any()
+    # without a bias: the top scores themselves, not renormalised on demand
+    weights, experts = route(
+        jnp.asarray(x), jnp.asarray(router), 3, norm_topk=False,
+        scoring="sigmoid")
+    np.testing.assert_array_equal(experts, plain)
+    np.testing.assert_allclose(
+        weights, np.take_along_axis(scores, plain, axis=-1), atol=1e-6)
+    # ties go to the lower expert
+    _, tied = route(jnp.zeros((2, 16)), jnp.asarray(router), 3,
+                    scoring="sigmoid")
+    np.testing.assert_array_equal(tied, [[0, 1, 2]] * 2)
+
+
+def test_moe_ffn_passes_the_scoring_through_with_an_eighth_held():
+    """One expert of eight held, sigmoid routing with a bias: ``moe_ffn``'s
+    part is the loop over the held expert with the sigmoid weights, and the
+    counts are over all eight."""
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.normal(size=(24, 16)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
+    bias = jnp.asarray(0.5 * rng.normal(size=(8,)), jnp.float32)
+    experts = {
+        "w_gate": jnp.asarray(0.3 * rng.normal(size=(1, 16, 8)), jnp.float32),
+        "w_up": jnp.asarray(0.3 * rng.normal(size=(1, 16, 8)), jnp.float32),
+        "w_down": jnp.asarray(0.3 * rng.normal(size=(1, 8, 16)), jnp.float32),
+    }
+    with jax.default_matmul_precision("highest"):
+        got, counts = moe_ffn(
+            x, router, experts, top_k=2, experts_held=(5, 6),
+            scoring="sigmoid", select_bias=bias)
+        weights, chosen = route(x, router, 2, scoring="sigmoid",
+                                select_bias=bias)
+        hidden = jax.nn.silu(x @ experts["w_gate"][0]) * (
+            x @ experts["w_up"][0])
+        want = (jnp.sum(jnp.where(chosen == 5, weights, 0.0), axis=-1)[:, None]
+                * (hidden @ experts["w_down"][0]))
+    assert int(counts.sum()) == 24 * 2 and 0 < int(counts[5]) < 24
+    np.testing.assert_allclose(got, want, atol=1e-5 * np.abs(want).max())

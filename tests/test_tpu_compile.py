@@ -463,3 +463,67 @@ def test_granite_programs_compile_and_fit(
     # (prefill: 754,259,968 bytes and a tenth; a float32 copy of the expert
     # layer's pairs [tokens * k, d] alone would add 336 MB)
     assert m.temp_size_in_bytes < (0.83e9 if program == "prefill" else 1.0e9)
+
+
+# -- Solar-Open2 at its published widths (PR 37) -------------------------------
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_solar_programs_compile_and_fit(
+        program, one_chip, no_compile_cache, as_on_the_chip):
+    """The cell's two programs, whole (the three KDA layers are one scan
+    body, so the four layers compile as two): a prefill segment of 16 pairs
+    x 128 positions against the state of 128 rows, and 8 decode steps."""
+    import json
+    import os
+
+    from sparkdl_tpu.models import solar_open2 as so
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "chipbench", "configs",
+            "solar_open2_250b-generate.json")) as fh:
+        cfg = so.SolarOpen2Config.from_dict(json.load(fh))
+    rows, span = 128, 4224
+
+    def on_chip(spec):
+        return jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s, jnp.float32 if path[-1].key in so.FLOAT32_LEAVES
+            else jnp.bfloat16, sharding=one_chip),
+        so.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    state = jax.tree_util.tree_map(
+        on_chip, so.state_spec(cfg, rows, span, jnp.bfloat16))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        compiled = jax.jit(
+            lambda p, s, *rest: so.prefill(p, cfg, s, *rest),
+            donate_argnums=(1,),
+        ).lower(params, state, ints(16, 128), ints(16), ints(16), ints(16)
+                ).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, s: so.decode(p, cfg, s, 8), donate_argnums=(1,)
+        ).lower(params, state).compile()
+    # the grouped expert product is the Pallas kernel: gate, up and down of
+    # the attention layer and of the KDA layers' scan body
+    assert compiled.as_text().count("tpu_custom_call") == 6
+    m = compiled.memory_analysis()
+    # 3,308 M parameters: 6.62 GB of bfloat16
+    weights = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(params))
+    assert 6.61e9 < weights < 6.63e9
+    recurrent = 3 * rows * (4 * 1_048_576 + 2 * 3 * 3 * 8192)
+    cache = 2 * rows * 8 * span * 128 * 2
+    assert m.alias_size_in_bytes >= recurrent + cache  # the state is donated
+    whole = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert 0.25 * V5E_HBM_BYTES < whole < 0.85 * V5E_HBM_BYTES, whole
+    # no second copy of the whole KDA state (1.6 GB) among the temporaries
+    # of a prefill segment; a decode step holds one layer's decayed state
+    # and its successor (0.54 GB each) beside the 128 rows' scores
+    assert m.temp_size_in_bytes < (1.7e9 if program == "prefill" else 2.6e9)
