@@ -72,6 +72,7 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.models.hybrid import (
     HybridModel,
+    Mixer,
     attention_segment,
     attention_token,
     feed_forward,
@@ -327,12 +328,13 @@ def _mamba_token(cfg, lp, u, window, state):
     return _mamba_out(cfg, lp, y, x, z), window, state
 
 
-def _attention_segment(cfg, lp, u, cache_k, cache_v, start):
+def _attention_segment(cfg, lp, u, cache_k, cache_v, layer, rows, start):
     """The mixer over a segment ``u`` [c, n, D] at positions ``start[c] +
-    arange(n)`` (:func:`~sparkdl_tpu.models.hybrid.attention_segment`)."""
+    arange(n)``, on the cache's leaves where they lie
+    (:func:`~sparkdl_tpu.models.hybrid.attention_segment`)."""
     out, cache_k, cache_v = attention_segment(
         *grouped_qkv(lp, u, cfg.num_key_value_heads, cfg.attention_head_dim),
-        cache_k, cache_v, start, cfg.attention_multiplier)
+        cache_k, cache_v, layer, rows, start, cfg.attention_multiplier)
     return jnp.dot(out, lp["wo"]), cache_k, cache_v
 
 
@@ -344,16 +346,17 @@ def _attention_token(cfg, lp, u, cache_k, cache_v, position):
     return jnp.dot(out, lp["wo"]), cache_k, cache_v
 
 
-def _layers(params, cfg, x, state, rows, mamba, attention):
+def _layers(params, cfg, x, state, rows, mamba, attention, in_place=False):
     """Every layer over ``x``
     (:func:`~sparkdl_tpu.models.hybrid.run_layers`): ``mamba(lp, u, window,
     state)`` and ``attention(lp, u, cache_k, cache_v)`` are the two mixers at
-    the caller's shape (a segment or a token).  Returns (x, state, counts [L,
-    E])."""
+    the caller's shape (a segment or a token); ``in_place``: ``attention``
+    takes the cache's leaves whole (``..., layer, rows``).  Returns (x,
+    state, counts [L, E])."""
     return run_layers(
         params, cfg.layer_types, x, state, rows,
-        {"mamba": (("conv", "ssm"), mamba),
-         "attention": (("k", "v"), attention)},
+        {"mamba": Mixer(("conv", "ssm"), mamba),
+         "attention": Mixer(("k", "v"), attention, in_place)},
         functools.partial(_feed_forward, cfg), eps=cfg.rms_norm_eps,
         residual=cfg.residual_multiplier)
 
@@ -385,8 +388,8 @@ def _segment(params, cfg, state, tokens, rows, start, lengths):
 
     return _layers(
         params, cfg, _embed(params, cfg, tokens), state, rows, mamba,
-        lambda lp, u, cache_k, cache_v: _attention_segment(
-            cfg, lp, u, cache_k, cache_v, start))
+        functools.partial(_attention_segment, cfg, start=start),
+        in_place=True)
 
 
 # -- entry points -------------------------------------------------------------

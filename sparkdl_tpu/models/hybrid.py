@@ -9,10 +9,12 @@ rows, ...].
   of stacked weights and of a state leaf);
 - :func:`grouped_qkv`, :func:`attention_segment`, :func:`attention_token`
   (the attention through the cache, up to but not including the output
-  projection, so that a model may gate what it projects);
+  projection, so that a model may gate what it projects), and
+  :func:`keys_scored` (how many slots a plan's segments score);
 - :func:`feed_forward` (``h + r * (MoE(u) + Shared(u))``);
-- :func:`layer_runs`, :func:`run_layers` (every layer in its published order:
-  the runs of recurrent layers scanned, the attention layers written out);
+- :func:`layer_runs`, :class:`Mixer`, :func:`run_layers` (every layer in its
+  published order: the runs of recurrent layers scanned, the attention layers
+  written out);
 - :func:`source_digest` (what identifies a model's mathematics in a
   program's fingerprint) and :class:`HybridModel` (what the stage takes).
 
@@ -24,6 +26,9 @@ from __future__ import annotations
 
 import hashlib
 import inspect
+from typing import Callable, NamedTuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +38,10 @@ from sparkdl_tpu.ops.moe import moe_ffn
 #: stands for "not visible" in a score; finite, so that a row that sees
 #: nothing softmaxes to a uniform garbage and not to NaN
 NEG = -1e30
+#: slots of a row's cache that a prefill segment scores at once
+#: (:func:`attention_segment`, :func:`keys_scored`); chosen on the chip
+#: (PERF.md section 6, PR 38)
+KEY_BLOCK = 512
 
 
 def rms_norm(x, gain, eps: float):
@@ -77,31 +86,90 @@ def grouped_qkv(lp, u, kv_heads: int, head_dim: int):
             jnp.dot(u, lp["wv"]).reshape(*lead, kv_heads, head_dim))
 
 
-def attention_segment(q, k, v, cache_k, cache_v, start, scale: float):
-    """A segment of ``n`` positions a row at ``start[c] + arange(n)``: the
-    segment's keys and values (``grouped_qkv``'s, [c, n, ...]) go into the
-    rows' cache ([c, KV, span, dh]) first, then every position sees the cache
-    up to itself; scores ``q.k * scale``, softmax in float32.  One row at a
-    time (``lax.map``): a row's float32 scores are [heads, n, span].
-    Returns (the heads' outputs [c, n, heads * dh], cache_k, cache_v)."""
-    n = q.shape[1]
-    put = jax.vmap(lambda cache, new, at: jax.lax.dynamic_update_slice(
-        cache, new.transpose(1, 0, 2), (0, at, 0)))
-    cache_k, cache_v = put(cache_k, k, start), put(cache_v, v, start)
-    slots = jnp.arange(cache_k.shape[2], dtype=jnp.int32)
+def _put_segments(cache, new, layer, rows, start):
+    """``new`` [c, n, KV, dh] into ``cache[layer, rows[c], :, start[c] +
+    arange(n)]``, in place where the cache is donated; a row past the last
+    is written nowhere."""
+    index = jnp.stack([jnp.full_like(rows, layer), rows, start], axis=-1)
+    return jax.lax.scatter(
+        cache, index, new.transpose(0, 2, 1, 3).astype(cache.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2, 3), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 3)),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
-    def one_row(row):
-        q, keys, values, start = row
-        scores = jnp.einsum("nkgd,kmd->kgnm", q, keys,
-                            preferred_element_type=jnp.float32)
-        scores = scores * scale
-        visible = slots[None, :] <= start + jnp.arange(n)[:, None]
-        probs = jax.nn.softmax(jnp.where(visible, scores, NEG), axis=-1)
-        out = jnp.einsum("kgnm,kmd->nkgd", probs.astype(values.dtype), values)
-        return out.reshape(n, -1)
 
-    out = jax.lax.map(one_row, (q, cache_k, cache_v, start))
+def attention_segment(q, k, v, cache_k, cache_v, layer, rows, start,
+                      scale: float):
+    """A segment of ``n`` positions a row at ``start[c] + arange(n)``,
+    through layer ``layer`` of the cache's leaves ([layers, rows, KV, span,
+    dh]) where they lie: the segment's keys and values (``grouped_qkv``'s,
+    [c, n, ...]) go into the rows ``rows`` [c] (None: all, in order; an index
+    past the last row writes nothing and reads the last row) first, then
+    every position sees its row's cache up to itself; scores ``q.k * scale``,
+    softmax in float32.  One row at a time (``lax.map``), and of its cache
+    only the blocks of ``KEY_BLOCK`` slots that hold one the segment can see:
+    a loop of ``ceil((start + n) / block)`` steps, so ONE program whatever
+    the ``start``, each folding float32 scores [heads, n, block] into a
+    running maximum, sum and float32 accumulator (the softmax over the
+    visible slots, in its online form).  Returns (the heads' outputs [c, n,
+    heads * dh], cache_k, cache_v)."""
+    c, n, kv, group, dh = q.shape
+    span = cache_k.shape[3]
+    if rows is None:
+        rows = jnp.arange(c, dtype=jnp.int32)
+    cache_k = _put_segments(cache_k, k, layer, rows, start)
+    cache_v = _put_segments(cache_v, v, layer, rows, start)
+    block = min(KEY_BLOCK, span)
+    ahead = jnp.arange(n, dtype=jnp.int32)[:, None]
+    within = jnp.arange(block, dtype=jnp.int32)
+
+    def one_row(pair):
+        q, row, start = pair
+
+        def fold(j, carry):
+            top, total, acc = carry
+            # the span need not be a multiple of the block: its last block
+            # is moved back to end with the span, and what it then shares
+            # with the block before is masked
+            at = jnp.minimum(j * block, span - block)
+            keys, values = (
+                jax.lax.dynamic_slice(
+                    cache, (layer, row, 0, at, 0), (1, 1, kv, block, dh))[0, 0]
+                for cache in (cache_k, cache_v))
+            slots = at + within
+            visible = (slots >= j * block) & (slots <= start + ahead)
+            scores = jnp.einsum("nkgd,kmd->kgnm", q, keys,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(visible, scores * scale, NEG)
+            new_top = jnp.maximum(top, scores.max(axis=-1))
+            probs = jnp.exp(scores - new_top[..., None])
+            kept = jnp.exp(top - new_top)
+            acc = acc * kept[..., None] + jnp.einsum(
+                "kgnm,kmd->kgnd", probs.astype(values.dtype), values,
+                preferred_element_type=jnp.float32)
+            return new_top, total * kept + probs.sum(axis=-1), acc
+
+        _, total, acc = jax.lax.fori_loop(
+            0, (start + n + block - 1) // block, fold,
+            (jnp.full((kv, group, n), NEG, jnp.float32),
+             jnp.zeros((kv, group, n), jnp.float32),
+             jnp.zeros((kv, group, n, dh), jnp.float32)))
+        out = (acc / total[..., None]).astype(cache_v.dtype)
+        return out.transpose(2, 0, 1, 3).reshape(n, -1)
+
+    out = jax.lax.map(
+        one_row, (q, jnp.minimum(rows, cache_k.shape[1] - 1), start))
     return out, cache_k, cache_v
+
+
+def keys_scored(start, n: int, span: int) -> int:
+    """The cache slots :func:`attention_segment` scores for segments of ``n``
+    positions at the positions ``start`` under a span of ``span``: whole
+    blocks up to each segment's own end (the whole span would be ``len(start)
+    * span``).  Host arithmetic, for the stage's counters."""
+    block = min(KEY_BLOCK, span)
+    return int((-(-(np.asarray(start, np.int64) + n) // block)).sum() * block)
 
 
 def attention_token(q, k, v, cache_k, cache_v, position, scale: float):
@@ -161,37 +229,52 @@ def layer_runs(kinds):
     return [tuple(run) for run in out]
 
 
+class Mixer(NamedTuple):
+    """A kind of layer's mixer in :func:`run_layers`: the names of the state
+    leaves a layer of the kind owns ([layers of the kind, rows, ...]) and
+    ``mix``, the mixer at the caller's shape (a segment or a token) on the
+    layer's weights: ``mix(lp, u, *slices) -> (out, *new slices)`` on the
+    layer's rows of each leaf, read before it and written after it; or, where
+    ``in_place``, ``mix(lp, u, *leaves, layer, rows) -> (out, *leaves)`` on
+    the leaves whole, of which it reads and writes what it needs itself."""
+
+    leaves: tuple
+    mix: Callable
+    in_place: bool = False
+
+
 def run_layers(params, kinds, x, state, rows, mixers, ffn_of, *, eps: float,
                residual: float = 1.0):
     """Every layer over ``x``, in the order of ``kinds``.
 
-    ``mixers[kind] = (leaves, mix)``: the names of the state leaves a layer
-    of that kind owns ([layers of the kind, rows, ...]) and ``mix(lp, u,
-    *slices) -> (out, *new slices)``, the mixer at the caller's shape (a
-    segment or a token) on the layer's weights ``params[kind]`` at its index;
-    ``"attention"`` layers are written out, every run of another kind is one
-    ``lax.scan`` over its indices.  ``ffn_of(fp, experts, layer, h)`` is the
-    feed-forward (:func:`feed_forward` with the model's settings).  ``x`` is
-    about the rows ``rows`` of ``state`` (None: all of them, in order); a
-    layer reads and writes only its own slice of the state, so no copy of
-    more than one layer's rows is ever alive.  Returns (x, state, counts [L,
-    E])."""
+    ``mixers[kind]`` is the kind's :class:`Mixer`, run on the layer's weights
+    ``params[kind]`` at its index; ``"attention"`` layers are written out,
+    every run of another kind is one ``lax.scan`` over its indices.
+    ``ffn_of(fp, experts, layer, h)`` is the feed-forward
+    (:func:`feed_forward` with the model's settings).  ``x`` is about the
+    rows ``rows`` of ``state`` (None: all of them, in order); a layer reads
+    and writes only its own slice of the state, so no copy of more than one
+    layer's rows is ever alive.  Returns (x, state, counts [L, E])."""
     ffn, experts = split_ffn(params)
     state = dict(state)
     counts = []
 
     def one_layer(kind, lp, x, held, of_kind, layer):
-        _, mix = mixers[kind]
-        out, *new = mix(
-            lp, rms_norm(x, lp["in_norm"], eps),
-            *(read(leaf, of_kind, rows) for leaf in held))
+        mixer = mixers[kind]
+        u = rms_norm(x, lp["in_norm"], eps)
+        if mixer.in_place:
+            out, *held = mixer.mix(lp, u, *held, of_kind, rows)
+        else:
+            out, *new = mixer.mix(
+                lp, u, *(read(leaf, of_kind, rows) for leaf in held))
+            held = [write(leaf, value, of_kind, rows)
+                    for leaf, value in zip(held, new)]
         h = x + (residual * out).astype(x.dtype)
         y, routed = ffn_of(at(ffn, layer), experts, layer, h)
-        return y, tuple(write(leaf, value, of_kind, rows)
-                        for leaf, value in zip(held, new)), routed
+        return y, tuple(held), routed
 
     for kind, first, of_kind, count in layer_runs(kinds):
-        names = mixers[kind][0]
+        names = mixers[kind].leaves
         held = tuple(state[name] for name in names)
         if kind == "attention":
             for offset in range(count):

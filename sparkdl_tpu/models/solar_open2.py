@@ -75,6 +75,7 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.models.hybrid import (
     HybridModel,
+    Mixer,
     attention_segment,
     attention_token,
     feed_forward,
@@ -361,10 +362,12 @@ def _gated(cfg, lp, u, out):
     return jnp.dot(out, lp["wo"])
 
 
-def _attention_segment(cfg, lp, u, cache_k, cache_v, start):
+def _attention_segment(cfg, lp, u, cache_k, cache_v, layer, rows, start):
+    """The mixer over a segment, on the cache's leaves where they lie
+    (:func:`~sparkdl_tpu.models.hybrid.attention_segment`)."""
     out, cache_k, cache_v = attention_segment(
         *grouped_qkv(lp, u, cfg.num_key_value_heads, cfg.head_dim),
-        cache_k, cache_v, start, cfg.head_dim ** -0.5)
+        cache_k, cache_v, layer, rows, start, cfg.head_dim ** -0.5)
     return _gated(cfg, lp, u, out), cache_k, cache_v
 
 
@@ -382,14 +385,16 @@ def _feed_forward(cfg, fp, experts, layer, h):
         top_k=cfg.num_experts_per_tok, held=cfg.held, scoring="sigmoid")
 
 
-def _layers(params, cfg, x, state, rows, kda, attention):
+def _layers(params, cfg, x, state, rows, kda, attention, in_place=False):
     """Every layer over ``x``
     (:func:`~sparkdl_tpu.models.hybrid.run_layers`): ``kda(lp, u, windows,
     state)`` and ``attention(lp, u, cache_k, cache_v)`` are the two mixers at
-    the caller's shape (a segment or a token)."""
+    the caller's shape (a segment or a token); ``in_place``: ``attention``
+    takes the cache's leaves whole (``..., layer, rows``)."""
     return run_layers(
         params, cfg.layer_types, x, state, rows,
-        {"kda": (("conv", "kda"), kda), "attention": (("k", "v"), attention)},
+        {"kda": Mixer(("conv", "kda"), kda),
+         "attention": Mixer(("k", "v"), attention, in_place)},
         functools.partial(_feed_forward, cfg), eps=cfg.rms_norm_eps)
 
 
@@ -417,8 +422,8 @@ def _segment(params, cfg, state, tokens, rows, start, lengths):
 
     return _layers(
         params, cfg, _embed(params, tokens), state, rows, kda,
-        lambda lp, u, cache_k, cache_v: _attention_segment(
-            cfg, lp, u, cache_k, cache_v, start))
+        functools.partial(_attention_segment, cfg, start=start),
+        in_place=True)
 
 
 # -- entry points -------------------------------------------------------------

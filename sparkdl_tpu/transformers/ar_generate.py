@@ -29,12 +29,18 @@ once per model object; an executable holds no weight constants.
 Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``ar_generate.partition`` (root; ``model``: the model's ``.name``, ``rows``,
 ``batches``, ``prompt_tokens``, ``generated_tokens``) > ``ar_generate.plan``, ``engine.place``,
-``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``),
+``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
+``keys_scored``, ``keys_spanned``),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``),
 ``engine.fetch_wait``, ``ar_generate.postprocess``.  Counters:
 ``ar_generate.prefill_tokens`` (real prompt tokens),
 ``ar_generate.prefill_pad_tokens`` (positions pushed through the layers that
-were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
+were pads, spare pairs or dummy rows), ``ar_generate.keys_scored`` (the
+cache slots the dispatches' pairs, spare ones too, score in an attention
+layer: whole blocks up to each pair's own end,
+:func:`~sparkdl_tpu.models.hybrid.keys_scored`) and
+``ar_generate.keys_spanned`` (pairs x span: what scoring the whole span would
+come to), ``ar_generate.decode_steps`` and
 ``ar_generate.decode_dispatches`` (a batch),
 ``ar_generate.decode_expert_reads`` (the (step, layer, held expert) triples
 that got a token: the expert matrices a batch's decode steps had to read),
@@ -54,6 +60,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from sparkdl_tpu.ml.base import Transformer
+from sparkdl_tpu.models.hybrid import keys_scored
 from sparkdl_tpu.param.base import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasInputCol, HasOutputCol
 from sparkdl_tpu.transformers.generation import (
@@ -129,6 +136,10 @@ class SegmentPlan:
             self.dispatches.append(((tokens, index, start, held), last))
         self.pad_tokens = (
             len(self.dispatches) * count * segment - self.real_tokens)
+        starts = np.concatenate(
+            [start for (_, _, start, _), _ in self.dispatches])
+        self.keys_scored = keys_scored(starts, segment, self.span)
+        self.keys_spanned = len(starts) * self.span
 
 
 class _Runner(ProgramRunner):
@@ -326,7 +337,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
     try:
         with tracer.boundary(
                 "ar_generate.prefill", tokens=plan.real_tokens,
-                pad_tokens=plan.pad_tokens, segments=len(plan.dispatches)):
+                pad_tokens=plan.pad_tokens, segments=len(plan.dispatches),
+                keys_scored=plan.keys_scored, keys_spanned=plan.keys_spanned):
             for arrays, (_, last) in zip(placed, plan.dispatches):
                 state, token, logprob, counts = runner.prefill(state, *arrays)
                 landed(window.submit(
@@ -355,6 +367,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
         tokens_out = [r[:, 0].astype(np.int32) for r in records_out]
     metrics.counter("ar_generate.prefill_tokens").add(plan.real_tokens)
     metrics.counter("ar_generate.prefill_pad_tokens").add(plan.pad_tokens)
+    metrics.counter("ar_generate.keys_scored").add(plan.keys_scored)
+    metrics.counter("ar_generate.keys_spanned").add(plan.keys_spanned)
     metrics.counter("ar_generate.decode_steps").add(gen - 1)
     metrics.counter("ar_generate.decode_dispatches").add(dispatches)
     metrics.counter("ar_generate.decode_expert_reads").add(reads)

@@ -174,6 +174,11 @@ def test_the_plan_of_a_batch():
     assert [last for _, last in plan.dispatches] == [
         [], [(1, 0), (2, 2)], [(1, 3), (2, 4)], [(0, 1), (1, 5)]]
     assert plan.pad_tokens == 4 * 3 * 8 - 60
+    # attention_segment writes a dispatch's segments into the cache in
+    # place, row by row: no row twice in a dispatch
+    for group in pairs:
+        named = [row for row, _, _ in group if row < 6]
+        assert len(set(named)) == len(named)
     tokens = plan.dispatches[2][0][0]
     np.testing.assert_array_equal(tokens[0], prompts[1][16:24])
     np.testing.assert_array_equal(tokens[1], [prompts[3][8]] + [0] * 7)
@@ -210,8 +215,10 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     # pairs: two segments of rows 0 and 2, one of row 1 and of the dummy
     # row (its one token): three dispatches of 2 x 8 positions
     prefill = [r for r in inside if r.name == "ar_generate.prefill"][-1]
+    # six pairs under a span of 512 + 128, each scoring one block of 512
     assert prefill.attributes == {
-        "tokens": 28, "pad_tokens": 3 * 16 - 28, "segments": 3}
+        "tokens": 28, "pad_tokens": 3 * 16 - 28, "segments": 3,
+        "keys_scored": 6 * 512, "keys_spanned": 6 * 640}
     decodes = [r for r in inside if r.name == "ar_generate.decode"]
     assert [d.attributes for d in decodes] == [
         {"steps": 2, "rows": 4}, {"steps": 2, "rows": 4},
@@ -235,6 +242,39 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert delta["moe.pairs_held"] == delta["moe.tokens_routed"]
     assert delta["moe.tokens_dropped"] == 0
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
+
+
+def test_keys_scored_and_spanned_are_the_plans_sums(
+        tpu_session, params, model, monkeypatch):
+    """What the prefill's attention scores, from the plan alone: every pair
+    of every dispatch, the spare one (row 3 of 3, ``start`` 0) too, whole
+    blocks up to ITS ``start + 8`` — against pairs x span, which the form
+    before PR 38 scored."""
+    from sparkdl_tpu.models import hybrid
+
+    monkeypatch.setattr(hybrid, "KEY_BLOCK", 24)
+    names = ("ar_generate.keys_scored", "ar_generate.keys_spanned")
+    before = [metrics.counter(c).value for c in names]
+    prompts = _prompts([30, 5, 9], seed=5)
+    rows = _stage(model, batch=3).transform(
+        _frame(tpu_session, prompts)).collect()
+    plan = SegmentPlan(prompts, 3, 8, 2, GEN)
+    starts = [int(s) for arrays, _ in plan.dispatches for s in arrays[2]]
+    spare = sum(int(r) == 3 for arrays, _ in plan.dispatches
+                for r in arrays[1])
+    assert spare == 1 and len(starts) == 2 * len(plan.dispatches) == 8
+    scored = sum(-(-(start + 8) // 24) * 24 for start in starts)
+    spanned = len(starts) * 640
+    assert 0 < scored < spanned
+    prefill = [r for r in tracer.recent()
+               if r.name == "ar_generate.prefill"][-1]
+    assert prefill.attributes["keys_scored"] == scored
+    assert prefill.attributes["keys_spanned"] == spanned
+    assert [metrics.counter(c).value - b for c, b in zip(names, before)] == [
+        scored, spanned]
+    # and the blocks of 24 generate what the reference does
+    for row, prompt in zip(rows, prompts):
+        _teacher_forced(params, prompt, row)
 
 
 def test_programs_and_state_are_shared_across_models_of_one_config(
