@@ -29,6 +29,10 @@ cache outcomes (in-memory hits are free and uncounted),
 ``engine.compile`` / ``engine.cache_load`` time the slow paths, and
 ``engine.inflight`` gauges the dispatch window.  ``engine.compile``
 spans appear only on actual compiles — a traced warm start shows none.
+What the device did with the dispatches is the dispatch window's to tell
+(:mod:`~sparkdl_tpu.engine.executor`): ``engine.device`` /
+``engine.transfer`` spans, the device's own timeline, and
+``engine.starved``, the host's count of nothing dispatched.
 """
 
 from __future__ import annotations

@@ -18,22 +18,38 @@ for that, in the transformers' loop
 The window is deliberately not a thread: jax's own runtime provides the
 asynchrony; this class only decides *when* to synchronize.
 
-It is also where the host can tell that the device has nothing to do:
-every window counts into one process-wide number of results dispatched
-and not yet fetched (:class:`_Outstanding`), and the time that number
-spends at zero is recorded as ``engine.starved`` boundary spans
-(:mod:`sparkdl_tpu.obs.trace`).
+It is also where the program keeps its two accounts of the device, both
+as boundary spans in the tracer's ring (:mod:`sparkdl_tpu.obs.trace`).
+``engine.starved`` (:class:`_Outstanding`) is what the dispatching
+thread can tell by counting: the stretches in which no result of any
+window was dispatched and unfetched.  It sees every moment the host
+left the device without work and misses the rest: a batch still on its
+way to an idle device, a result that was ready long before its fetch,
+which program ran when.  ``engine.device`` and ``engine.transfer``
+(:class:`_CompletionWatcher`) are the device's own timeline, taken by
+one thread that only waits: when each dispatch a caller names
+(``submit(..., program=)``) became ready and, from stream order, when it
+began to compute; when each placed batch arrived.  They see what the
+device did and miss only what a wait cannot be begun for: an input
+that its program's donation deleted before the wait started
+(``observed=False``).
+Read ``engine.device`` for how busy the device was and with what;
+``engine.starved`` stays as the count that needs no second thread.
 """
 
 from __future__ import annotations
 
+import logging
+import sys
 import threading
 from collections import deque
-from typing import Any, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from sparkdl_tpu.obs.trace import tracer
+from sparkdl_tpu.obs.trace import Span, tracer
+
+logger = logging.getLogger(__name__)
 
 #: the in-flight window depth of a :class:`DispatchWindow` made without
 #: one: one batch computing, one transferring
@@ -88,12 +104,13 @@ class _Outstanding:
     process.  The clock is stamped when the count falls to zero, and the
     next ``submitted`` that finds it zero records the boundary span
     ``engine.starved`` from the stamp to now (none before the first
-    submit of the process).  That is a lower bound on the device's idle
-    time — exact where the host blocks on the last result of a
-    partition — and, being a span in the tracer's ring, it can be laid
-    over what the dispatching thread ran meanwhile.  A window dropped
-    with results in flight and never ``abandon``-ed holds the count up,
-    which only lowers the bound."""
+    submit of the process).  That is the host's account: what the
+    dispatching thread knows without waiting for anything.  It leaves
+    out a device that waits for a dispatched batch's input and one that
+    finished long before the fetch; ``engine.device``
+    (:class:`_CompletionWatcher`) measures those.  A window dropped with
+    results in flight and never ``abandon``-ed holds the count up, which
+    only shortens the stretches."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -118,6 +135,147 @@ class _Outstanding:
 
 
 _outstanding = _Outstanding()
+
+
+class _Watched:
+    """One entry of the watcher's queue: an array to wait for, and the
+    span to write when it is ready."""
+
+    __slots__ = ("leaf", "name", "stamp_ns", "parent", "attributes",
+                 "input", "ready_ns", "dropped")
+
+    def __init__(self, leaf: Any, name: str, stamp_ns: int, parent: Span,
+                 attributes: Dict[str, Any],
+                 input: Optional["_Watched"] = None):
+        self.leaf, self.name, self.stamp_ns = leaf, name, stamp_ns
+        self.parent, self.attributes = parent, attributes
+        #: the transfer whose batch this dispatch computes on
+        self.input = input
+        #: when the wait returned; None until then, and for a wait that failed
+        self.ready_ns: Optional[int] = None
+        self.dropped = False
+
+
+class _CompletionWatcher:
+    """The device's timeline, from ONE daemon thread that only waits.
+
+    A device runs one program at a time in the order of dispatch.  So
+    the thread calls ``block_until_ready()`` (which releases the GIL) on
+    one leaf of every watched result, in submission order, and writes at
+    each return the backdated boundary span ``engine.device``: from the
+    latest of the entry before's ready time, the arrival of this
+    entry's input where that was seen, and its own dispatch stamp, to
+    now; ``queued_ms`` is what lay between the dispatch and that start.
+    A placed batch handed over before its dispatch is waited for the
+    same way and gives ``engine.transfer``, from the start of its
+    ``engine.place`` to its arrival; the thread reaches it when the
+    dispatch before is ready, which is early enough: a batch that had
+    arrived by then exposed nothing.  A donation that XLA takes up (an
+    output can live in the input's buffer) deletes the array at
+    dispatch: a wait begun before goes on to the arrival, one begun
+    after fails at once, and the span closes there with
+    ``observed=False`` (counter ``engine.transfers_unobserved``).  One
+    that XLA drops (the featurizer's uint8 batch fits no float32
+    output) leaves the array alive, and every arrival is seen.
+
+    Both are children of the span the caller names (a partition's root)
+    on this thread, so no reader of the dispatching thread's self times
+    sees them.  The thread observes and decides nothing: nobody waits
+    for it, and it holds its lock across no wait.  What it cannot give
+    better than the GIL's switch interval (5 ms while another thread
+    computes in Python) moves time between neighbouring spans and
+    cancels in their sum; a span whose parent ended meanwhile ends with
+    the parent, so the newest span of the ring is never this thread's."""
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._entries: "deque[_Watched]" = deque()
+        self._thread: Optional[threading.Thread] = None
+        self._waiting_for: Optional[_Watched] = None
+        self._ready_ns = 0  # when the dispatch before became ready
+
+    def watch(self, entry: _Watched) -> None:
+        with self._cond:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="sparkdl-completion-watcher",
+                    daemon=True)
+                self._thread.start()
+            self._entries.append(entry)
+            self._cond.notify_all()
+
+    def drop(self, entries: List[_Watched]) -> None:
+        """Forget ``entries``: none of them gets a span, not even the one
+        being waited for."""
+        with self._cond:
+            for entry in entries:
+                entry.dropped = True
+            self._entries = deque(
+                e for e in self._entries if not e.dropped)
+
+    def settle(self, timeout: Optional[float] = None) -> bool:
+        """Wait (the tests do, no dispatching thread ever) until every
+        entry handed over so far has its span; False on timeout."""
+        with self._cond:
+            return self._cond.wait_for(
+                lambda: not self._entries and self._waiting_for is None,
+                timeout)
+
+    def _run(self) -> None:
+        while True:
+            with self._cond:
+                self._waiting_for = None
+                self._cond.notify_all()
+                while not self._entries:
+                    self._cond.wait()
+                entry = self._waiting_for = self._entries.popleft()
+            try:
+                self._wait_for(entry)
+            except Exception:  # the thread outlives whatever one entry does
+                logger.exception("completion watcher: %s", entry.name)
+
+    def _wait_for(self, entry: _Watched) -> None:
+        leaf, entry.leaf = entry.leaf, None
+        failed = False
+        try:
+            wait = getattr(leaf, "block_until_ready", None)
+            if wait is not None:
+                wait()
+        except Exception:
+            # a donated input deleted before the wait began, or a
+            # computation that failed (which the fetch raises as before)
+            failed = True
+        del leaf
+        attributes = dict(entry.attributes)
+        if entry.name == "engine.device":
+            arrived = entry.input.ready_ns if entry.input else None
+            start = max(self._ready_ns, arrived or 0, entry.stamp_ns)
+            attributes["queued_ms"] = (start - entry.stamp_ns) / 1e6
+            if failed:
+                attributes["error"] = True
+        else:
+            start = entry.stamp_ns
+            attributes["observed"] = not failed
+            if failed:
+                from sparkdl_tpu.utils.metrics import metrics
+
+                metrics.counter("engine.transfers_unobserved").add(1)
+        # ready before its fetch came back, and that before its partition
+        # ended: where the GIL kept this thread longer, the parent's end
+        # is the nearer bound (and no span of a partition outlives it)
+        end = min(tracer.clock_ns(), entry.parent.end_ns or sys.maxsize)
+        start = min(start, end)
+        if not entry.dropped:
+            with tracer.boundary(entry.name, parent=entry.parent,
+                                 start_ns=start, end_ns=end, **attributes):
+                pass
+        if entry.name == "engine.device":
+            self._ready_ns = end
+        elif not failed:
+            entry.ready_ns = end
+
+
+_watcher = _CompletionWatcher()
 
 
 def _host_nbytes(host: Any) -> int:
@@ -161,13 +319,24 @@ class DispatchWindow:
     meta)`` instead of raising, so the caller never loses the meta of a
     failed batch.  The ``engine.inflight`` gauge tracks the live window
     depth.
+
+    A caller that knows what it dispatched names it:
+    ``submit(result, meta, program=name, parent=span, rows=n)`` has the
+    process's :class:`_CompletionWatcher` write an ``engine.device`` span
+    under ``span`` when the result is ready, and ``watch_transfer`` an
+    ``engine.transfer`` for the batch the next submit computes on.  The
+    window waits for neither; without ``program`` nothing is watched.
     """
 
     def __init__(self, depth: Optional[int] = None,
                  capture_errors: bool = False):
         self.depth = DEFAULT_DEPTH if depth is None else max(0, int(depth))
         self.capture_errors = bool(capture_errors)
-        self._inflight: "deque[Tuple[Any, Any]]" = deque()
+        #: (result, meta, what the watcher holds of it)
+        self._inflight: "deque[Tuple[Any, Any, Tuple[_Watched, ...]]]" = (
+            deque())
+        #: the transfer handed over for the next submit
+        self._transfer: Optional[_Watched] = None
         from sparkdl_tpu.utils.metrics import metrics
 
         self._gauge = metrics.gauge("engine.inflight")
@@ -194,7 +363,7 @@ class DispatchWindow:
         return out
 
     def _pop(self) -> Tuple[Any, Any]:
-        result, meta = self._inflight.popleft()
+        result, meta, _ = self._inflight.popleft()
         self._gauge.set(len(self._inflight))
         try:
             with tracer.boundary("engine.fetch_wait") as span:
@@ -208,12 +377,43 @@ class DispatchWindow:
         finally:
             _outstanding.fetched()
 
-    def submit(self, result: Any, meta: Any = None) -> List[Tuple[Any, Any]]:
+    def watch_transfer(self, placed: Any, start_ns: int,
+                       parent: Optional[Span], **attributes: Any) -> None:
+        """Hand the batch just placed, BEFORE its dispatch, to the
+        watcher: ``engine.transfer`` under ``parent`` from ``start_ns``
+        (the placing's start) to the arrival of its last leaf, which the
+        next watched ``submit`` takes as its input's."""
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(placed)
+        if parent is None or not leaves:
+            return
+        self._transfer = _Watched(
+            leaves[-1], "engine.transfer", start_ns, parent, attributes)
+        _watcher.watch(self._transfer)
+
+    def submit(self, result: Any, meta: Any = None, *,
+               program: Optional[str] = None, parent: Optional[Span] = None,
+               **attributes: Any) -> List[Tuple[Any, Any]]:
         """Enqueue a dispatched result; returns the (host_result, meta)
-        pairs that just fell out of the window (possibly empty)."""
+        pairs that just fell out of the window (possibly empty).
+        ``program`` (with ``parent``, the span its ``engine.device`` goes
+        under, and what else the span should say: ``rows``, ``steps``)
+        has the result's completion watched."""
+        watched: Tuple[_Watched, ...] = ()
+        if program is not None and parent is not None:
+            import jax
+
+            transfer, self._transfer = self._transfer, None
+            entry = _Watched(
+                jax.tree_util.tree_leaves(result)[0], "engine.device",
+                tracer.clock_ns(), parent,
+                dict(attributes, program=program), input=transfer)
+            _watcher.watch(entry)
+            watched = (entry,) if transfer is None else (transfer, entry)
         _outstanding.submitted()
         _start_host_copy(result)
-        self._inflight.append((result, meta))
+        self._inflight.append((result, meta, watched))
         self._gauge.set(len(self._inflight))
         out = []
         while len(self._inflight) > self.depth:
@@ -227,7 +427,14 @@ class DispatchWindow:
 
     def abandon(self) -> None:
         """Drop in-flight results without fetching (error-path cleanup;
-        the device arrays are released to GC)."""
+        the device arrays are released to GC, and the watcher forgets
+        them: a result nobody fetched gets no span)."""
+        unfetched = [e for _, _, watched in self._inflight for e in watched]
+        if self._transfer is not None:
+            unfetched.append(self._transfer)
+            self._transfer = None
+        if unfetched:
+            _watcher.drop(unfetched)
         _outstanding.fetched(len(self._inflight))
         self._inflight.clear()
         self._gauge.set(0)

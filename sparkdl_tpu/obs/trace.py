@@ -214,13 +214,15 @@ class Span:
             return None
         return (self._end - self._start) * 1000.0
 
-    def end(self) -> None:
+    def end(self, end_ns: Optional[int] = None) -> None:
         """Close the span and deliver it to the tracer's sinks.
-        Idempotent — a double end keeps the first timestamp."""
+        Idempotent — a double end keeps the first timestamp.  ``end_ns``
+        closes it at a ``clock_ns`` stamp already past, for a maker that
+        learnt of the end late."""
         with self._lock:
             if self._end is not None:
                 return
-            self.end_ns = self._tracer.clock_ns()
+            self.end_ns = self._tracer.clock_ns() if end_ns is None else end_ns
             self._end = self.end_ns / 1e9
         self._tracer._deliver(self)
 
@@ -449,7 +451,8 @@ class Tracer:
 
     @contextmanager
     def boundary(self, name: str, parent: Optional[Span] = None,
-                 start_ns: Optional[int] = None, **attributes: Any):
+                 start_ns: Optional[int] = None,
+                 end_ns: Optional[int] = None, **attributes: Any):
         """Open a span where work crosses a LAYER boundary (a partition,
         a pack, a dispatch, a fetch: a handful per batch).  An ordinary
         :class:`Span` — current for the block, child of ``parent`` or of
@@ -463,7 +466,11 @@ class Tracer:
         returns None while tracing is disabled.  ``start_ns`` backdates
         the span to a ``clock_ns`` stamp taken when an interval began
         that only its end reveals (``engine.starved``); such a span
-        cannot be annotated."""
+        cannot be annotated.  ``end_ns`` closes it at a stamp already past
+        (``engine.device`` under a partition that ended before the
+        watcher's thread got to write it): the ring takes the record when
+        it is written, so such a one stands behind a span that ended
+        after it."""
         if parent is None:
             parent = self._current.get()
         sp = Span(self, name, parent, attributes, boundary=True,
@@ -476,7 +483,7 @@ class Tracer:
             self._current.reset(token)
             if annotation is not None:
                 annotation.__exit__(None, None, None)
-            sp.end()
+            sp.end(end_ns)
 
     def start_boundary(self, name: str, **attributes: Any) -> Span:
         """A ROOT boundary span that its maker ends by hand
@@ -490,7 +497,8 @@ class Tracer:
 
     def recent(self) -> List[BoundaryRecord]:
         """A snapshot of the ring: the newest finished boundary spans,
-        oldest first by END time (a parent follows its children)."""
+        oldest first by END time (a parent follows its children, but for
+        one closed at a stamp already past: ``boundary(end_ns=)``)."""
         with self._ring_lock:
             return list(self._ring)
 

@@ -32,15 +32,17 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
 ``keys_scored``, ``keys_spanned``),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``),
-``engine.fetch_wait``, ``ar_generate.postprocess``.  Counters:
+``engine.fetch_wait``, ``ar_generate.postprocess``; and, on the engine's
+watcher thread, one ``engine.device`` a dispatch (``program``:
+``<model>_prefill`` / ``<model>_decode``; ``rows``, ``steps``,
+``queued_ms``): when the device computed it.  ``keys_scored`` counts the
+cache slots the dispatches' pairs, spare ones too, score in an attention
+layer (whole blocks up to each pair's own end,
+:func:`~sparkdl_tpu.models.hybrid.keys_scored`), ``keys_spanned`` pairs x
+span: what scoring the whole span would come to.  Counters:
 ``ar_generate.prefill_tokens`` (real prompt tokens),
 ``ar_generate.prefill_pad_tokens`` (positions pushed through the layers that
-were pads, spare pairs or dummy rows), ``ar_generate.keys_scored`` (the
-cache slots the dispatches' pairs, spare ones too, score in an attention
-layer: whole blocks up to each pair's own end,
-:func:`~sparkdl_tpu.models.hybrid.keys_scored`) and
-``ar_generate.keys_spanned`` (pairs x span: what scoring the whole span would
-come to), ``ar_generate.decode_steps`` and
+were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
 ``ar_generate.decode_dispatches`` (a batch),
 ``ar_generate.decode_expert_reads`` (the (step, layer, held expert) triples
 that got a token: the expert matrices a batch's decode steps had to read),
@@ -299,12 +301,15 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
 
 def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
                     steps: int):
-    """(tokens [gen] a prompt, record [gen, 2] a prompt), in order."""
+    """(tokens [gen] a prompt, record [gen, 2] a prompt), in order.  Every
+    dispatch's ``engine.device`` span goes under the span the call finds
+    open: the partition's root."""
     from sparkdl_tpu.engine import DispatchWindow
     from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
 
     model = runner.model
+    root = tracer.current()
     with tracer.boundary("ar_generate.plan", rows=len(prompts)) as span:
         plan = SegmentPlan(prompts, rows, runner.segment, runner.count, gen)
         span.set_attribute("segments", len(plan.dispatches))
@@ -343,14 +348,18 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
                 state, token, logprob, counts = runner.prefill(state, *arrays)
                 landed(window.submit(
                     (token, logprob, counts),
-                    meta=(last, runner.count * runner.segment)))
+                    meta=(last, runner.count * runner.segment),
+                    program=f"{model.name}_prefill", parent=root,
+                    rows=runner.count))
         dispatches, left = 0, gen - 1
         while left:
             now = min(steps, left)
             with tracer.boundary("ar_generate.decode", steps=now, rows=rows):
                 state, token, logprob, counts = runner.decode(state, now)
             landed(window.submit(
-                (token, logprob, counts), meta=(None, now * rows)))
+                (token, logprob, counts), meta=(None, now * rows),
+                program=f"{model.name}_decode", parent=root, rows=rows,
+                steps=now))
             dispatches, left = dispatches + 1, left - now
         landed(window.drain())
     finally:
@@ -367,8 +376,6 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
         tokens_out = [r[:, 0].astype(np.int32) for r in records_out]
     metrics.counter("ar_generate.prefill_tokens").add(plan.real_tokens)
     metrics.counter("ar_generate.prefill_pad_tokens").add(plan.pad_tokens)
-    metrics.counter("ar_generate.keys_scored").add(plan.keys_scored)
-    metrics.counter("ar_generate.keys_spanned").add(plan.keys_spanned)
     metrics.counter("ar_generate.decode_steps").add(gen - 1)
     metrics.counter("ar_generate.decode_dispatches").add(dispatches)
     metrics.counter("ar_generate.decode_expert_reads").add(reads)

@@ -32,14 +32,16 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``generate.prefill``, ``generate.block``, ``engine.fetch_wait``,
 ``generate.postprocess``; ``generate.block`` carries ``denoise_forwards``,
 ``commit_forwards`` (1 where the step committed the block before it, 0 on
-block 0), ``fused`` (the same: that commit shared a forward) and
-``weight_passes``.  Counters, per real row-block:
+block 0), ``fused`` (the same: that commit shared a forward),
+``weight_passes`` (passes over the layers' weights: ``denoisingSteps``),
+``row_passes`` (those, times the real rows still generating in the block:
+what a fixed token is paid for with) and ``fixed``.  On the engine's watcher thread, one ``engine.device`` a dispatch
+(``program``: ``sdar_prefill`` / ``sdar_block``; ``rows``, ``queued_ms``):
+when the device computed it.  Counters, per real row-block:
 ``generate.denoise_forwards``,
 ``generate.commit_forwards`` (blocks whose final tokens went through the
-layers for the cache: all but a row's last), ``generate.commits_fused``
-(those of them that shared a pass over the weights with a denoising
-forward: all), ``generate.weight_passes`` (passes over the layers' weights:
-``denoisingSteps`` a block), ``generate.tokens_fixed``; and
+layers for the cache: all but a row's last, each inside a denoising
+forward), ``generate.tokens_fixed``; and
 ``moe.tokens_routed``, ``moe.pairs_held``, ``moe.tokens_dropped``,
 ``moe.expert_load_max``, ``moe.expert_load_mean`` (from the routing counts
 that come back with every program's result).
@@ -120,6 +122,10 @@ class BatchPlan:
             self.chunks.append((min(at, rows - count), count, length))
             at += count
         self.prefilled = int(self.whole.sum())
+
+    def live_rows(self, index: int) -> int:
+        """Real rows that still generate in block ``index``."""
+        return int((self.blocks_of_row > index).sum())
 
     def fixed_in_block(self, index: int) -> int:
         """Positions the real rows fix in block ``index``."""
@@ -334,12 +340,15 @@ class BlockDiffusionTransformer(Transformer, HasInputCol, HasOutputCol):
 
 
 def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
-    """(tokens [gen] a prompt, record [positions, 3] a prompt), in order."""
+    """(tokens [gen] a prompt, record [positions, 3] a prompt), in order.
+    Every dispatch's ``engine.device`` span goes under the span the call
+    finds open: the partition's root."""
     from sparkdl_tpu.engine import DispatchWindow
     from sparkdl_tpu.obs.trace import tracer
     from sparkdl_tpu.utils.metrics import metrics
 
     block, steps = runner.block, runner.steps
+    root = tracer.current()
     with tracer.boundary("generate.plan", rows=len(prompts)) as span:
         plan = BatchPlan(prompts, rows, block, gen)
         span.set_attribute("chunks", len(plan.chunks))
@@ -370,7 +379,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
                 cache_k, cache_v, counts = runner.prefill(
                     cache_k, cache_v, tokens, whole, first_row, count, length)
                 landed(window.submit(
-                    (counts,), meta=("prefill", count * length)))
+                    (counts,), meta=("prefill", count * length),
+                    program="sdar_prefill", parent=root, rows=count))
         start = whole
         fixed = [plan.fixed_in_block(index) for index in range(plan.blocks)]
         # a block's final tokens stay on the device and ride the next
@@ -382,7 +392,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
             with tracer.boundary(
                 "generate.block", index=index, denoise_forwards=steps,
                 commit_forwards=chained, fused=chained, weight_passes=steps,
-                fixed=fixed[index],
+                row_passes=steps * plan.live_rows(index), fixed=fixed[index],
             ):
                 cache_k, cache_v, start, where, record = runner.block_step(
                     cache_k, cache_v, whole, start, where,
@@ -390,7 +400,8 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
                     known if index == 0 else unknown, pending)
             pending = record[0]
             landed(window.submit(
-                record, meta=("block", (steps + chained) * rows * block)))
+                record, meta=("block", (steps + chained) * rows * block),
+                program="sdar_block", parent=root, rows=rows))
         landed(window.drain())
     finally:
         window.abandon()
@@ -417,7 +428,5 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int):
     committed = needed - len(prompts)
     metrics.counter("generate.denoise_forwards").add(needed * steps)
     metrics.counter("generate.commit_forwards").add(committed)
-    metrics.counter("generate.commits_fused").add(committed)
-    metrics.counter("generate.weight_passes").add(needed * steps)
     metrics.counter("generate.tokens_fixed").add(sum(fixed))
     return tokens_out, records_out

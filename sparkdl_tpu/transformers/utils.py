@@ -642,7 +642,12 @@ def run_batched_partitions(
     ``data.pack``, ``engine.load_wait``, ``engine.place``,
     ``engine.dispatch`` and ``engine.fetch_wait`` are its children by
     explicit parent.  Without, they hang under the caller's current span,
-    which gets ``batches``.
+    which gets ``batches``.  Under the same parent, on the engine's
+    watcher thread: ``engine.transfer`` (``bytes``, ``observed``) a placed
+    batch and ``engine.device`` (``program``, ``rows``, ``queued_ms``) a
+    dispatch — when the batch arrived, and when the device computed it
+    (:class:`~sparkdl_tpu.engine.executor._CompletionWatcher`); none where
+    the caller has no span open.
     """
     from sparkdl_tpu.data import Dataset
     from sparkdl_tpu.engine import DispatchWindow
@@ -783,15 +788,18 @@ def run_batched_partitions(
     started = time.perf_counter()
     try:
         with maybe_trace():
-            for p, _, _ in chunks:
+            for p, lo, hi in chunks:
                 span = span_of(p)
                 with tracer.boundary("engine.load_wait", parent=span):
                     batch, nbytes = next(packed)
                 with forward_timer.time():
                     with tracer.boundary(
                         "engine.place", parent=span, bytes=nbytes
-                    ):
+                    ) as placing:
                         placed = tree_map(_place, batch)
+                        # before the dispatch: ``fn`` may donate the batch
+                        window.watch_transfer(
+                            placed, placing.start_ns, span, bytes=nbytes)
                     with tracer.boundary(
                         "engine.dispatch", parent=span, program=program
                     ):
@@ -805,7 +813,9 @@ def run_batched_partitions(
                             "run_batched_multi"
                         )
                     with tracer.use_span(oldest()):
-                        fell_out = window.submit(result)
+                        fell_out = window.submit(
+                            result, program=program, parent=span,
+                            rows=hi - lo)
                 for host, _ in fell_out:
                     take(host)
             # the wait that finds the end (and the prefetch thread gone)

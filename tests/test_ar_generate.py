@@ -244,6 +244,36 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
 
 
+def test_every_dispatch_gets_a_device_span_under_the_partitions_root(
+        tpu_session, model):
+    """The engine's watcher writes one ``engine.device`` a dispatch, named by
+    program, under the root of the partition whose rows it carries."""
+    from sparkdl_tpu.engine import executor
+
+    prompts = _prompts([9, 6, 13], seed=2)
+    _stage(model, batch=4).transform(_frame(tpu_session, prompts)).collect()
+    assert executor._watcher.settle(timeout=60)
+    mine = tracer.recent()
+    root = [r for r in mine if r.name == "ar_generate.partition"][-1]
+    device = [r for r in mine
+              if r.name == "engine.device" and r.parent_id == root.span_id]
+    # three prefill dispatches of 2 pairs, then 2 + 2 + 1 decode steps
+    assert [(d.attributes["program"], d.attributes["rows"],
+             d.attributes.get("steps")) for d in device] == [
+        ("granite_prefill", 2, None)] * 3 + [
+        ("granite_decode", 4, 2), ("granite_decode", 4, 2),
+        ("granite_decode", 4, 1)]
+    for before, after in zip(device, device[1:]):
+        assert before.end_ns <= after.start_ns  # one program at a time
+    assert root.start_ns <= device[0].start_ns
+    assert device[-1].end_ns <= root.end_ns
+    assert {d.thread_id for d in device} == {executor._watcher._thread.ident}
+    assert root.thread_id not in {d.thread_id for d in device}
+    # the stage hands no placed batch over: nothing to donate, ~100 arrays
+    assert not [r for r in mine if r.name == "engine.transfer"
+                and r.parent_id == root.span_id]
+
+
 def test_keys_scored_and_spanned_are_the_plans_sums(
         tpu_session, params, model, monkeypatch):
     """What the prefill's attention scores, from the plan alone: every pair
@@ -253,8 +283,6 @@ def test_keys_scored_and_spanned_are_the_plans_sums(
     from sparkdl_tpu.models import hybrid
 
     monkeypatch.setattr(hybrid, "KEY_BLOCK", 24)
-    names = ("ar_generate.keys_scored", "ar_generate.keys_spanned")
-    before = [metrics.counter(c).value for c in names]
     prompts = _prompts([30, 5, 9], seed=5)
     rows = _stage(model, batch=3).transform(
         _frame(tpu_session, prompts)).collect()
@@ -270,8 +298,10 @@ def test_keys_scored_and_spanned_are_the_plans_sums(
                if r.name == "ar_generate.prefill"][-1]
     assert prefill.attributes["keys_scored"] == scored
     assert prefill.attributes["keys_spanned"] == spanned
-    assert [metrics.counter(c).value - b for c, b in zip(names, before)] == [
-        scored, spanned]
+    # the span is the only place they are written: no counter repeats them
+    assert (plan.keys_scored, plan.keys_spanned) == (scored, spanned)
+    assert not [name for name in metrics.snapshot("ar_generate.")
+                if "keys_" in name]
     # and the blocks of 24 generate what the reference does
     for row, prompt in zip(rows, prompts):
         _teacher_forced(params, prompt, row)

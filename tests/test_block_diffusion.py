@@ -182,7 +182,6 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert not tracer.enabled
     counters = (
         "generate.denoise_forwards", "generate.commit_forwards",
-        "generate.commits_fused", "generate.weight_passes",
         "generate.tokens_fixed", "moe.tokens_routed", "moe.pairs_held",
         "moe.tokens_dropped",
         "moe.expert_load_max", "moe.expert_load_mean")
@@ -215,9 +214,13 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     delta = {c: metrics.counter(c).value - before[c] for c in counters}
     # 3 rows x 3 blocks, a row's last never committed
     assert delta["generate.commit_forwards"] == 3 * (3 - 1)
-    assert delta["generate.commits_fused"] == delta["generate.commit_forwards"]
+    # every commit shared its forward (``fused``): the spans say so, block
+    # by block, and no counter repeats them
+    assert (sum(b.attributes["fused"] * 3 for b in blocks)
+            == delta["generate.commit_forwards"])
     assert delta["generate.denoise_forwards"] == 9 * 4
-    assert delta["generate.weight_passes"] == 9 * 4
+    # passes over the weights the real rows paid for: 3 rows in each block
+    assert [b.attributes["row_passes"] for b in blocks] == [3 * 4] * 3
     assert delta["generate.tokens_fixed"] == 9 * BLOCK - 4
     # prefill 4 rows x 128 padded tokens; block 0 four forwards of 4 x 4
     # tokens, blocks 1 and 2 five each (the first forward carries the block
@@ -230,6 +233,33 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
 
 
+def test_every_dispatch_gets_a_device_span_under_the_partitions_root(
+        tpu_session, model):
+    from sparkdl_tpu.engine import executor
+
+    prompts = _prompts([9, 6, 13], seed=2)
+    _stage(model, batch=4).transform(_frame(tpu_session, prompts)).collect()
+    assert executor._watcher.settle(timeout=60)
+    mine = tracer.recent()
+    root = [r for r in mine if r.name == "generate.partition"][-1]
+    device = [r for r in mine
+              if r.name == "engine.device" and r.parent_id == root.span_id]
+    # one prefill chunk of the 4 padded rows, then three block steps
+    assert [(d.attributes["program"], d.attributes["rows"])
+            for d in device] == [("sdar_prefill", 4)] + [("sdar_block", 4)] * 3
+    for before, after in zip(device, device[1:]):
+        assert before.end_ns <= after.start_ns  # one program at a time
+    assert root.start_ns <= device[0].start_ns
+    assert device[-1].end_ns <= root.end_ns
+    assert root.thread_id not in {d.thread_id for d in device}
+    # and the reader's ratio from the blocks' attributes: 4 passes a block
+    # over 3 live rows, for the positions those rows fixed
+    blocks = [r for r in mine
+              if r.name == "generate.block" and r.parent_id == root.span_id]
+    assert sum(b.attributes["row_passes"] for b in blocks) == 9 * 4
+    assert sum(b.attributes["fixed"] for b in blocks) == 9 * BLOCK - 4
+
+
 def test_a_batch_of_one_block_dispatches_the_plain_shape_once(
         tpu_session, params):
     """A prompt of whole blocks and ``genLength`` <= ``blockLength``: one
@@ -237,8 +267,7 @@ def test_a_batch_of_one_block_dispatches_the_plain_shape_once(
     own = SdarMoeModel(CONFIG, params)  # a runner, and so a program table, of its own
     prompts = _prompts([8, 4], seed=6)
     before = {c: metrics.counter(c).value for c in (
-        "generate.commit_forwards", "generate.commits_fused",
-        "generate.weight_passes")}
+        "generate.commit_forwards",)}
     stage = BlockDiffusionTransformer(
         inputCol="prompt", outputCol="generated", recordCol="record",
         model=own, genLength=BLOCK, blockLength=BLOCK, denoisingSteps=4,
@@ -249,12 +278,11 @@ def test_a_batch_of_one_block_dispatches_the_plain_shape_once(
     root = [r for r in tracer.recent() if r.name == "generate.partition"][-1]
     blocks = [r for r in tracer.recent()
               if r.name == "generate.block" and r.parent_id == root.span_id]
-    assert [(b.attributes["index"], b.attributes["commit_forwards"])
-            for b in blocks] == [(0, 0)]
+    assert [(b.attributes["index"], b.attributes["commit_forwards"],
+             b.attributes["fused"], b.attributes["row_passes"])
+            for b in blocks] == [(0, 0, 0, 2 * 4)]
     delta = {c: metrics.counter(c).value - before[c] for c in before}
-    assert delta == {"generate.commit_forwards": 0,
-                     "generate.commits_fused": 0,
-                     "generate.weight_passes": 2 * 4}
+    assert delta == {"generate.commit_forwards": 0}
     (runner,) = vars(own)["_block_diffusion_runners"].values()
     # the last element of a block program's key: whether a block is pending
     assert [key[-1] for key in runner.programs if key[0] == "block"] == [False]

@@ -576,3 +576,268 @@ def test_read_images_spans_are_roots_on_the_calling_thread(
     # the reader's rule: self time = duration less same-thread children
     spans = {r.span_id for r in recs}
     assert not [r for r in tracer.recent() if r.parent_id in spans]
+
+
+# ----------------------------------------------------------------------
+# engine.device / engine.transfer: the completion watcher
+# ----------------------------------------------------------------------
+WATCHER = "sparkdl-completion-watcher"
+
+
+def settled():
+    assert executor._watcher.settle(timeout=30)
+
+
+def watcher_threads():
+    return [t for t in threading.enumerate() if t.name == WATCHER]
+
+
+class Slow:
+    """A dispatched result that becomes ready when the test says so."""
+
+    def __init__(self, error=None, nbytes=8):
+        self.ready, self.waited = threading.Event(), threading.Event()
+        self.error, self.nbytes = error, nbytes
+
+    def block_until_ready(self):
+        self.waited.set()
+        assert self.ready.wait(timeout=30)
+        if self.error is not None:
+            raise self.error
+
+    def __array__(self, dtype=None, copy=None):
+        self.block_until_ready()  # a fetch raises what the computation did
+        return np.zeros(1)
+
+
+def named(name, mark):
+    return [r for r in since(mark) if r.name == name]
+
+
+def test_one_device_span_a_watched_submit_in_submission_order():
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=3)
+    results = [Slow() for _ in range(3)]
+    with tracer.boundary("featurize.partition", rows=12) as root:
+        for i, result in enumerate(results):
+            assert window.submit(
+                result, meta=i, program="p", parent=root, rows=4 + i) == []
+        assert results[0].waited.wait(timeout=30)
+        assert not results[1].waited.is_set()  # one wait at a time, in order
+        for result in reversed(results):  # ready in any order: spans in this
+            result.ready.set()
+        settled()
+        assert [meta for _, meta in window.drain()] == [0, 1, 2]
+    spans = named("engine.device", mark)
+    assert [s.attributes["rows"] for s in spans] == [4, 5, 6]
+    assert all(s.attributes["program"] == "p" for s in spans)
+    assert all(s.attributes["queued_ms"] >= 0 for s in spans)
+    for before, after in zip(spans, spans[1:]):
+        assert before.start_ns <= before.end_ns <= after.start_ns
+    # children of the partition's root, on the watcher's thread, never roots
+    assert {s.parent_id for s in spans} == {root.span_id}
+    thread = executor._watcher._thread  # the one the process has
+    assert thread in watcher_threads()
+    assert {s.thread_id for s in spans} == {thread.ident}
+    assert thread.daemon and thread.ident != threading.get_ident()
+    roots = [r for r in since(mark) if r.parent_id is None]
+    assert [r.name for r in roots] == ["featurize.partition"]
+    assert spans[-1].end_ns <= roots[0].end_ns
+    ends = [r.end_ns for r in tracer.recent()]
+    assert ends == sorted(ends)  # written as they end: the ring's order
+
+
+def test_a_device_span_starts_at_the_latest_of_dispatch_input_and_the_one_before():
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=2)
+    batch, result = Slow(nbytes=64), Slow()
+    with tracer.boundary("featurize.partition") as root:
+        with tracer.boundary("engine.place") as placing:
+            window.watch_transfer(batch, placing.start_ns, root, bytes=64)
+        window.submit(result, program="p", parent=root, rows=1)
+        assert batch.waited.wait(timeout=30)
+        batch.ready.set()  # the input arrives ...
+        assert result.waited.wait(timeout=30)
+        arrived = tracer.clock_ns()
+        result.ready.set()  # ... and only then can the device compute
+        settled()
+        list(window.drain())
+    (transfer,) = named("engine.transfer", mark)
+    (device,) = named("engine.device", mark)
+    (place,) = named("engine.place", mark)
+    assert transfer.attributes == {"bytes": 64, "observed": True}
+    assert transfer.start_ns == place.start_ns
+    assert transfer.parent_id == device.parent_id == root.span_id
+    assert device.start_ns == transfer.end_ns <= arrived
+    assert device.attributes["queued_ms"] > 0
+
+
+def test_a_submit_without_a_program_starts_no_thread(monkeypatch):
+    own = executor._CompletionWatcher()
+    monkeypatch.setattr(executor, "_watcher", own)
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=0)
+    with tracer.boundary("caller") as caller:
+        window.submit(np.zeros(2), meta="m")
+        window.submit(np.zeros(2), program="p")  # nobody to hang it under
+        window.watch_transfer(np.zeros(2), mark, None)
+    assert own._thread is None and not own._entries
+    assert {r.name for r in since(mark)} <= {
+        "engine.fetch_wait", "engine.starved", "caller"}
+    window.submit(np.zeros(2), program="p", parent=caller)
+    assert own._thread is not None and own.settle(timeout=30)
+    assert len(named("engine.device", mark)) == 1
+
+
+def test_abandon_leaves_no_entry_no_late_span_and_no_second_thread():
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=4)
+    fetched, waited_for, queued = Slow(), Slow(), Slow()
+    batch = Slow()
+    with tracer.boundary("featurize.partition") as root:
+        fetched.ready.set()
+        window.submit(fetched, program="p", parent=root, rows=1)
+        settled()
+        threads = watcher_threads()
+        window._pop()  # fetched: its span stays
+        window.submit(waited_for, program="p", parent=root, rows=2)
+        window.submit(queued, program="p", parent=root, rows=3)
+        window.watch_transfer(batch, mark, root, bytes=8)  # never dispatched
+        assert waited_for.waited.wait(timeout=30)
+        window.abandon()
+        assert not executor._watcher._entries  # nothing left in the queue
+        assert len(window) == 0
+        waited_for.ready.set()  # the wait under way returns: no span for it
+        settled()
+    assert not queued.waited.is_set() and not batch.waited.is_set()
+    assert [s.attributes["rows"] for s in named("engine.device", mark)] == [1]
+    assert named("engine.transfer", mark) == []
+    assert watcher_threads() == threads and executor._watcher._thread.is_alive()
+
+
+def test_a_failed_computation_raises_at_the_fetch_and_the_watcher_goes_on():
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=1)
+    failing, sound = Slow(error=RuntimeError("device fault")), Slow()
+    with tracer.boundary("featurize.partition") as root:
+        window.submit(failing, program="p", parent=root, rows=1)
+        failing.ready.set()
+        sound.ready.set()
+        with pytest.raises(RuntimeError, match="device fault"):
+            window.submit(sound, program="p", parent=root, rows=2)
+        window.abandon()  # as every loop's ``finally`` does
+        settled()
+        again = DispatchWindow(depth=0)
+        again.submit(np.ones(2), program="q", parent=root, rows=3)
+        settled()
+    spans = named("engine.device", mark)
+    assert [(s.attributes["rows"], s.attributes.get("error"))
+            for s in spans] == [(1, True), (3, None)]
+    assert executor._watcher._thread.is_alive()
+
+
+def _donating_partitions_run(span_name):
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.transformers.utils import run_batched_partitions
+
+    program = jax.jit(lambda x: jnp.tanh(x) * 2.0, donate_argnums=0)
+    parts = [[np.full((3, 5), 10 * p + i, np.float32) for i in range(n)]
+             for p, n in enumerate([20, 7])]
+    out = []
+    run_batched_partitions(
+        program, parts, lambda rows: np.stack,
+        lambda done: out.append(done.result), 8, span_name=span_name)
+    return out
+
+
+def test_a_donated_input_neither_raises_nor_blocks_and_changes_no_result():
+    """The featurizer donates its batch: whichever of the watcher's wait
+    and the dispatch comes first, the loop's results are those of a run
+    nobody watches (no span open: nothing to hang a span under)."""
+    from sparkdl_tpu.utils.metrics import metrics
+
+    unwatched_mark = tracer.clock_ns()
+    unwatched = _donating_partitions_run(None)
+    settled()
+    assert named("engine.device", unwatched_mark) == []
+    assert named("engine.transfer", unwatched_mark) == []
+    unobserved = metrics.counter("engine.transfers_unobserved").value
+    mark = tracer.clock_ns()
+    watched = _donating_partitions_run("featurize.partition")
+    settled()
+    assert len(watched) == len(unwatched) == 2
+    for a, b in zip(watched, unwatched):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+    roots = {r.span_id: r for r in named("featurize.partition", mark)}
+    transfers, devices = (named(n, mark) for n in (
+        "engine.transfer", "engine.device"))
+    # 3 + 1 batches, each under the partition whose rows it carries
+    assert sorted(roots[s.parent_id].attributes["rows"] for s in devices) \
+        == [7, 20, 20, 20]
+    assert sorted(s.attributes["rows"] for s in devices) == [4, 7, 8, 8]
+    assert {s.attributes["program"] for s in devices} == {"<lambda>"}
+    assert [s.attributes["bytes"] for s in transfers] == [8 * 3 * 5 * 4] * 4
+    assert [s.parent_id for s in transfers] == [s.parent_id for s in devices]
+    missed = [s for s in transfers if not s.attributes["observed"]]
+    assert (metrics.counter("engine.transfers_unobserved").value
+            - unobserved) == len(missed)
+
+
+def test_a_wait_that_begins_after_the_donation_closes_the_span_unobserved():
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.utils.metrics import metrics
+
+    program = jax.jit(lambda x: x + 1.0, donate_argnums=0)
+    unobserved = metrics.counter("engine.transfers_unobserved").value
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=1)
+    ahead = Slow()  # holds the watcher while the batch is placed and donated
+    with tracer.boundary("featurize.partition") as root:
+        window.submit(ahead, program="p", parent=root, rows=1)
+        assert ahead.waited.wait(timeout=30)
+        placed = jnp.ones((4, 4))
+        window.watch_transfer(placed, tracer.clock_ns(), root, bytes=64)
+        result = program(placed)
+        assert placed.is_deleted()
+        ahead.ready.set()
+        window.submit(result, program="p", parent=root, rows=4)
+        settled()
+        host = [host for host, _ in window.drain()][-1]
+    np.testing.assert_array_equal(host, np.full((4, 4), 2.0, np.float32))
+    (transfer,) = named("engine.transfer", mark)
+    assert transfer.attributes == {"bytes": 64, "observed": False}
+    first, second = named("engine.device", mark)
+    assert "error" not in second.attributes
+    # an arrival nobody saw bounds nothing: stream order and the stamp do
+    assert second.start_ns >= first.end_ns
+    assert metrics.counter("engine.transfers_unobserved").value \
+        == unobserved + 1
+
+
+def test_a_span_the_watcher_writes_late_ends_with_its_partition():
+    """The watcher may get to write a span only after the dispatching
+    thread fetched the result and ended the partition: the result was
+    ready before that, so the span ends with its parent, and the newest
+    span of the ring (the readers' mark of the window's end) is never the
+    watcher's."""
+    mark = tracer.clock_ns()
+    window = DispatchWindow(depth=1)
+    result = Slow()
+    with tracer.boundary("featurize.partition") as root:
+        window.submit(result, program="p", parent=root, rows=1)
+        assert result.waited.wait(timeout=30)
+    with tracer.boundary("sql.collect"):
+        pass
+    result.ready.set()  # as if the GIL had kept the watcher until now
+    settled()
+    list(window.drain())
+    (device,) = named("engine.device", mark)
+    (partition,) = named("featurize.partition", mark)
+    (collect,) = named("sql.collect", mark)
+    assert device.start_ns <= device.end_ns == partition.end_ns
+    assert max(r.end_ns for r in since(mark)
+               if r.name != "engine.fetch_wait") == collect.end_ns
