@@ -507,3 +507,14 @@ class SolarOpen2Model(HybridModel):
     config_class = SolarOpen2Config
     recurrent_leaves = ("conv", "kda")
     functions = sys.modules[__name__]
+
+    def rule_chunks(self, pairs: int, segment: int) -> Tuple[int, int]:
+        """``(chunks, fused)`` of one prefill dispatch of ``pairs`` segments
+        of ``segment`` positions: the (row, chunk) pairs its KDA layers put
+        through the chunked rule, and those of them that go through the
+        kernel (:func:`sparkdl_tpu.ops.delta_rule.fused_chunks`)."""
+        cfg = self.config
+        chunks, fused = delta_rule.fused_chunks(
+            pairs, segment, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim,
+            cfg.kda_chunk_size)
+        return cfg.count("kda") * chunks, cfg.count("kda") * fused

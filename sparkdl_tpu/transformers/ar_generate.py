@@ -30,7 +30,8 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``ar_generate.partition`` (root; ``model``: the model's ``.name``, ``rows``,
 ``batches``, ``prompt_tokens``, ``generated_tokens``) > ``ar_generate.plan``, ``engine.place``,
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
-``keys_scored``, ``keys_spanned``),
+``keys_scored``, ``keys_spanned``; ``rule_chunks``, ``rule_chunks_fused``
+where the model has a chunked rule),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``),
 ``engine.fetch_wait``, ``ar_generate.postprocess``; and, on the engine's
 watcher thread, one ``engine.device`` a dispatch (``program``:
@@ -39,7 +40,11 @@ watcher thread, one ``engine.device`` a dispatch (``program``:
 cache slots the dispatches' pairs, spare ones too, score in an attention
 layer (whole blocks up to each pair's own end,
 :func:`~sparkdl_tpu.models.hybrid.keys_scored`), ``keys_spanned`` pairs x
-span: what scoring the whole span would come to.  Counters:
+span: what scoring the whole span would come to.  ``rule_chunks`` counts
+the (row, chunk) pairs the dispatches' layers put through a chunked
+recurrence and ``rule_chunks_fused`` those that went through its kernel,
+both as the model's ``rule_chunks(pairs, segment)`` gives them for a dispatch
+(a model without that method gets neither).  Counters:
 ``ar_generate.prefill_tokens`` (real prompt tokens),
 ``ar_generate.prefill_pad_tokens`` (positions pushed through the layers that
 were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
@@ -208,7 +213,8 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
         "the model's functions and params: an object with .params, .name, "
         ".fingerprint, .experts_held, .state_spec(rows, span), "
         ".recurrent_bytes(rows), "
-        ".experts_per_token, .prefill(...) and .decode(...) "
+        ".experts_per_token, .prefill(...) and .decode(...), and "
+        ".rule_chunks(pairs, segment) where it has a chunked rule "
         "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel, "
         "sparkdl_tpu.models.solar_open2.SolarOpen2Model)",
     )
@@ -339,11 +345,17 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
             count_routing(counts, routed_tokens, model.experts_per_token,
                           (lo, hi))
 
+    rule = {}
+    if hasattr(model, "rule_chunks"):
+        chunks, fused = model.rule_chunks(runner.count, runner.segment)
+        rule = dict(rule_chunks=chunks * len(plan.dispatches),
+                    rule_chunks_fused=fused * len(plan.dispatches))
     try:
         with tracer.boundary(
                 "ar_generate.prefill", tokens=plan.real_tokens,
                 pad_tokens=plan.pad_tokens, segments=len(plan.dispatches),
-                keys_scored=plan.keys_scored, keys_spanned=plan.keys_spanned):
+                keys_scored=plan.keys_scored, keys_spanned=plan.keys_spanned,
+                **rule):
             for arrays, (_, last) in zip(placed, plan.dispatches):
                 state, token, logprob, counts = runner.prefill(state, *arrays)
                 landed(window.submit(

@@ -419,6 +419,48 @@ def test_the_second_model_generates_through_the_same_stage(
         "decode", "decode", "prefill"]
 
 
+def test_a_prefill_span_carries_the_rules_chunks_where_the_model_has_a_rule(
+        tpu_session, model, solar, monkeypatch):
+    """``rule_chunks``: dispatches x pairs x chunks a segment x KDA layers,
+    from the plan's shapes; ``rule_chunks_fused``: those that went through
+    the kernel, none at these widths (heads of 8 channels) whatever the
+    backend.  A model without a chunked rule of its own gets neither."""
+    from sparkdl_tpu.ops import delta_rule
+
+    solar_model, _ = solar
+    prompts = _prompts([5, 30, 16, 9], seed=1)
+    frame = _frame(tpu_session, prompts)
+
+    def prefill_span(stage_model):
+        _stage(stage_model).transform(frame).collect()
+        return [r for r in tracer.recent()
+                if r.name == "ar_generate.prefill"][-1].attributes
+
+    # 2 + 4 + 2 + 2 segments of 8 positions in five dispatches of two pairs;
+    # a segment is one chunk of 8 (kda_chunk_size) in each of 3 KDA layers
+    plan = SegmentPlan(prompts, 4, 8, 2, GEN)
+    assert len(plan.dispatches) == 5
+    assert solar_model.rule_chunks(2, 8) == (3 * 2, 0)
+    assert solar_model.rule_chunks(2, 20) == (3 * 2 * 3, 0)
+    got = prefill_span(solar_model)
+    assert (got["rule_chunks"], got["rule_chunks_fused"]) == (5 * 2 * 3, 0)
+    assert got["segments"] == 5
+    assert not [key for key in prefill_span(model) if key.startswith("rule")]
+    # the model asks the function the rule itself picks its path by: at the
+    # published widths on the chip every chunk of the stage's one prefill
+    # shape goes through the kernel
+    from sparkdl_tpu.models.solar_open2 import SolarOpen2Model
+
+    wide = SolarOpen2Model(dict(
+        SOLAR, kda_chunk_size=64, linear_attn_config=dict(
+            SOLAR["linear_attn_config"], head_dim=128,
+            num_heads=2 * delta_rule.HEAD_BLOCK)), None)
+    assert wide.rule_chunks(16, 128) == (3 * 32, 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert wide.rule_chunks(16, 128) == (3 * 32, 3 * 32)
+    assert solar_model.rule_chunks(2, 8) == (3 * 2, 0)
+
+
 def test_two_models_one_after_the_other_in_one_process(
         tpu_session, params, solar):
     """Neither's state or programs reach the other: each model object keeps

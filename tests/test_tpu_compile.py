@@ -473,6 +473,28 @@ def test_granite_programs_compile_and_fit(
 
 # -- Solar-Open2 at its published widths (PR 37) -------------------------------
 
+def test_chunked_delta_rule_kernel_compiles(one_chip, no_compile_cache):
+    """``ops/delta_rule``'s kernel alone at the cell's prefill shape: 16
+    pairs x 128 positions, 64 heads of 128 channels, chunks of 64 in
+    sub-blocks of 16, bfloat16 operands beside the float32 decays and
+    state."""
+    from sparkdl_tpu.ops import delta_rule
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = (16, 128, 64, 128)
+    compiled = jax.jit(
+        lambda *args: delta_rule._kda_chunked_kernel(*args, 64)
+    ).lower(
+        spec(jnp.bfloat16, *wide), spec(jnp.bfloat16, *wide),
+        spec(jnp.bfloat16, *wide), spec(jnp.float32, *wide),
+        spec(jnp.float32, 16, 128, 64), spec(jnp.float32, 16, 64, 128, 128),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _fits(compiled)
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_solar_programs_compile_and_fit(
         program, one_chip, no_compile_cache, as_on_the_chip):
@@ -516,10 +538,15 @@ def test_solar_programs_compile_and_fit(
             lambda p, s: so.decode(p, cfg, s, 8), donate_argnums=(1,)
         ).lower(params, state).compile()
     # the grouped expert product is the Pallas kernel: gate, up and down of
-    # the attention layer and of the KDA layers' scan body
+    # the attention layer and of the KDA layers' scan body; a prefill's scan
+    # body holds the chunked rule's kernel besides, once (PR 40)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") == (7 if program == "prefill" else 6)
     if program == "prefill":
+        assert len(re.findall(r"%kda_chunked[.\d]* = ", text)) == 1
+        # none of the chunked form's tensors a (row, head, chunk, sub-block)
+        # is left in HBM: 67 MB each in plain XLA
+        assert not re.findall(r"f32\[16,64,2,4,16,", text)
         # as in the granite prefill (PR 38): [8, 8, 128, 4224] float32 a row
         # and 2 x 138 MB of gathered cache a dispatch before
         assert not re.findall(r"f32\[[\d,]*,4224\]", text)
