@@ -51,12 +51,14 @@ from ci.sparkdl_check.core import FileContext, Rule, rule
 #: expert reads, tokens generated) and "ssm" (bytes of recurrent state
 #: a batch holds) joined with the ISSUE-35 autoregressive stage over a
 #: state-space / attention hybrid.
+#: "mla" (bytes of latent cache a batch holds) joined with the ISSUE-41
+#: latent-attention decoder.
 ALLOWED_PREFIXES = (
     "sparkdl", "data", "serving", "resilience", "estimator", "engine",
     "streaming", "slo", "ts", "supervisor", "router", "wire",
     "rollout", "tenant", "fleet", "replica", "faultnet", "diag",
     "profile", "cache", "decode", "batcher", "csql", "generate", "moe",
-    "ar_generate", "ssm",
+    "ar_generate", "ssm", "mla",
 )
 
 METRIC_FACTORIES = {"counter", "timer", "gauge", "histogram"}

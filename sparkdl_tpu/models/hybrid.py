@@ -1,9 +1,10 @@
-"""What the hybrid decoders share (``granite_hybrid``, ``solar_open2``): a
-stack of two kinds of layer — a recurrent mixer with a per-row state, and
-grouped-query softmax attention with NO position term through a key/value
-cache — each followed by a sparse-expert feed-forward with a shared expert,
-over ONE per-row state pytree whose leaves are stacked [layers of a kind,
-rows, ...].
+"""What the stage's decoders share (``granite_hybrid``, ``solar_open2``,
+``moonlight``): a stack of layers of one or two kinds — a recurrent mixer
+with a per-row state, grouped-query softmax attention with NO position term
+through a key/value cache, latent attention through a latent cache
+(``ops/mla.py``) — each followed by a sparse-expert feed-forward with a
+shared expert (after leading dense layers, where a model has them), over ONE
+per-row state pytree whose leaves are stacked [layers of a kind, rows, ...].
 
 - :func:`rms_norm`; :func:`at`, :func:`read`, :func:`write` (a layer's slice
   of stacked weights and of a state leaf);
@@ -11,10 +12,11 @@ rows, ...].
   (the attention through the cache, up to but not including the output
   projection, so that a model may gate what it projects), and
   :func:`keys_scored` (how many slots a plan's segments score);
-- :func:`feed_forward` (``h + r * (MoE(u) + Shared(u))``);
+- :func:`feed_forward` (``h + r * (s * MoE(u) + Shared(u))``),
+  :func:`dense_feed_forward`, :func:`swiglu`;
 - :func:`layer_runs`, :class:`Mixer`, :func:`run_layers` (every layer in its
-  published order: the runs of recurrent layers scanned, the attention layers
-  written out);
+  published order: the runs of other kinds scanned, the grouped-query
+  attention layers written out, leading dense layers apart);
 - :func:`source_digest` (what identifies a model's mathematics in a
   program's fingerprint) and :class:`HybridModel` (what the stage takes).
 
@@ -196,24 +198,39 @@ def split_ffn(params):
     return ffn, experts
 
 
+def swiglu(u, gate, up, down):
+    """``(silu(u W_gate) * u W_up) W_down``, the products' float32 results
+    multiplied before they are rounded back."""
+    g = jnp.dot(u, gate, preferred_element_type=jnp.float32)
+    v = jnp.dot(u, up, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * v).astype(u.dtype), down)
+
+
 def feed_forward(fp, experts, layer, h, *, eps: float, top_k: int, held,
-                 residual: float = 1.0, scoring: str = "softmax"):
-    """``h + residual * (MoE(u) + Shared(u))`` with ``u = RMSNorm_post(h)``,
-    and the layer's routing counts; ``fp`` is the layer's own slice (its
-    ``router_bias``, where it has one, is the router's selection bias), the
-    experts' weights the whole stack (``moe_ffn(stack_index=layer)``)."""
+                 residual: float = 1.0, scoring: str = "softmax",
+                 routed_scale: float = 1.0):
+    """``h + residual * (routed_scale * MoE(u) + Shared(u))`` with ``u =
+    RMSNorm_post(h)``, and the layer's routing counts; ``fp`` is the layer's
+    own slice (its ``router_bias``, where it has one, is the router's
+    selection bias), the experts' weights the whole stack
+    (``moe_ffn(stack_index=layer)``).  ``routed_scale`` multiplies the
+    routed pairs' float32 weights, not the shared expert."""
     lead, d = h.shape[:-1], h.shape[-1]
     u = rms_norm(h, fp["post_norm"], eps).reshape(-1, d)
     routed, counts = moe_ffn(
         u, fp["router"], experts, top_k=top_k, experts_held=held,
         norm_topk=True, stack_index=layer, scoring=scoring,
-        select_bias=fp.get("router_bias"))
-    gate = jnp.dot(u, fp["shared_gate"], preferred_element_type=jnp.float32)
-    up = jnp.dot(u, fp["shared_up"], preferred_element_type=jnp.float32)
-    shared = jnp.dot((jax.nn.silu(gate) * up).astype(u.dtype),
-                     fp["shared_down"])
+        select_bias=fp.get("router_bias"), scale=routed_scale)
+    shared = swiglu(u, fp["shared_gate"], fp["shared_up"], fp["shared_down"])
     out = (routed + shared).reshape(*lead, d)
     return h + (residual * out).astype(h.dtype), counts
+
+
+def dense_feed_forward(dp, h, *, eps: float):
+    """A leading dense layer's ``h + SwiGLU(RMSNorm_post(h))`` (``dp``: the
+    layer's ``post_norm``, ``gate``, ``up``, ``down``)."""
+    u = rms_norm(h, dp["post_norm"], eps)
+    return h + swiglu(u, dp["gate"], dp["up"], dp["down"]).astype(h.dtype)
 
 
 def layer_runs(kinds):
@@ -244,22 +261,27 @@ class Mixer(NamedTuple):
 
 
 def run_layers(params, kinds, x, state, rows, mixers, ffn_of, *, eps: float,
-               residual: float = 1.0):
+               residual: float = 1.0, dense_of=None):
     """Every layer over ``x``, in the order of ``kinds``.
 
     ``mixers[kind]`` is the kind's :class:`Mixer`, run on the layer's weights
     ``params[kind]`` at its index; ``"attention"`` layers are written out,
     every run of another kind is one ``lax.scan`` over its indices.
     ``ffn_of(fp, experts, layer, h)`` is the feed-forward
-    (:func:`feed_forward` with the model's settings).  ``x`` is about the
-    rows ``rows`` of ``state`` (None: all of them, in order); a layer reads
-    and writes only its own slice of the state, so no copy of more than one
-    layer's rows is ever alive.  Returns (x, state, counts [L, E])."""
+    (:func:`feed_forward` with the model's settings).  ``dense_of(dp, h)``,
+    where given, is the feed-forward of the leading dense layers, one a
+    layer of ``params["dense"]`` (stacked [k, ...]: the first k layers);
+    their routing counts are rows of zeros, and the expert layers' stacks
+    begin at layer k.  ``x`` is about the rows ``rows`` of ``state`` (None:
+    all of them, in order); a layer reads and writes only its own slice of
+    the state, so no copy of more than one layer's rows is ever alive.
+    Returns (x, state, counts [L, E])."""
     ffn, experts = split_ffn(params)
+    dense = 0 if dense_of is None else params["dense"]["post_norm"].shape[0]
     state = dict(state)
     counts = []
 
-    def one_layer(kind, lp, x, held, of_kind, layer):
+    def one_layer(kind, lp, x, held, of_kind, layer, leading=False):
         mixer = mixers[kind]
         u = rms_norm(x, lp["in_norm"], eps)
         if mixer.in_place:
@@ -270,10 +292,24 @@ def run_layers(params, kinds, x, state, rows, mixers, ffn_of, *, eps: float,
             held = [write(leaf, value, of_kind, rows)
                     for leaf, value in zip(held, new)]
         h = x + (residual * out).astype(x.dtype)
+        if leading:
+            routed = jnp.zeros((ffn["router"].shape[-1],), jnp.int32)
+            return (dense_of(at(params["dense"], layer), h), tuple(held),
+                    routed)
+        if dense:
+            layer = layer - dense
         y, routed = ffn_of(at(ffn, layer), experts, layer, h)
         return y, tuple(held), routed
 
+    runs = []
     for kind, first, of_kind, count in layer_runs(kinds):
+        # a run is cut where the leading dense layers end
+        cut = min(max(dense - first, 0), count)
+        if cut:
+            runs.append((kind, first, of_kind, cut, True))
+        if count - cut:
+            runs.append((kind, first + cut, of_kind + cut, count - cut, False))
+    for kind, first, of_kind, count, leading in runs:
         names = mixers[kind].leaves
         held = tuple(state[name] for name in names)
         if kind == "attention":
@@ -281,14 +317,16 @@ def run_layers(params, kinds, x, state, rows, mixers, ffn_of, *, eps: float,
                 index = of_kind + offset
                 lp = jax.tree_util.tree_map(lambda w: w[index], params[kind])
                 x, held, routed = one_layer(
-                    kind, lp, x, held, index, jnp.int32(first + offset))
+                    kind, lp, x, held, index, jnp.int32(first + offset),
+                    leading)
                 counts.append(routed[None])
         else:
-            def scanned(carry, index, kind=kind):
+            def scanned(carry, index, kind=kind, leading=leading):
                 x, held = carry
                 of_kind, layer = index
                 x, held, routed = one_layer(
-                    kind, at(params[kind], of_kind), x, held, of_kind, layer)
+                    kind, at(params[kind], of_kind), x, held, of_kind, layer,
+                    leading)
                 return (x, held), routed
 
             (x, held), routed = jax.lax.scan(
@@ -325,6 +363,16 @@ class HybridModel:
 
     name = family = config_class = functions = None
     recurrent_leaves = ()
+    #: the state leaves that ``mla.latent_cache_bytes`` counts
+    latent_leaves = ()
+    #: leading layers whose feed-forward is dense: their rows of a
+    #: program's routing counts are zeros, and the stage skips them
+    dense_layers = 0
+    #: ``{"prefill": ..., "decode": ...}``: which form of its attention each
+    #: program takes, where the attention has more than one published form
+    #: (MLA's absorbed or decompressed; the stage's spans carry it as
+    #: ``attention_path``)
+    attention_paths = None
 
     def __init__(self, config, params):
         self.config = (
@@ -352,10 +400,17 @@ class HybridModel:
     def recurrent_bytes(self, rows: int) -> int:
         """Bytes of recurrent state (``recurrent_leaves``) that ``rows`` rows
         hold on the device."""
-        spec = self.state_spec(rows, 1)
+        return self._bytes(self.recurrent_leaves, rows, 1)
+
+    def latent_bytes(self, rows: int, span: int) -> int:
+        """Bytes of latent cache (``latent_leaves``) that ``rows`` rows hold
+        on the device under a span of ``span`` positions."""
+        return self._bytes(self.latent_leaves, rows, span)
+
+    def _bytes(self, leaves, rows: int, span: int) -> int:
+        spec = self.state_spec(rows, span)
         return sum(
-            spec[name].size * spec[name].dtype.itemsize
-            for name in self.recurrent_leaves)
+            spec[name].size * spec[name].dtype.itemsize for name in leaves)
 
     def prefill(self, params, state, tokens, rows, start, lengths):
         return self.functions.prefill(

@@ -1,0 +1,259 @@
+"""Multi-head latent attention (MLA; DeepSeek-V2/V3, ``model_type
+deepseek_v3``) through ONE latent cache.
+
+A layer's shapes (:class:`Shape`): ``heads`` H; a query/key head of ``nope``
+channels without a position term and ``rope`` channels rotated by RoPE; a
+value head of ``v`` channels; a latent of ``rank`` r.  With ``u`` the
+layer's normed input::
+
+    q = u W_q                       [H, nope + rope] -> q_nope, q_pe
+    [c_raw | k_pe] = u W_kva        [r + rope]
+    c = RMSNorm_kv(c_raw)           (its own eps)
+    [k_nope | v] = c W_kvb          [H, nope + v]
+    q_pe, k_pe rotated by :func:`rope` at the position (k_pe is ONE head
+    that every head shares)
+    score_h(t, s) = (q_nope_h . k_nope_h,s + q_pe_h . k_pe_s) * scale
+    out = concat_h(softmax(score_h) v_h) W_o
+
+The cache holds ``[c | k_pe]`` of every position: ONE state leaf ``latent``
+[layers, rows, span, r + rope] in the compute dtype, ``c`` after its norm
+and ``k_pe`` after RoPE, written where it lies (in place under donation) and
+never re-laid.
+
+Both programs take the **absorbed** form: ``W_kvb``'s key half folds into
+the query (``q_lat_h = q_nope_h W_UK_h^T``), ``[q_lat | q_pe]`` is scored
+against ``[c | k_pe]`` as it is stored, ``softmax . [c | k_pe]`` is taken in
+latent space, and ``W_UV_h`` comes after: the heads share every byte of the
+cache read.  In prefill it was timed on the chip against the published
+decompressed form (every visited slot's ``c`` expanded by ``W_kvb`` into the
+heads' keys and values) and was the faster (PERF.md section 5, PR 41).
+Softmaxes, the norm's statistics and the rotation are float32; everything
+else runs in the weights' dtype.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from sparkdl_tpu.models.hybrid import NEG, rms_norm
+
+class Shape(NamedTuple):
+    """One MLA layer's widths and constants (``deepseek_v3``'s keys)."""
+
+    heads: int
+    nope: int  # qk_nope_head_dim
+    rope: int  # qk_rope_head_dim
+    v: int  # v_head_dim
+    rank: int  # kv_lora_rank
+    theta: float  # rope_theta
+    kv_eps: float  # the latent norm's eps
+    scale: float  # the scores' factor, (nope + rope) ** -0.5 as published
+
+    @property
+    def latent(self) -> int:
+        """Channels a cached position holds: ``c`` and ``k_pe``."""
+        return self.rank + self.rope
+
+
+def rope(x, position, theta: float):
+    """RoPE over the last axis of ``x`` [..., d] at ``position`` (broadcast
+    against ``x.shape[:-1]``) in DeepSeek-V3's interleaved pairing: channels
+    ``2i`` and ``2i + 1`` turn together by ``position * theta^(-2i/d)``, and
+    come out de-interleaved, the turned even channels first (the published
+    code's ``view(..., d/2, 2).transpose`` before ``rotate_half``).  Float32
+    angles; the result in ``x``'s dtype."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = position.astype(jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    even, odd = xf[..., 0::2], xf[..., 1::2]
+    return jnp.concatenate(
+        [even * cos - odd * sin, odd * cos + even * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def project(lp, u, position, shape: Shape):
+    """``(q_nope [..., H, nope], q_pe [..., H, rope], latent [..., r +
+    rope])`` of ``u`` [..., D] at ``position`` [...]: the queries, and what
+    the cache keeps of the position (``c`` normed, ``k_pe`` turned)."""
+    q = jnp.dot(u, lp["wq"])
+    q = q.reshape(*u.shape[:-1], shape.heads, shape.nope + shape.rope)
+    q_pe = rope(q[..., shape.nope:], position[..., None], shape.theta)
+    kva = jnp.dot(u, lp["wkva"])
+    c = rms_norm(kva[..., :shape.rank], lp["kv_norm"], shape.kv_eps)
+    k_pe = rope(kva[..., shape.rank:], position, shape.theta)
+    return q[..., :shape.nope], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def _up(lp, shape: Shape):
+    """``W_kvb`` [r, H, nope + v] as ``(W_UK [r, H, nope], W_UV [r, H,
+    v])``."""
+    w = lp["wkvb"].reshape(shape.rank, shape.heads, shape.nope + shape.v)
+    return w[..., :shape.nope], w[..., shape.nope:]
+
+
+def from_latent(out, w_uv):
+    """``out`` [..., H, r + rope] (``softmax . [c | k_pe]``) times ``W_UV``
+    [r, H, v], with ``W_UV`` given zero rows for the rope channels rather
+    than ``out`` sliced: XLA moves a slice of a product's result into its
+    operand, and in decode that operand is the whole layer's cache."""
+    rank, heads, v = w_uv.shape
+    padded = jnp.concatenate(
+        [w_uv, jnp.zeros((out.shape[-1] - rank, heads, v), w_uv.dtype)])
+    return jnp.einsum("...hc,chd->...hd", out, padded)
+
+
+def absorbed_query(q_nope, q_pe, w_uk):
+    """``[q_nope_h W_UK_h^T | q_pe_h]`` [..., H, r + rope]: the query that
+    scores the cache as it is stored."""
+    q_lat = jnp.einsum("...hd,chd->...hc", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], axis=-1)
+
+
+#: slots a write moves at once: the TPU's lane tile.  The chip lays the leaf
+#: out with the span minor (576 channels would pad to 640, a span of whole
+#: tiles pads nothing), and a scatter whose window is a slot's channels
+#: makes XLA copy the whole leaf into another layout and back; a window of
+#: whole lane tiles of the channel-major view is written where it lies
+#: (``tests/test_tpu_compile.py``)
+WRITE_BLOCK = 128
+
+
+def write_positions(latent, new, layer, rows, start, lengths):
+    """``new`` [c, n, r + rope] into ``latent[layer, rows[c], start[c] +
+    j]`` for ``j < lengths[c]``, in place where the leaf is donated; a pad
+    (``j >= lengths[c]``) and a row past the last are written nowhere.
+
+    As a read-modify-write of one window a row, ``WRITE_BLOCK``-aligned and
+    wide enough for ``n`` positions at any start, in the channel-major view
+    ``[layers, rows, r + rope, span]`` (what the chip stores): the window is
+    gathered, the real positions are put in, and it goes back by a scatter
+    of whole lane tiles."""
+    c, n, width = new.shape
+    span = latent.shape[2]
+    wide = min(span, -(-(n + WRITE_BLOCK - 1) // WRITE_BLOCK) * WRITE_BLOCK)
+    base = jnp.minimum(start // WRITE_BLOCK * WRITE_BLOCK, span - wide)
+    index = jnp.stack([jnp.full((c,), layer, jnp.int32), rows,
+                       jnp.zeros((c,), jnp.int32), base], axis=-1)
+    channel_major = jnp.swapaxes(latent, 2, 3)
+    window = jax.lax.gather(
+        channel_major, index,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1, 2), collapsed_slice_dims=(0, 1),
+            start_index_map=(0, 1, 2, 3)),
+        (1, 1, width, wide), mode=jax.lax.GatherScatterMode.CLIP)
+    # window slot k holds position base + k; new position j goes to
+    # k = start - base + j where j is real
+    k = jnp.arange(wide, dtype=jnp.int32)[None, :]
+    j = k - (start - base)[:, None]
+    real = (j >= 0) & (j < lengths[:, None])
+    taken = jnp.take_along_axis(
+        new.astype(latent.dtype), jnp.clip(j, 0, n - 1)[..., None], axis=1)
+    window = jnp.where(real[:, None, :], jnp.swapaxes(taken, 1, 2), window)
+    channel_major = jax.lax.scatter(
+        channel_major, index, window,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2, 3)),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+    return jnp.swapaxes(channel_major, 2, 3)
+
+
+def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
+                block: int = 512):
+    """A segment ``u`` [c, n, D] of ``n`` positions a row at ``start[c] +
+    arange(n)``, ``lengths[c]`` of them real, through layer ``layer`` of the
+    leaf ``latent`` [layers, rows, span, r + rope] where it lies: the real
+    positions' latents go into the rows ``rows`` [c] (None: all, in order;
+    an index past the last row writes nothing and reads the last row)
+    first, then every position sees its row's cache up to itself.  One row
+    at a time (``lax.map``), and of its cache only the blocks of ``block``
+    slots that hold one the segment can see (as
+    :func:`~sparkdl_tpu.models.hybrid.attention_segment`, so
+    :func:`~sparkdl_tpu.models.hybrid.keys_scored` counts them), each
+    folded into a running maximum, sum and float32 accumulator, in the
+    absorbed form.  Returns (the output projection's input [c, n, H v],
+    latent)."""
+    c, n = u.shape[:2]
+    span, width = latent.shape[2], shape.latent
+    if rows is None:
+        rows = jnp.arange(c, dtype=jnp.int32)
+    position = start[:, None] + jnp.arange(n, dtype=jnp.int32)
+    q_nope, q_pe, new = project(lp, u, position, shape)
+    latent = write_positions(latent, new, layer, rows, start, lengths)
+    w_uk, w_uv = _up(lp, shape)
+    heads = shape.heads
+    query = absorbed_query(q_nope, q_pe, w_uk)  # [c, n, H, r + rope]
+    block = min(block, span)
+    ahead = jnp.arange(n, dtype=jnp.int32)[:, None]
+    within = jnp.arange(block, dtype=jnp.int32)
+
+    def one_row(pair):
+        query, row, start = pair
+
+        def fold(j, carry):
+            top, total, acc = carry
+            # the last block is moved back to end with the span, and what it
+            # then shares with the block before is masked
+            at = jnp.minimum(j * block, span - block)
+            slots = jax.lax.dynamic_slice(
+                latent, (layer, row, at, 0), (1, 1, block, width))[0, 0]
+            seen = at + within
+            visible = (seen >= j * block) & (seen <= start + ahead)
+            scores = jnp.einsum("nhc,mc->hnm", query, slots,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(visible, scores * shape.scale, NEG)
+            new_top = jnp.maximum(top, scores.max(axis=-1))
+            probs = jnp.exp(scores - new_top[..., None])
+            kept = jnp.exp(top - new_top)
+            acc = acc * kept[..., None] + jnp.einsum(
+                "hnm,mc->hnc", probs.astype(slots.dtype), slots,
+                preferred_element_type=jnp.float32)
+            return new_top, total * kept + probs.sum(axis=-1), acc
+
+        _, total, acc = jax.lax.fori_loop(
+            0, (start + n + block - 1) // block, fold,
+            (jnp.full((heads, n), NEG, jnp.float32),
+             jnp.zeros((heads, n), jnp.float32),
+             jnp.zeros((heads, n, width), jnp.float32)))
+        out = (acc / total[..., None]).astype(latent.dtype)
+        # back from latent space: the value half of W_kvb
+        out = from_latent(out.transpose(1, 0, 2), w_uv)
+        return out.reshape(n, heads * shape.v)
+
+    out = jax.lax.map(
+        one_row, (query, jnp.minimum(rows, latent.shape[1] - 1), start))
+    return out, latent
+
+
+def mla_token(lp, u, latent, layer, position, shape: Shape):
+    """One position a row, ``u`` [r, D] at ``position`` [r], through layer
+    ``layer`` of the leaf, every row at once, in the absorbed form: the
+    position's latent goes into its slot first, then ``[q_lat | q_pe]``
+    scores every slot of the row's span (those past the row's position
+    masked), softmax in float32, ``softmax . [c | k_pe]`` in latent space
+    and ``W_UV`` after.  Returns (the output projection's input [r, H v],
+    latent)."""
+    r = u.shape[0]
+    q_nope, q_pe, new = project(lp, u, position, shape)
+    latent = write_positions(
+        latent, new[:, None], layer, jnp.arange(r, dtype=jnp.int32),
+        position, jnp.ones((r,), jnp.int32))
+    w_uk, w_uv = _up(lp, shape)
+    query = absorbed_query(q_nope, q_pe, w_uk)  # [r, H, r + rope]
+    cache = jax.lax.dynamic_index_in_dim(latent, layer, 0, keepdims=False)
+    # the cache is the LEFT operand of the scores' product, so that both
+    # products take it with its channels minor, as it is stored
+    scores = jnp.einsum("rsc,rhc->rsh", cache, query,
+                        preferred_element_type=jnp.float32) * shape.scale
+    visible = jnp.arange(cache.shape[1])[None, :] <= position[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(visible[..., None], scores, NEG), axis=1)
+    out = jnp.einsum("rsh,rsc->rhc", probs.astype(cache.dtype), cache)
+    out = from_latent(out, w_uv)
+    return out.reshape(r, shape.heads * shape.v), latent

@@ -151,6 +151,7 @@ def moe_ffn(
     stack_index=None,
     scoring: str = "softmax",
     select_bias=None,
+    scale: float = 1.0,
 ):
     """The held experts' part of ``sum_e w_e * down_e(silu(gate_e x) * up_e x)``.
 
@@ -167,7 +168,8 @@ def moe_ffn(
     Returns ``(out [n, D] in x's dtype, counts [E] int32)``; ``counts[e]`` is
     the number of tokens routed to expert e (held here or not), so
     ``counts.sum() == n * top_k`` and nothing was dropped.  ``scoring`` and
-    ``select_bias`` are :func:`route`'s.
+    ``select_bias`` are :func:`route`'s; ``scale`` multiplies the pairs'
+    float32 weights after their renormalisation (a ``routed_scaling_factor``).
     """
     n, d = x.shape
     n_experts = router.shape[-1]
@@ -218,6 +220,8 @@ def moe_ffn(
     else:
         parts = _rows_at(down, back).reshape(top_k, n, d)
     pair_weight = weights.T  # [k, n]
+    if scale != 1:
+        pair_weight = pair_weight * scale
     held = held.reshape(top_k, n)
     out = jnp.zeros((n, d), jnp.float32)
     for j in range(top_k):

@@ -3,7 +3,8 @@ a decode step, over a DataFrame column of prompts (arrays of token ids), for
 a model whose per-row state is a pytree that stays on the device: recurrent
 states beside a growing key/value cache
 (:class:`~sparkdl_tpu.models.granite_hybrid.GraniteHybridModel`,
-:class:`~sparkdl_tpu.models.solar_open2.SolarOpen2Model`).
+:class:`~sparkdl_tpu.models.solar_open2.SolarOpen2Model`), or a latent
+cache alone (:class:`~sparkdl_tpu.models.moonlight.MoonlightModel`).
 
 A batch is one state, donated from dispatch to dispatch, and two programs:
 
@@ -32,7 +33,11 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
 ``keys_scored``, ``keys_spanned``; ``rule_chunks``, ``rule_chunks_fused``
 where the model has a chunked rule),
-``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``),
+``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``, ``slots_read``:
+rows x span x steps, the cache slots its attention reads, and
+``slots_visible``: those at or before each row's own position), and on both
+``attention_path`` where the model's ``attention_paths`` names the form each
+program takes (``"absorbed"`` / ``"decompressed"``),
 ``engine.fetch_wait``, ``ar_generate.postprocess``; and, on the engine's
 watcher thread, one ``engine.device`` a dispatch (``program``:
 ``<model>_prefill`` / ``<model>_decode``; ``rows``, ``steps``,
@@ -52,7 +57,8 @@ were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
 ``ar_generate.decode_expert_reads`` (the (step, layer, held expert) triples
 that got a token: the expert matrices a batch's decode steps had to read),
 ``ar_generate.tokens_generated`` (real rows), ``ssm.state_bytes`` (bytes of recurrent state a batch holds on
-the device), and ``moe.tokens_routed``, ``moe.pairs_held`` (the routed pairs
+the device), ``mla.latent_cache_bytes`` (bytes of latent cache a batch holds:
+the model's ``latent_bytes``), and ``moe.tokens_routed``, ``moe.pairs_held`` (the routed pairs
 whose expert is held here), ``moe.tokens_dropped``, ``moe.expert_load_max``,
 ``moe.expert_load_mean`` (from the routing counts that come back with every
 program's result).
@@ -112,6 +118,9 @@ class SegmentPlan:
             raise ValueError(
                 "an empty prompt has no last token to generate after")
         self.real_tokens = int(lengths[:real].sum())
+        #: the batch's prompt positions, dummy rows' too (what the decode's
+        #: attention sees before its first step)
+        self.all_tokens = int(lengths.sum())
         self.span = (_round_up(max(int(lengths.max()), segment), SPAN_STEP)
                      + _round_up(gen, _LENGTH_STEP))
         #: a dispatch's (tokens [count, segment], rows, start, lengths) and
@@ -147,6 +156,14 @@ class SegmentPlan:
             [start for (_, _, start, _), _ in self.dispatches])
         self.keys_scored = keys_scored(starts, segment, self.span)
         self.keys_spanned = len(starts) * self.span
+
+    def slots_visible(self, rows: int, done: int, steps: int) -> int:
+        """The cache slots at or before each row's own position that the
+        decode steps ``done`` .. ``done + steps - 1`` of ``rows`` rows see:
+        at step t a row of a prompt of L tokens takes in position L + t and
+        sees L + t + 1 slots."""
+        return (steps * (self.all_tokens + rows)
+                + rows * (steps * done + steps * (steps - 1) // 2))
 
 
 class _Runner(ProgramRunner):
@@ -216,7 +233,8 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
         ".experts_per_token, .prefill(...) and .decode(...), and "
         ".rule_chunks(pairs, segment) where it has a chunked rule "
         "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel, "
-        "sparkdl_tpu.models.solar_open2.SolarOpen2Model)",
+        "sparkdl_tpu.models.solar_open2.SolarOpen2Model, "
+        "sparkdl_tpu.models.moonlight.MoonlightModel)",
     )
     recordCol = Param(
         "undefined", "recordCol",
@@ -330,6 +348,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
     later: List[Any] = []
 
     lo, hi = model.experts_held
+    dense = getattr(model, "dense_layers", 0)
     reads = 0
 
     def landed(pairs):
@@ -343,13 +362,16 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
             for slot, row in last or ():
                 first[row] = result[0][slot], result[1][slot]
             count_routing(counts, routed_tokens, model.experts_per_token,
-                          (lo, hi))
+                          (lo, hi), dense)
 
-    rule = {}
+    rule, paths = {}, getattr(model, "attention_paths", None) or {}
     if hasattr(model, "rule_chunks"):
         chunks, fused = model.rule_chunks(runner.count, runner.segment)
         rule = dict(rule_chunks=chunks * len(plan.dispatches),
                     rule_chunks_fused=fused * len(plan.dispatches))
+    if "prefill" in paths:
+        rule["attention_path"] = paths["prefill"]
+    decoded = {"attention_path": paths["decode"]} if "decode" in paths else {}
     try:
         with tracer.boundary(
                 "ar_generate.prefill", tokens=plan.real_tokens,
@@ -366,7 +388,11 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
         dispatches, left = 0, gen - 1
         while left:
             now = min(steps, left)
-            with tracer.boundary("ar_generate.decode", steps=now, rows=rows):
+            with tracer.boundary(
+                    "ar_generate.decode", steps=now, rows=rows,
+                    slots_read=rows * plan.span * now,
+                    slots_visible=plan.slots_visible(
+                        rows, gen - 1 - left, now), **decoded):
                 state, token, logprob, counts = runner.decode(state, now)
             landed(window.submit(
                 (token, logprob, counts), meta=(None, now * rows),
@@ -393,4 +419,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
     metrics.counter("ar_generate.decode_expert_reads").add(reads)
     metrics.counter("ar_generate.tokens_generated").add(len(prompts) * gen)
     metrics.counter("ssm.state_bytes").add(model.recurrent_bytes(rows))
+    if hasattr(model, "latent_bytes"):
+        metrics.counter("mla.latent_cache_bytes").add(
+            model.latent_bytes(rows, plan.span))
     return tokens_out, records_out

@@ -83,15 +83,18 @@ def runner_for(model, attribute: str, key, make: Callable):
     return held[key]
 
 
-def count_routing(counts, tokens: int, per_token: int, held) -> None:
+def count_routing(counts, tokens: int, per_token: int, held,
+                  dense: int = 0) -> None:
     """``counts`` [L, E]: the (token, expert) pairs each expert of each
     layer got in one program's forwards, through each layer of which
     ``tokens`` tokens went with ``per_token`` experts each; ``held`` = (lo,
     hi): the experts whose part is computed here (``moe.pairs_held`` beside
-    ``moe.tokens_routed`` is the share of the routed work this chip does)."""
+    ``moe.tokens_routed`` is the share of the routed work this chip does).
+    The first ``dense`` layers route nothing (leading dense layers): their
+    rows are left out."""
     from sparkdl_tpu.utils.metrics import metrics
 
-    counts = np.asarray(counts)
+    counts = np.asarray(counts)[dense:]
     routed = int(counts.sum())
     metrics.counter("moe.tokens_routed").add(routed)
     metrics.counter("moe.pairs_held").add(

@@ -220,9 +220,14 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
         "tokens": 28, "pad_tokens": 3 * 16 - 28, "segments": 3,
         "keys_scored": 6 * 512, "keys_spanned": 6 * 640}
     decodes = [r for r in inside if r.name == "ar_generate.decode"]
+    # a row of L tokens (the dummy row's 1) sees L + t + 1 slots at decode
+    # step t, of the 640 its attention reads: 29 + 4 (t + 1) a step
     assert [d.attributes for d in decodes] == [
-        {"steps": 2, "rows": 4}, {"steps": 2, "rows": 4},
-        {"steps": 1, "rows": 4}]
+        {"steps": 2, "rows": 4, "slots_read": 2 * 4 * 640,
+         "slots_visible": 33 + 37},
+        {"steps": 2, "rows": 4, "slots_read": 2 * 4 * 640,
+         "slots_visible": 41 + 45},
+        {"steps": 1, "rows": 4, "slots_read": 4 * 640, "slots_visible": 49}]
     delta = {c: metrics.counter(c).value - before[c] for c in COUNTERS}
     assert delta["ar_generate.prefill_tokens"] == 28
     assert delta["ar_generate.prefill_pad_tokens"] == 20

@@ -566,3 +566,67 @@ def test_solar_programs_compile_and_fit(
     # of a prefill segment; a decode step holds one layer's decayed state
     # and its successor (0.54 GB each) beside the 128 rows' scores
     assert m.temp_size_in_bytes < (1.25e9 if program == "prefill" else 2.6e9)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
+        program, one_chip, no_compile_cache, as_on_the_chip):
+    """The cell's two programs, whole (every MLA layer of a kind in one
+    scan): a prefill segment of 16 pairs x 128 positions and 8 decode
+    steps, against the latent cache of 128 rows at a span of
+    7,424.  The chip lays the leaf out span-minor (576 channels would pad
+    to 640); a scatter of a slot's channels made XLA copy the whole 5.47 GB
+    leaf into the other layout and back, and a slice of the decode's latent
+    output a layer's cache (0.97 GB): neither is left."""
+    import json
+    import os
+
+    from sparkdl_tpu.models import moonlight as ml
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "chipbench", "configs",
+            "moonlight_16b_a3b-generate.json")) as fh:
+        cfg = ml.MoonlightConfig.from_dict(json.load(fh))
+    rows, span = 128, 7424
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s, jnp.float32 if path[-1].key in ml.FLOAT32_LEAVES
+            else jnp.bfloat16, sharding=one_chip),
+        ml.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        ml.state_spec(cfg, rows, span, jnp.bfloat16))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        compiled = jax.jit(
+            lambda p, s, *rest: ml.prefill(p, cfg, s, *rest),
+            donate_argnums=(1,),
+        ).lower(params, state, ints(16, 128), ints(16), ints(16), ints(16)
+                ).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, s: ml.decode(p, cfg, s, 8), donate_argnums=(1,)
+        ).lower(params, state).compile()
+    text = compiled.as_text()
+    # gate, up and down of the expert layers' scan body
+    assert text.count("tpu_custom_call") == 3
+    leaf = "bf16[5,128,7424,576]"
+    assert not [line for line in text.splitlines()
+                if leaf in line and re.search(r"= \S+ copy(-start)?\(", line)]
+    assert f"{leaf}{{3,2,1,0" not in text  # the leaf in one layout only
+    assert "bf16[128,7424,512]" not in text
+    m = compiled.memory_analysis()
+    # 3,093.5 M parameters: 6.19 GB of bfloat16; the latent cache 5.47 GB
+    weights = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(params))
+    assert 6.18e9 < weights < 6.19e9
+    latent = 5 * rows * span * 576 * 2
+    assert m.alias_size_in_bytes >= latent  # the state is donated
+    assert m.argument_size_in_bytes < weights + latent + 1e7
+    # no second copy of the leaf, nor of a layer's share of it (1.09 GB)
+    assert m.temp_size_in_bytes < 0.3e9
