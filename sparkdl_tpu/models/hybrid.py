@@ -407,6 +407,12 @@ class HybridModel:
         on the device under a span of ``span`` positions."""
         return self._bytes(self.latent_leaves, rows, span)
 
+    def slots_read(self, positions, span: int) -> int:
+        """The cache slots a decode dispatch's attention reads for rows that
+        take in ``positions`` [steps, rows] under a span of ``span`` slots:
+        the whole span a row and step (:func:`attention_token`)."""
+        return int(np.size(positions)) * span
+
     def _bytes(self, leaves, rows: int, span: int) -> int:
         spec = self.state_spec(rows, span)
         return sum(

@@ -371,3 +371,9 @@ class MoonlightModel(HybridModel):
     @property
     def dense_layers(self) -> int:
         return self.config.dense
+
+    def slots_read(self, positions, span: int) -> int:
+        """What :func:`~sparkdl_tpu.ops.mla.mla_token` reads: whole blocks up
+        to each row's position where its kernel runs, the whole span where
+        the plain form does (:func:`~sparkdl_tpu.ops.mla.token_slots_read`)."""
+        return mla.token_slots_read(positions, self.config.mla.latent, span)

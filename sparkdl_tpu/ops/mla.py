@@ -29,14 +29,34 @@ decompressed form (every visited slot's ``c`` expanded by ``W_kvb`` into the
 heads' keys and values) and was the faster (PERF.md section 5, PR 41).
 Softmaxes, the norm's statistics and the rotation are float32; everything
 else runs in the weights' dtype.
+
+**Two forms of the decode's attention**, picked by what :func:`token_block`
+can see (the backend and the shapes; no argument, no environment variable):
+
+- on the TPU, where a slot's channels are whole sublane tiles and the span
+  whole lane tiles, ONE Pallas kernel (:func:`_token_kernel`): a grid step
+  a row, which copies the row's blocks of ``TOKEN_BLOCK`` slots up to its
+  position, each once, out of the leaf where it lies and scores, softmaxes
+  and sums all heads against that one copy;
+- elsewhere (the tier-1 tests' CPU, the rehearsal) plain ``jax.numpy``:
+  every slot of the span scored, the slots past a row's position masked.
+
+Both keep the same precision: float32 scores and softmax statistics,
+probabilities in the cache's dtype against the cache, float32 sums (the
+kernel divides by the sum at the end, as :func:`mla_segment` does).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from sparkdl_tpu.models.hybrid import NEG, rms_norm
 
@@ -231,14 +251,160 @@ def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
     return out, latent
 
 
+#: slots a block of the decode kernel's walk over a row's cache.  Each block
+#: passes the matrix unit twice for 16 query rows, so a block costs about
+#: what its copy does; at the Moonlight cell's shape a layer's call took
+#: 1.13 / 0.84 / 0.78 ms with blocks of 256 / 512 / 1,024 on the chip, and
+#: 1,024 reads twice the slots past the rows' positions (visible 0.86 of
+#: what is read, against 0.93; PERF.md section 5)
+TOKEN_BLOCK = 512
+
+
+def token_block(width: int, span: int) -> int:
+    """The slots of a block in which :func:`mla_token`'s attention walks each
+    row's cache: on the TPU, where a slot's ``width`` channels are whole
+    sublane tiles and the ``span`` whole lane tiles of the leaf as the chip
+    stores it, the kernel's block (:func:`_token_attention`); elsewhere 0,
+    the plain form (:func:`_token_attention_plain`), which reads the whole
+    span."""
+    taken = (jax.default_backend() == "tpu"
+             and width % 16 == 0 and span % 128 == 0)
+    return min(TOKEN_BLOCK, span) if taken else 0
+
+
+def token_slots_read(positions, width: int, span: int) -> int:
+    """The cache slots :func:`mla_token`'s attention reads for rows that
+    take in ``positions`` (any shape, one a row and step) from a cache of
+    ``width`` channels a slot and ``span`` slots: whole blocks up to each
+    position where the kernel runs (:func:`token_block`), the whole span
+    where the plain form does."""
+    positions = np.asarray(positions, np.int64)
+    block = token_block(width, span)
+    if not block:
+        return int(positions.size) * span
+    return int((positions // block + 1).sum()) * block
+
+
+def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
+                  buffer, copies, first, *, block, span, scale):
+    """One row's attention over its cache: the blocks of ``block`` slots
+    that hold one at or before the row's position, each copied once from
+    the leaf in HBM (``latent_ref`` [layers, rows, width, span], the
+    channel-major view) into one of the two halves of ``buffer`` [2, width,
+    block] while the block before is scored, then folded into a running
+    maximum, sum and float32 accumulator.  The last block of the span is
+    moved back to end with it, and what it shares with the block before is
+    masked.  The copies run on across rows (the grid is sequential): a
+    row's last block starts the next row's first, and ``first`` (SMEM) says
+    which half that went to."""
+    row, rows = pl.program_id(0), pl.num_programs(0)
+    layer, position = layer_ref[0], position_ref[row]
+    heads = query_ref.shape[0]
+
+    def copy(r, j, half):
+        at = jnp.minimum(j * block, span - block)
+        return pltpu.make_async_copy(
+            latent_ref.at[layer, r, :, pl.ds(at, block)], buffer.at[half],
+            copies.at[half])
+
+    @pl.when(row == 0)
+    def _():
+        first[0] = 0
+        copy(0, 0, 0).start()
+
+    blocks = position // block + 1
+    query = query_ref[...]
+    within = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+
+    def fold(j, carry):
+        top, total, acc = carry
+        half = (first[0] + j) % 2
+
+        @pl.when(j + 1 < blocks)
+        def _():
+            copy(row, j + 1, 1 - half).start()
+
+        @pl.when((j + 1 == blocks) & (row + 1 < rows))
+        def _():
+            copy(row + 1, 0, 1 - half).start()
+
+        copy(row, j, half).wait()
+        slots = buffer[half]  # [width, block]
+        scores = jax.lax.dot_general(
+            query, slots, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        seen = jnp.minimum(j * block, span - block) + within
+        visible = (seen >= j * block) & (seen <= position)
+        scores = jnp.where(visible, scores, NEG)
+        new_top = jnp.maximum(top, scores.max(axis=1, keepdims=True))
+        probs = jnp.exp(scores - new_top)
+        kept = jnp.exp(top - new_top)
+        acc = acc * kept + jax.lax.dot_general(
+            probs.astype(slots.dtype), slots, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return new_top, total * kept + probs.sum(axis=1, keepdims=True), acc
+
+    _, total, acc = jax.lax.fori_loop(
+        0, blocks, fold,
+        (jnp.full((heads, 1), NEG, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros(query_ref.shape, jnp.float32)))
+    out_ref[...] = (acc / total).astype(out_ref.dtype)
+    first[0] = (first[0] + blocks) % 2
+
+
+def _token_attention(query, latent, layer, position, scale: float,
+                     block: int, interpret=False):
+    """``softmax . [c | k_pe]`` [r, H, width] in the cache's dtype for the
+    absorbed ``query`` [r, H, width] of every row against layer ``layer`` of
+    the leaf ``latent`` [layers, r, span, width], slots past ``position``
+    [r] masked: ONE Pallas kernel (:func:`_token_kernel`), a grid step a
+    row, that reads of the leaf only each row's blocks up to its position,
+    once, in the channel-major view the chip stores."""
+    r, heads, width = query.shape
+    span = latent.shape[2]
+    row_block = pl.BlockSpec((None, heads, width), lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_token_kernel, block=block, span=span, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(r,),
+            in_specs=[row_block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row_block,
+            scratch_shapes=[pltpu.VMEM((2, width, block), latent.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(query.shape, latent.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mla_token",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), position.astype(jnp.int32),
+      query.astype(latent.dtype), jnp.swapaxes(latent, 2, 3))
+
+
+def _token_attention_plain(query, latent, layer, position, scale: float):
+    """:func:`_token_attention` in plain ``jax.numpy``: every slot of the
+    span scored, those past the row's position masked, one softmax."""
+    cache = jax.lax.dynamic_index_in_dim(latent, layer, 0, keepdims=False)
+    # the cache is the LEFT operand of the scores' product, so that both
+    # products take it with its channels minor, as it is stored
+    scores = jnp.einsum("rsc,rhc->rsh", cache, query,
+                        preferred_element_type=jnp.float32) * scale
+    visible = jnp.arange(cache.shape[1])[None, :] <= position[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(visible[..., None], scores, NEG), axis=1)
+    return jnp.einsum("rsh,rsc->rhc", probs.astype(cache.dtype), cache)
+
+
 def mla_token(lp, u, latent, layer, position, shape: Shape):
     """One position a row, ``u`` [r, D] at ``position`` [r], through layer
     ``layer`` of the leaf, every row at once, in the absorbed form: the
     position's latent goes into its slot first, then ``[q_lat | q_pe]``
-    scores every slot of the row's span (those past the row's position
-    masked), softmax in float32, ``softmax . [c | k_pe]`` in latent space
-    and ``W_UV`` after.  Returns (the output projection's input [r, H v],
-    latent)."""
+    scores the row's slots up to its position, softmax in float32,
+    ``softmax . [c | k_pe]`` in latent space and ``W_UV`` after.  One
+    kernel on the TPU at widths it takes (:func:`token_block`), plain
+    ``jax.numpy`` elsewhere.  Returns (the output projection's input [r,
+    H v], latent)."""
     r = u.shape[0]
     q_nope, q_pe, new = project(lp, u, position, shape)
     latent = write_positions(
@@ -246,14 +412,12 @@ def mla_token(lp, u, latent, layer, position, shape: Shape):
         position, jnp.ones((r,), jnp.int32))
     w_uk, w_uv = _up(lp, shape)
     query = absorbed_query(q_nope, q_pe, w_uk)  # [r, H, r + rope]
-    cache = jax.lax.dynamic_index_in_dim(latent, layer, 0, keepdims=False)
-    # the cache is the LEFT operand of the scores' product, so that both
-    # products take it with its channels minor, as it is stored
-    scores = jnp.einsum("rsc,rhc->rsh", cache, query,
-                        preferred_element_type=jnp.float32) * shape.scale
-    visible = jnp.arange(cache.shape[1])[None, :] <= position[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(visible[..., None], scores, NEG), axis=1)
-    out = jnp.einsum("rsh,rsc->rhc", probs.astype(cache.dtype), cache)
+    block = token_block(shape.latent, latent.shape[2])
+    if block:
+        out = _token_attention(query, latent, layer, position, shape.scale,
+                               block)
+    else:
+        out = _token_attention_plain(query, latent, layer, position,
+                                     shape.scale)
     out = from_latent(out, w_uv)
     return out.reshape(r, shape.heads * shape.v), latent

@@ -34,8 +34,11 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``keys_scored``, ``keys_spanned``; ``rule_chunks``, ``rule_chunks_fused``
 where the model has a chunked rule),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``, ``slots_read``:
-rows x span x steps, the cache slots its attention reads, and
-``slots_visible``: those at or before each row's own position), and on both
+the cache slots its attention reads a row and step as the model's
+``slots_read(positions, span)`` gives them — rows x span x steps where the
+attention scores the whole span, whole blocks up to each row's position
+where Moonlight's decode kernel walks them — and ``slots_visible``: those
+at or before each row's own position), and on both
 ``attention_path`` where the model's ``attention_paths`` names the form each
 program takes (``"absorbed"`` / ``"decompressed"``),
 ``engine.fetch_wait``, ``ar_generate.postprocess``; and, on the engine's
@@ -118,9 +121,9 @@ class SegmentPlan:
             raise ValueError(
                 "an empty prompt has no last token to generate after")
         self.real_tokens = int(lengths[:real].sum())
-        #: the batch's prompt positions, dummy rows' too (what the decode's
-        #: attention sees before its first step)
-        self.all_tokens = int(lengths.sum())
+        #: every row's prompt length, dummy rows' too (the decode's first
+        #: positions)
+        self.lengths = lengths
         self.span = (_round_up(max(int(lengths.max()), segment), SPAN_STEP)
                      + _round_up(gen, _LENGTH_STEP))
         #: a dispatch's (tokens [count, segment], rows, start, lengths) and
@@ -157,13 +160,11 @@ class SegmentPlan:
         self.keys_scored = keys_scored(starts, segment, self.span)
         self.keys_spanned = len(starts) * self.span
 
-    def slots_visible(self, rows: int, done: int, steps: int) -> int:
-        """The cache slots at or before each row's own position that the
-        decode steps ``done`` .. ``done + steps - 1`` of ``rows`` rows see:
-        at step t a row of a prompt of L tokens takes in position L + t and
-        sees L + t + 1 slots."""
-        return (steps * (self.all_tokens + rows)
-                + rows * (steps * done + steps * (steps - 1) // 2))
+    def positions(self, done: int, steps: int) -> np.ndarray:
+        """[steps, rows]: the position each row takes in at the decode steps
+        ``done`` .. ``done + steps - 1``: at step t a row of a prompt of L
+        tokens takes in position L + t (and sees L + t + 1 slots)."""
+        return self.lengths[None, :] + np.arange(done, done + steps)[:, None]
 
 
 class _Runner(ProgramRunner):
@@ -229,7 +230,7 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
         "undefined", "model",
         "the model's functions and params: an object with .params, .name, "
         ".fingerprint, .experts_held, .state_spec(rows, span), "
-        ".recurrent_bytes(rows), "
+        ".recurrent_bytes(rows), .slots_read(positions, span), "
         ".experts_per_token, .prefill(...) and .decode(...), and "
         ".rule_chunks(pairs, segment) where it has a chunked rule "
         "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel, "
@@ -388,11 +389,11 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
         dispatches, left = 0, gen - 1
         while left:
             now = min(steps, left)
+            positions = plan.positions(gen - 1 - left, now)
             with tracer.boundary(
                     "ar_generate.decode", steps=now, rows=rows,
-                    slots_read=rows * plan.span * now,
-                    slots_visible=plan.slots_visible(
-                        rows, gen - 1 - left, now), **decoded):
+                    slots_read=model.slots_read(positions, plan.span),
+                    slots_visible=int((positions + 1).sum()), **decoded):
                 state, token, logprob, counts = runner.decode(state, now)
             landed(window.submit(
                 (token, logprob, counts), meta=(None, now * rows),

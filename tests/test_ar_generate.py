@@ -249,6 +249,31 @@ def test_spans_and_counters_exist_without_tracing(tpu_session, model):
     assert delta["moe.expert_load_max"] >= delta["moe.expert_load_mean"] > 0
 
 
+def test_slots_read_follow_the_attention_that_runs(model, monkeypatch):
+    """A decode dispatch's ``slots_read`` is the model's count at the plan's
+    positions: granite's (and solar's) attention scores the whole span on
+    every backend; Moonlight's reads whole blocks up to each row's position
+    where its kernel runs (the TPU, a latent of whole tiles: 512 + 64
+    channels here) and the whole span where its plain form does."""
+    from sparkdl_tpu.models.moonlight import MoonlightModel
+    from test_moonlight import CONFIG as MOONLIGHT
+
+    plan = SegmentPlan(_prompts([9, 600, 13]), 4, 8, 2, gen=3)
+    assert plan.span == 1024 + 128
+    # the dummy row's prompt is one token; decode steps 1 and 2
+    positions = plan.positions(1, 2)
+    np.testing.assert_array_equal(
+        positions, [[10, 601, 14, 2], [11, 602, 15, 3]])
+    wide = MoonlightModel(
+        dict(MOONLIGHT, kv_lora_rank=512, qk_rope_head_dim=64), None)
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert model.slots_read(positions, plan.span) == 2 * 4 * 1152
+    assert wide.slots_read(positions, plan.span) == (6 + 2 * 2) * 512
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert wide.slots_read(positions, plan.span) == 2 * 4 * 1152
+
+
 def test_every_dispatch_gets_a_device_span_under_the_partitions_root(
         tpu_session, model):
     """The engine's watcher writes one ``engine.device`` a dispatch, named by

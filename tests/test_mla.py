@@ -1,0 +1,146 @@
+"""``ops/mla.py``'s decode attention in its two forms at the published head
+count and latent width (16 heads, 512 + 64 channels): the kernel
+(``_token_attention``, run here under Pallas' TPU interpret mode) against the
+plain ``jax.numpy`` form (what ``mla_token`` picks off the TPU), over ragged
+positions and a span that is no whole number of blocks.  Both take bfloat16
+operands and keep float32 scores, softmax statistics and sums, so they differ
+by rounding order only: the probabilities go to bfloat16 before the sum in
+both, unnormalised in the kernel, so the outputs differ by a bfloat16 ulp or
+two (2**-7 of the largest; one slot seen too many moves an output by more
+than twice that).  And which form is picked, by the backend and the shapes
+alone, and the slots each reads."""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+from sparkdl_tpu.ops import mla
+
+#: the published widths (Moonlight-16B-A3B) with a small hidden size
+SHAPE = mla.Shape(heads=16, nope=128, rope=64, v=128, rank=512,
+                  theta=50000.0, kv_eps=1e-6, scale=192 ** -0.5)
+HIDDEN = 64
+#: a position at 0, on a block's last slot, on a block's first, inside, and
+#: on the span's last; the spans: 2.25 blocks of 512 (the last one moved back
+#: to end with the span), and less than one block
+CASES = {1152: [0, 511, 512, 700, 1151], 384: [0, 127, 128, 200, 383]}
+TOLERANCE = 2 ** -7
+
+
+_interpreted_kernel = functools.partial(
+    mla._token_attention, interpret=pltpu.InterpretParams())
+
+
+def _layer_params(seed):
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape, std=0.05):
+        return jnp.asarray(std * rng.normal(size=shape), jnp.bfloat16)
+
+    heads = SHAPE.heads
+    return {
+        # a peaked softmax, as a trained model's (PERF.md section 2)
+        "wq": normal(HIDDEN, heads * (SHAPE.nope + SHAPE.rope), std=0.3),
+        "wkva": normal(HIDDEN, SHAPE.latent, std=0.2),
+        "kv_norm": jnp.ones((SHAPE.rank,), jnp.bfloat16),
+        "wkvb": normal(SHAPE.rank, heads * (SHAPE.nope + SHAPE.v)),
+    }
+
+
+def _latent(span, rows, seed):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.normal(size=(2, rows, span, SHAPE.latent)),
+                       jnp.bfloat16)
+
+
+@pytest.mark.parametrize("span", sorted(CASES))
+def test_the_kernel_is_the_plain_form_over_ragged_positions(span):
+    """``softmax . [c | k_pe]`` of both forms against one leaf: every row's
+    blocks up to its position, the last one's overlap with the block before
+    masked, slots past the position left out."""
+    rows = len(CASES[span])
+    rng = np.random.default_rng(span)
+    query = jnp.asarray(0.1 * rng.normal(size=(rows, SHAPE.heads,
+                                               SHAPE.latent)), jnp.bfloat16)
+    latent = _latent(span, rows, seed=1)
+    position = jnp.asarray(CASES[span], jnp.int32)
+    block = min(mla.TOKEN_BLOCK, span)
+    got = _interpreted_kernel(query, latent, jnp.int32(1), position,
+                              SHAPE.scale, block)
+    want = mla._token_attention_plain(query, latent, jnp.int32(1), position,
+                                      SHAPE.scale)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    size = np.abs(want).max(axis=(1, 2))  # a row's largest
+    assert (np.abs(got - want).max(axis=(1, 2)) < TOLERANCE * size).all()
+    # the tolerance sees one slot too many: the next slot seen as well moves
+    # every row (but the last, at the span's end) by more than twice it
+    past = mla._token_attention_plain(
+        query, latent, jnp.int32(1), jnp.minimum(position + 1, span - 1),
+        SHAPE.scale)
+    moved = np.abs(np.asarray(past, np.float32) - want).max(axis=(1, 2))
+    assert (moved[:-1] > 2 * TOLERANCE * size[:-1]).all()
+
+
+@pytest.mark.parametrize("span", sorted(CASES))
+def test_mla_token_writes_and_attends_alike_on_both_branches(
+        span, monkeypatch):
+    """``mla_token`` whole, plain here and then on the kernel's branch: the
+    leaf as written is the same to the bit, the output within bfloat16
+    rounding."""
+    rows = len(CASES[span])
+    lp = _layer_params(seed=4)
+    rng = np.random.default_rng(5)
+    u = jnp.asarray(rng.normal(size=(rows, HIDDEN)), jnp.bfloat16)
+    latent = _latent(span, rows, seed=6)
+    position = jnp.asarray(CASES[span], jnp.int32)
+    assert mla.token_block(SHAPE.latent, span) == 0
+    want, want_leaf = mla.mla_token(lp, u, latent, jnp.int32(1), position,
+                                    SHAPE)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert mla.token_block(SHAPE.latent, span) == min(mla.TOKEN_BLOCK, span)
+    ran = []
+
+    def kernel(*args):
+        ran.append(args[-1])
+        return _interpreted_kernel(*args)
+
+    monkeypatch.setattr(mla, "_token_attention", kernel)
+    got, leaf = mla.mla_token(lp, u, latent, jnp.int32(1), position, SHAPE)
+    assert ran == [min(mla.TOKEN_BLOCK, span)]
+    np.testing.assert_array_equal(np.asarray(leaf, np.float32),
+                                  np.asarray(want_leaf, np.float32))
+    # the new slots are written, and read by the attention that follows
+    _, _, new = mla.project(lp, u, position, SHAPE)
+    np.testing.assert_array_equal(
+        np.asarray(leaf[1, np.arange(rows), position], np.float32),
+        np.asarray(new, np.float32))
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    size = np.abs(want).max(axis=1)  # a row's largest
+    assert (np.abs(got - want).max(axis=1) < 2 * TOLERANCE * size).all()
+
+
+@pytest.mark.parametrize("backend,width,span,block,read", [
+    # the cell's: 58 lane tiles, 36 sublane tiles; blocks 1, 1, 2 and 15
+    ("tpu", 576, 7424, 512, 19 * 512),
+    ("tpu", 576, 384, 384, 4 * 384),  # a span under a block is one block
+    ("tpu", 48, 640, 512, 6 * 512),   # the tests' width: 3 tiles of 16
+    ("tpu", 40, 640, 0, 4 * 640),     # a width of no whole tiles
+    ("tpu", 576, 7400, 0, 4 * 7400),  # a span of no whole lane tiles
+    ("cpu", 576, 7424, 0, 4 * 7424)],
+    ids=["tpu-cell", "tpu-short", "tpu-narrow", "tpu-ragged-width",
+         "tpu-ragged-span", "cpu"])
+def test_the_kernel_is_picked_by_backend_and_shapes_alone(
+        monkeypatch, backend, width, span, block, read):
+    """``token_block`` is what ``mla_token`` asks and what the decode span's
+    ``slots_read`` counts by: whole blocks up to each position where the
+    kernel runs, the whole span where the plain form does."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert mla.token_block(width, span) == block
+    positions = np.array([[0, 511, 512, span - 1]]) % span
+    assert mla.token_slots_read(positions, width, span) == read

@@ -418,6 +418,56 @@ def test_the_model_generates_through_the_stage(
         n + t + 1 for n in LENGTHS for t in range(gen - 1))
 
 
+def test_the_kernels_branch_through_the_stage_reads_whole_blocks(
+        tpu_session, params, rows, small_dispatches, monkeypatch):
+    """The decode on the kernel's branch, as on the chip: ``token_block``
+    says blocks of 8 slots (the chip's are 512 at the published width) and
+    the kernel runs in Pallas' TPU interpreter inside the layer scans.
+    Every generated position is still the reference's, and ``slots_read``
+    counts whole blocks up to each row's position, where the plain branch
+    above counts the whole span."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from sparkdl_tpu import AutoregressiveTransformer
+    from sparkdl_tpu.obs.trace import tracer
+
+    # no executable of the plain branch from the engine (a fingerprint of its
+    # own) or its store, and none of this one to the store
+    monkeypatch.setattr(ml, "_source_digest", lambda: "the-kernels-branch")
+    monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "off")
+    monkeypatch.setattr(mla, "token_block", lambda width, span: 8)
+    ran = []
+    kernel = mla._token_attention
+
+    def interpreted(*args):
+        ran.append(args[-1])
+        return kernel(*args, interpret=pltpu.InterpretParams())
+
+    monkeypatch.setattr(mla, "_token_attention", interpreted)
+    gen = 6
+    frame = tpu_session.createDataFrame(
+        list(enumerate(rows)), ["rowId", "prompt"], numPartitions=1)
+    out = AutoregressiveTransformer(
+        inputCol="prompt", outputCol="generated", recordCol="record",
+        model=ml.MoonlightModel(CONFIG, params), genLength=gen,
+        batchSize=4).transform(frame).collect()
+    # traced once a decode program (2 steps and 1) and layer scan
+    assert ran and set(ran) == {8}
+    for prompt, row in zip(rows, out):
+        tokens = np.asarray(row["generated"])
+        want = reference.teacher_forced(params, CONFIG, prompt, tokens)
+        np.testing.assert_array_equal(want.argmax(axis=-1), tokens)
+        np.testing.assert_allclose(np.asarray(row["record"])[:, 1],
+                                   want.max(axis=-1), atol=4e-6)
+    decodes = [r for r in tracer.recent() if r.name == "ar_generate.decode"]
+    # at decode step t a row of L tokens takes in position L + t, in block
+    # (L + t) // 8 of the walk
+    assert sum(d.attributes["slots_read"] for d in decodes[-3:]) == sum(
+        8 * ((n + t) // 8 + 1) for n in LENGTHS for t in range(gen - 1))
+    assert sum(d.attributes["slots_visible"] for d in decodes[-3:]) == sum(
+        n + t + 1 for n in LENGTHS for t in range(gen - 1))
+
+
 def test_a_model_without_paths_leaves_the_spans_without_one(
         tpu_session, small_dispatches):
     """Granite's decode spans carry the slot counts too, and no path."""

@@ -568,6 +568,30 @@ def test_solar_programs_compile_and_fit(
     assert m.temp_size_in_bytes < (1.25e9 if program == "prefill" else 2.6e9)
 
 
+def test_mla_token_kernel_compiles(one_chip, no_compile_cache):
+    """``ops/mla``'s decode kernel alone at the cell's decode shape: 128
+    rows' absorbed queries of 16 heads x 576 channels against the channel-
+    major view of the latent leaf, 5 layers x 7,424 slots, blocks of 512
+    (the span is 14.5 of them: the last one moved back)."""
+    from sparkdl_tpu.ops import mla
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda query, latent, layer, position: mla._token_attention(
+            query, latent, layer, position, 192 ** -0.5, mla.TOKEN_BLOCK)
+    ).lower(
+        spec(jnp.bfloat16, 128, 16, 576), spec(jnp.bfloat16, 5, 128, 7424, 576),
+        spec(jnp.int32), spec(jnp.int32, 128),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _fits(compiled)
+    # the leaf is read where it lies: nothing of its size among the
+    # temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
         program, one_chip, no_compile_cache, as_on_the_chip):
@@ -612,11 +636,19 @@ def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
             lambda p, s: ml.decode(p, cfg, s, 8), donate_argnums=(1,)
         ).lower(params, state).compile()
     text = compiled.as_text()
-    # gate, up and down of the expert layers' scan body
-    assert text.count("tpu_custom_call") == 3
+    # gate, up and down of the expert layers' scan body; in decode the
+    # attention's kernel besides, once in each of the two layer scans'
+    # bodies (the dense layer's and the expert layers')
+    assert text.count("tpu_custom_call") == (3 if program == "prefill" else 5)
+    if program == "decode":
+        assert len(re.findall(r"%mla_token[.\d]* = ", text)) == 2
+        # the scores of every row against the whole span are gone from HBM
+        assert "f32[128,7424,16]" not in text
     leaf = "bf16[5,128,7424,576]"
+    # the kernel takes the channel-major view, a bitcast of the leaf
     assert not [line for line in text.splitlines()
-                if leaf in line and re.search(r"= \S+ copy(-start)?\(", line)]
+                if (leaf in line or "bf16[5,128,576,7424]" in line)
+                and re.search(r"= \S+ copy(-start)?\(", line)]
     assert f"{leaf}{{3,2,1,0" not in text  # the leaf in one layout only
     assert "bf16[128,7424,512]" not in text
     m = compiled.memory_analysis()
