@@ -129,6 +129,15 @@ def _ctor_name(value: ast.AST) -> Optional[str]:
 
 
 def collect_lock_state(tree: ast.Module, relpath: str) -> FileLockState:
+    """The file's lock state, collected once a parsed tree: the call graph
+    and three rules ask for it, and none of them changes it."""
+    memo = tree.__dict__.setdefault("_lock_states", {})
+    if relpath not in memo:
+        memo[relpath] = _collect_lock_state(tree, relpath)
+    return memo[relpath]
+
+
+def _collect_lock_state(tree: ast.Module, relpath: str) -> FileLockState:
     state = FileLockState(relpath)
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):

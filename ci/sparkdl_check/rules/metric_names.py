@@ -53,12 +53,14 @@ from ci.sparkdl_check.core import FileContext, Rule, rule
 #: state-space / attention hybrid.
 #: "mla" (bytes of latent cache a batch holds) joined with the ISSUE-41
 #: latent-attention decoder.
+#: "dsa" (bytes of a learned sparse attention's index cache a batch holds)
+#: joined with the GLM-5 decoder.
 ALLOWED_PREFIXES = (
     "sparkdl", "data", "serving", "resilience", "estimator", "engine",
     "streaming", "slo", "ts", "supervisor", "router", "wire",
     "rollout", "tenant", "fleet", "replica", "faultnet", "diag",
     "profile", "cache", "decode", "batcher", "csql", "generate", "moe",
-    "ar_generate", "ssm", "mla",
+    "ar_generate", "ssm", "mla", "dsa",
 )
 
 METRIC_FACTORIES = {"counter", "timer", "gauge", "histogram"}

@@ -1,5 +1,5 @@
 """What the stage's decoders share (``granite_hybrid``, ``solar_open2``,
-``moonlight``): a stack of layers of one or two kinds — a recurrent mixer
+``moonlight``, ``glm5``): a stack of layers of one or two kinds — a recurrent mixer
 with a per-row state, grouped-query softmax attention with NO position term
 through a key/value cache, latent attention through a latent cache
 (``ops/mla.py``) — each followed by a sparse-expert feed-forward with a
@@ -365,6 +365,13 @@ class HybridModel:
     recurrent_leaves = ()
     #: the state leaves that ``mla.latent_cache_bytes`` counts
     latent_leaves = ()
+    #: the state leaves that ``dsa.index_cache_bytes`` counts (a learned
+    #: sparse attention's indexer keys)
+    index_leaves = ()
+    #: a prefill dispatch may take several consecutive segments of one row
+    #: (its programs write every pair's caches before any pair reads, and
+    #: the per-row state is caches alone)
+    packs_segments = False
     #: leading layers whose feed-forward is dense: their rows of a
     #: program's routing counts are zeros, and the stage skips them
     dense_layers = 0
@@ -406,6 +413,11 @@ class HybridModel:
         """Bytes of latent cache (``latent_leaves``) that ``rows`` rows hold
         on the device under a span of ``span`` positions."""
         return self._bytes(self.latent_leaves, rows, span)
+
+    def index_bytes(self, rows: int, span: int) -> int:
+        """Bytes of indexer keys (``index_leaves``) that ``rows`` rows hold
+        on the device under a span of ``span`` positions."""
+        return self._bytes(self.index_leaves, rows, span)
 
     def slots_read(self, positions, span: int) -> int:
         """The cache slots a decode dispatch's attention reads for rows that

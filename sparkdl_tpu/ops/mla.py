@@ -69,7 +69,7 @@ class Shape(NamedTuple):
     v: int  # v_head_dim
     rank: int  # kv_lora_rank
     theta: float  # rope_theta
-    kv_eps: float  # the latent norm's eps
+    kv_eps: float  # the latent norm's eps (and the low-rank query's)
     scale: float  # the scores' factor, (nope + rope) ** -0.5 as published
 
     @property
@@ -96,11 +96,24 @@ def rope(x, position, theta: float):
     ).astype(x.dtype)
 
 
-def project(lp, u, position, shape: Shape):
+def query_latent(lp, u, shape: Shape):
+    """``c_q = RMSNorm_q(u W_qa)`` [..., q_lora_rank]: a low-rank query's
+    latent (``q_a_proj``, ``q_a_layernorm`` at the latent norm's eps)."""
+    return rms_norm(jnp.dot(u, lp["wqa"]), lp["q_norm"], shape.kv_eps)
+
+
+def project(lp, u, position, shape: Shape, c_q=None):
     """``(q_nope [..., H, nope], q_pe [..., H, rope], latent [..., r +
     rope])`` of ``u`` [..., D] at ``position`` [...]: the queries, and what
-    the cache keeps of the position (``c`` normed, ``k_pe`` turned)."""
-    q = jnp.dot(u, lp["wq"])
+    the cache keeps of the position (``c`` normed, ``k_pe`` turned).  The
+    query is ``u W_q``, or ``c_q W_qb`` where the layer has a low-rank query
+    (``wqa``; ``c_q``: :func:`query_latent`'s, where the caller has it)."""
+    if "wqa" in lp:
+        if c_q is None:
+            c_q = query_latent(lp, u, shape)
+        q = jnp.dot(c_q, lp["wqb"])
+    else:
+        q = jnp.dot(u, lp["wq"])
     q = q.reshape(*u.shape[:-1], shape.heads, shape.nope + shape.rope)
     q_pe = rope(q[..., shape.nope:], position[..., None], shape.theta)
     kva = jnp.dot(u, lp["wkva"])
@@ -144,7 +157,8 @@ def absorbed_query(q_nope, q_pe, w_uk):
 WRITE_BLOCK = 128
 
 
-def write_positions(latent, new, layer, rows, start, lengths):
+def write_positions(latent, new, layer, rows, start, lengths,
+                    aligned: bool = False):
     """``new`` [c, n, r + rope] into ``latent[layer, rows[c], start[c] +
     j]`` for ``j < lengths[c]``, in place where the leaf is donated; a pad
     (``j >= lengths[c]``) and a row past the last are written nowhere.
@@ -153,11 +167,19 @@ def write_positions(latent, new, layer, rows, start, lengths):
     wide enough for ``n`` positions at any start, in the channel-major view
     ``[layers, rows, r + rope, span]`` (what the chip stores): the window is
     gathered, the real positions are put in, and it goes back by a scatter
-    of whole lane tiles."""
+    of whole lane tiles.  ``aligned``: each ``start`` is a multiple of ``n``
+    and ``start + n`` lies within the span (the stage's segments: whole lane
+    tiles on the chip), and the window is the segment's own, so that
+    consecutive segments of one row in one call write windows that do not
+    overlap."""
     c, n, width = new.shape
     span = latent.shape[2]
-    wide = min(span, -(-(n + WRITE_BLOCK - 1) // WRITE_BLOCK) * WRITE_BLOCK)
-    base = jnp.minimum(start // WRITE_BLOCK * WRITE_BLOCK, span - wide)
+    if aligned:
+        wide, base = n, start
+    else:
+        wide = min(span,
+                   -(-(n + WRITE_BLOCK - 1) // WRITE_BLOCK) * WRITE_BLOCK)
+        base = jnp.minimum(start // WRITE_BLOCK * WRITE_BLOCK, span - wide)
     index = jnp.stack([jnp.full((c,), layer, jnp.int32), rows,
                        jnp.zeros((c,), jnp.int32), base], axis=-1)
     channel_major = jnp.swapaxes(latent, 2, 3)
@@ -191,21 +213,34 @@ def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
     leaf ``latent`` [layers, rows, span, r + rope] where it lies: the real
     positions' latents go into the rows ``rows`` [c] (None: all, in order;
     an index past the last row writes nothing and reads the last row)
-    first, then every position sees its row's cache up to itself.  One row
-    at a time (``lax.map``), and of its cache only the blocks of ``block``
-    slots that hold one the segment can see (as
-    :func:`~sparkdl_tpu.models.hybrid.attention_segment`, so
-    :func:`~sparkdl_tpu.models.hybrid.keys_scored` counts them), each
-    folded into a running maximum, sum and float32 accumulator, in the
-    absorbed form.  Returns (the output projection's input [c, n, H v],
-    latent)."""
+    first, then every position sees its row's cache up to itself
+    (:func:`segment_attention`).  Returns (the output projection's input
+    [c, n, H v], latent)."""
     c, n = u.shape[:2]
-    span, width = latent.shape[2], shape.latent
     if rows is None:
         rows = jnp.arange(c, dtype=jnp.int32)
     position = start[:, None] + jnp.arange(n, dtype=jnp.int32)
     q_nope, q_pe, new = project(lp, u, position, shape)
     latent = write_positions(latent, new, layer, rows, start, lengths)
+    return segment_attention(lp, q_nope, q_pe, latent, layer, rows, start,
+                             shape, block), latent
+
+
+def segment_attention(lp, q_nope, q_pe, latent, layer, rows, start,
+                      shape: Shape, block: int = 512, chosen=None):
+    """The attention of a segment's queries (``q_nope``, ``q_pe`` [c, n, H,
+    ...] at ``start[c] + arange(n)``) over their rows ``rows`` [c] of layer
+    ``layer`` of the leaf, as written: one row at a time (``lax.map``), and
+    of its cache only the blocks of ``block`` slots that hold one the
+    segment can see (as :func:`~sparkdl_tpu.models.hybrid.attention_segment`,
+    so :func:`~sparkdl_tpu.models.hybrid.keys_scored` counts them), each
+    folded into a running maximum, sum and float32 accumulator, in the
+    absorbed form.  ``chosen`` [c, n, span] (nonzero: kept), where given,
+    leaves out of each query's softmax the visible slots it does not keep
+    (a learned sparse attention's selection, ``ops/dsa.py``).  Returns the
+    output projection's input [c, n, H v]."""
+    n = q_nope.shape[1]
+    span, width = latent.shape[2], shape.latent
     w_uk, w_uv = _up(lp, shape)
     heads = shape.heads
     query = absorbed_query(q_nope, q_pe, w_uk)  # [c, n, H, r + rope]
@@ -214,7 +249,7 @@ def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
     within = jnp.arange(block, dtype=jnp.int32)
 
     def one_row(pair):
-        query, row, start = pair
+        query, row, start = pair[:3]
 
         def fold(j, carry):
             top, total, acc = carry
@@ -225,6 +260,9 @@ def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
                 latent, (layer, row, at, 0), (1, 1, block, width))[0, 0]
             seen = at + within
             visible = (seen >= j * block) & (seen <= start + ahead)
+            if chosen is not None:
+                visible = visible & (jax.lax.dynamic_slice(
+                    pair[3], (0, at), (n, block)) != 0)
             scores = jnp.einsum("nhc,mc->hnm", query, slots,
                                 preferred_element_type=jnp.float32)
             scores = jnp.where(visible, scores * shape.scale, NEG)
@@ -246,9 +284,10 @@ def mla_segment(lp, u, latent, layer, rows, start, lengths, shape: Shape,
         out = from_latent(out.transpose(1, 0, 2), w_uv)
         return out.reshape(n, heads * shape.v)
 
-    out = jax.lax.map(
-        one_row, (query, jnp.minimum(rows, latent.shape[1] - 1), start))
-    return out, latent
+    pairs = (query, jnp.minimum(rows, latent.shape[1] - 1), start)
+    if chosen is not None:
+        pairs = pairs + (chosen,)
+    return jax.lax.map(one_row, pairs)
 
 
 #: slots a block of the decode kernel's walk over a row's cache.  Each block
@@ -382,15 +421,20 @@ def _token_attention(query, latent, layer, position, scale: float,
       query.astype(latent.dtype), jnp.swapaxes(latent, 2, 3))
 
 
-def _token_attention_plain(query, latent, layer, position, scale: float):
+def _token_attention_plain(query, latent, layer, position, scale: float,
+                           chosen=None):
     """:func:`_token_attention` in plain ``jax.numpy``: every slot of the
-    span scored, those past the row's position masked, one softmax."""
+    span scored, those past the row's position masked, one softmax.
+    ``chosen`` [r, span] (nonzero: kept), where given, masks the slots a
+    row does not keep as well (``ops/dsa.py``)."""
     cache = jax.lax.dynamic_index_in_dim(latent, layer, 0, keepdims=False)
     # the cache is the LEFT operand of the scores' product, so that both
     # products take it with its channels minor, as it is stored
     scores = jnp.einsum("rsc,rhc->rsh", cache, query,
                         preferred_element_type=jnp.float32) * scale
     visible = jnp.arange(cache.shape[1])[None, :] <= position[:, None]
+    if chosen is not None:
+        visible = visible & (chosen != 0)
     probs = jax.nn.softmax(
         jnp.where(visible[..., None], scores, NEG), axis=1)
     return jnp.einsum("rsh,rsc->rhc", probs.astype(cache.dtype), cache)

@@ -64,19 +64,35 @@ def _tile(dim: int, cap: int = 2048) -> int:
     return fitting[0] if fitting else dim
 
 
+#: the most elements a tile of an expert's matrix may hold: two of them (the
+#: kernel's double buffer) in bfloat16, beside a row tile and the float32
+#: accumulator, within the 16 MB of fast memory a kernel may use.  GLM-5's
+#: 6,144 x 2,048 expert matrices are the first whose whole-dimension tiles
+#: (2,048 x 2,048) did not fit
+TILE_ELEMENTS = 2048 * 1536
+
+
 def gmm_grouped_dot(rows, weights, sizes, interpret: bool = False):
     """``megablox.gmm`` over row tiles of 128 (the rows padded up to whole
-    tiles; the pads lie behind the last group)."""
+    tiles; the pads lie behind the last group), the weights in tiles of at
+    most :data:`TILE_ELEMENTS`."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = rows.shape
+    n = weights.shape[-1]
     tile_m = min(128, -(-m // 16) * 16)
     padded = -(-m // tile_m) * tile_m
     if padded != m:
         rows = jnp.pad(rows, ((0, padded - m), (0, 0)))
+    tile_k, tile_n = _tile(k), _tile(n)
+    if tile_k * tile_n > TILE_ELEMENTS:  # the larger side gives way
+        if tile_k >= tile_n:
+            tile_k = _tile(k, TILE_ELEMENTS // tile_n)
+        else:
+            tile_n = _tile(n, TILE_ELEMENTS // tile_k)
     out = gmm(
         rows, weights, sizes, preferred_element_type=rows.dtype,
-        tiling=(tile_m, _tile(k), _tile(weights.shape[-1])),
+        tiling=(tile_m, tile_k, tile_n),
         interpret=interpret,
     )
     return out[:m]

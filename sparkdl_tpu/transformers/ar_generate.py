@@ -3,8 +3,10 @@ a decode step, over a DataFrame column of prompts (arrays of token ids), for
 a model whose per-row state is a pytree that stays on the device: recurrent
 states beside a growing key/value cache
 (:class:`~sparkdl_tpu.models.granite_hybrid.GraniteHybridModel`,
-:class:`~sparkdl_tpu.models.solar_open2.SolarOpen2Model`), or a latent
-cache alone (:class:`~sparkdl_tpu.models.moonlight.MoonlightModel`).
+:class:`~sparkdl_tpu.models.solar_open2.SolarOpen2Model`), a latent
+cache alone (:class:`~sparkdl_tpu.models.moonlight.MoonlightModel`), or a
+latent cache beside a learned sparse attention's index cache
+(:class:`~sparkdl_tpu.models.glm5.Glm5Model`).
 
 A batch is one state, donated from dispatch to dispatch, and two programs:
 
@@ -14,7 +16,11 @@ A batch is one state, donated from dispatch to dispatch, and two programs:
   row's state from its segment before.  The pairs go segment by segment
   (every row's first, then every longer row's second, ...), so rows of
   different lengths finish at different dispatches and a pad costs at most
-  the rest of one segment; a dispatch's spare pairs name no row.  A row's
+  the rest of one segment; a dispatch's spare pairs name no row.  For a
+  model that packs segments (``packs_segments``) the pairs go in order
+  of (segment, row) instead, ``SEGMENT_ROWS`` a dispatch: a row's consecutive
+  segments may share a dispatch (its program writes every pair's caches
+  before any pair reads), and only the last dispatch has spare pairs.  A row's
   state comes back as of ITS last real token; prefill overwrites every
   row's state (the dummy rows that pad a partition's last batch are prompts
   of one token), so nothing of the batch before is ever read.  The row's
@@ -29,18 +35,27 @@ once per model object; an executable holds no weight constants.
 
 Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 ``ar_generate.partition`` (root; ``model``: the model's ``.name``, ``rows``,
-``batches``, ``prompt_tokens``, ``generated_tokens``) > ``ar_generate.plan``, ``engine.place``,
+``batches``, ``prompt_tokens``, ``generated_tokens``) > ``ar_generate.plan``
+(``segments``, ``span``, ``packed``: the most segments of one row in a
+dispatch), ``engine.place``,
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
 ``keys_scored``, ``keys_spanned``; ``rule_chunks``, ``rule_chunks_fused``
-where the model has a chunked rule),
+where the model has a chunked rule; ``keys_indexed``, ``keys_selected``
+where it has a learned sparse attention: the (query, key) pairs its indexer
+scores and those its attention scores after selection, over the layers, as
+the model's ``index_pairs(starts, segment, span)`` gives them for the path
+taken),
 ``ar_generate.decode`` (one a dispatch; ``steps``, ``rows``, ``slots_read``:
 the cache slots its attention reads a row and step as the model's
 ``slots_read(positions, span)`` gives them — rows x span x steps where the
 attention scores the whole span, whole blocks up to each row's position
 where Moonlight's decode kernel walks them — and ``slots_visible``: those
-at or before each row's own position), and on both
+at or before each row's own position; ``slots_selected`` where the model
+selects: those its attention scores after selection, the model's
+``slots_selected(positions, span)``), and on both
 ``attention_path`` where the model's ``attention_paths`` names the form each
-program takes (``"absorbed"`` / ``"decompressed"``),
+program takes (``"absorbed"``; ``"masked"``: selected keys by a mask
+over every visible one),
 ``engine.fetch_wait``, ``ar_generate.postprocess``; and, on the engine's
 watcher thread, one ``engine.device`` a dispatch (``program``:
 ``<model>_prefill`` / ``<model>_decode``; ``rows``, ``steps``,
@@ -61,7 +76,9 @@ were pads, spare pairs or dummy rows), ``ar_generate.decode_steps`` and
 that got a token: the expert matrices a batch's decode steps had to read),
 ``ar_generate.tokens_generated`` (real rows), ``ssm.state_bytes`` (bytes of recurrent state a batch holds on
 the device), ``mla.latent_cache_bytes`` (bytes of latent cache a batch holds:
-the model's ``latent_bytes``), and ``moe.tokens_routed``, ``moe.pairs_held`` (the routed pairs
+the model's ``latent_bytes``), ``dsa.index_cache_bytes`` (bytes of a
+learned sparse attention's index cache: the model's ``index_bytes``, 0
+where it has none), and ``moe.tokens_routed``, ``moe.pairs_held`` (the routed pairs
 whose expert is held here), ``moe.tokens_dropped``, ``moe.expert_load_max``,
 ``moe.expert_load_mean`` (from the routing counts that come back with every
 program's result).
@@ -111,7 +128,7 @@ class SegmentPlan:
     which dispatch."""
 
     def __init__(self, prompts: List[np.ndarray], rows: int, segment: int,
-                 count: int, gen: int):
+                 count: int, gen: int, packed: bool = False):
         real = len(prompts)
         # a dummy row (batch padding) is a prompt of one token
         prompts = [np.asarray(p, np.int32) for p in prompts] + [
@@ -129,8 +146,24 @@ class SegmentPlan:
         #: a dispatch's (tokens [count, segment], rows, start, lengths) and
         #: the (slot, row) of the rows whose LAST segment it holds
         self.dispatches = []
-        done = np.zeros(rows, np.int64)  # segments a row has behind it
         left = -(-lengths // segment)
+        #: the most segments of one row in a dispatch
+        self.packed = 1
+        if packed:
+            self._pack(prompts, left, segment, count, rows)
+        else:
+            self._one_a_row(prompts, left, segment, count, rows)
+        self.pad_tokens = (
+            len(self.dispatches) * count * segment - self.real_tokens)
+        #: every pair's start, spare pairs too, in dispatch order
+        self.starts = np.concatenate(
+            [start for (_, _, start, _), _ in self.dispatches])
+        self.keys_scored = keys_scored(self.starts, segment, self.span)
+        self.keys_spanned = len(self.starts) * self.span
+
+    def _one_a_row(self, prompts, left, segment: int, count: int, rows: int):
+        """A dispatch takes one segment of a row at most."""
+        done = np.zeros(rows, np.int64)  # segments a row has behind it
         while left.any():
             # a row's segments follow each other, so a dispatch takes one of
             # each row at most: the rows with most still to go first (ties
@@ -153,12 +186,32 @@ class SegmentPlan:
                 if not left[row]:
                     last.append((slot, row))
             self.dispatches.append(((tokens, index, start, held), last))
-        self.pad_tokens = (
-            len(self.dispatches) * count * segment - self.real_tokens)
-        starts = np.concatenate(
-            [start for (_, _, start, _), _ in self.dispatches])
-        self.keys_scored = keys_scored(starts, segment, self.span)
-        self.keys_spanned = len(starts) * self.span
+
+    def _pack(self, prompts, left, segment: int, count: int, rows: int):
+        """Every (row, segment) pair in order of (segment, row), ``count`` a
+        dispatch: no spare pair but in the last dispatch, and a row's
+        consecutive segments may share one (for a model whose state is
+        caches alone, which a dispatch writes before any pair reads), never
+        one before its predecessor's."""
+        pairs = sorted((s, row) for row in range(rows)
+                       for s in range(int(left[row])))
+        for lo in range(0, len(pairs), count):
+            group = pairs[lo:lo + count]
+            tokens = np.zeros((count, segment), np.int32)
+            index = np.full(count, rows, np.int32)  # past the last: nobody
+            start = np.zeros(count, np.int32)
+            held = np.zeros(count, np.int32)
+            last = []
+            for slot, (s, row) in enumerate(group):
+                part = prompts[row][s * segment:(s + 1) * segment]
+                tokens[slot, :len(part)] = part
+                index[slot], start[slot], held[slot] = row, s * segment, len(
+                    part)
+                if s + 1 == left[row]:
+                    last.append((slot, row))
+            self.packed = max(self.packed, int(np.bincount(
+                [row for _, row in group]).max()))
+            self.dispatches.append(((tokens, index, start, held), last))
 
     def positions(self, done: int, steps: int) -> np.ndarray:
         """[steps, rows]: the position each row takes in at the decode steps
@@ -235,7 +288,8 @@ class AutoregressiveTransformer(Transformer, HasInputCol, HasOutputCol):
         ".rule_chunks(pairs, segment) where it has a chunked rule "
         "(sparkdl_tpu.models.granite_hybrid.GraniteHybridModel, "
         "sparkdl_tpu.models.solar_open2.SolarOpen2Model, "
-        "sparkdl_tpu.models.moonlight.MoonlightModel)",
+        "sparkdl_tpu.models.moonlight.MoonlightModel, "
+        "sparkdl_tpu.models.glm5.Glm5Model)",
     )
     recordCol = Param(
         "undefined", "recordCol",
@@ -336,9 +390,11 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
     model = runner.model
     root = tracer.current()
     with tracer.boundary("ar_generate.plan", rows=len(prompts)) as span:
-        plan = SegmentPlan(prompts, rows, runner.segment, runner.count, gen)
+        plan = SegmentPlan(prompts, rows, runner.segment, runner.count, gen,
+                           packed=getattr(model, "packs_segments", False))
         span.set_attribute("segments", len(plan.dispatches))
         span.set_attribute("span", plan.span)
+        span.set_attribute("packed", int(plan.packed))
     host = [arrays for arrays, _ in plan.dispatches]
     with tracer.boundary(
             "engine.place", bytes=sum(a.nbytes for group in host for a in group)):
@@ -372,6 +428,9 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
                     rule_chunks_fused=fused * len(plan.dispatches))
     if "prefill" in paths:
         rule["attention_path"] = paths["prefill"]
+    if hasattr(model, "index_pairs"):
+        rule["keys_indexed"], rule["keys_selected"] = model.index_pairs(
+            plan.starts, runner.segment, plan.span)
     decoded = {"attention_path": paths["decode"]} if "decode" in paths else {}
     try:
         with tracer.boundary(
@@ -390,6 +449,9 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
         while left:
             now = min(steps, left)
             positions = plan.positions(gen - 1 - left, now)
+            if hasattr(model, "slots_selected"):
+                decoded["slots_selected"] = model.slots_selected(
+                    positions, plan.span)
             with tracer.boundary(
                     "ar_generate.decode", steps=now, rows=rows,
                     slots_read=model.slots_read(positions, plan.span),
@@ -423,4 +485,7 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
     if hasattr(model, "latent_bytes"):
         metrics.counter("mla.latent_cache_bytes").add(
             model.latent_bytes(rows, plan.span))
+    index_bytes = getattr(model, "index_bytes", None)
+    metrics.counter("dsa.index_cache_bytes").add(
+        index_bytes(rows, plan.span) if index_bytes else 0)
     return tokens_out, records_out

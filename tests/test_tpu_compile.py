@@ -662,3 +662,96 @@ def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
     assert m.argument_size_in_bytes < weights + latent + 1e7
     # no second copy of the leaf, nor of a layer's share of it (1.09 GB)
     assert m.temp_size_in_bytes < 0.3e9
+
+
+def test_dsa_select_kernel_compiles(one_chip, no_compile_cache):
+    """``ops/dsa``'s selection kernel alone at the GLM-5 cell's prefill
+    shape: 16 pairs of 128 queries x 32 indexer heads of 128 against the
+    slot-major index leaf of 5 layers x 16 rows x 30,848 slots, keeping 2,048
+    a query; the row's keys and the scores stay in fast memory."""
+    from sparkdl_tpu.ops import dsa
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, w, keys, layer, rows, start: dsa._select(
+            q, w, keys, layer, rows, start, 2048, dsa.SELECT_BLOCK)
+    ).lower(
+        spec(jnp.bfloat16, 16, 128, 32, 128), spec(jnp.float32, 16, 128, 32),
+        spec(jnp.bfloat16, 5, 16, 30848, 128), spec(jnp.int32),
+        spec(jnp.int32, 16), spec(jnp.int32, 16),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _fits(compiled)
+    # the leaf is read where it lies: nothing of its size (632 MB) among the
+    # temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_glm5_programs_compile_and_fit(
+        program, one_chip, no_compile_cache, as_on_the_chip):
+    """The GLM-5 cell's two programs whole: a prefill dispatch of 16 pairs x
+    128 positions (consecutive segments of one row may share it) and 8
+    decode steps, against the caches of 16 rows at a span of 30,848.  Both
+    leaves are written and read where they lie: the latent (1.78 GB) by
+    segments of whole write blocks that go back in one scatter of windows
+    that do not overlap (written one pair after another, the leaf was copied
+    into another layout and back, 4.1 GB of temporaries), the index keys
+    (632 MB) by one scatter of whole slots (by windows as the latent, they
+    were copied whole into another layout in every layer)."""
+    import json
+    import os
+
+    from sparkdl_tpu.models import glm5
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "chipbench", "configs",
+            "glm_5_744b_a40b-generate.json")) as fh:
+        cfg = glm5.Glm5Config.from_dict(json.load(fh))
+    rows, span = 16, 30848
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s, jnp.float32 if path[-1].key in glm5.FLOAT32_LEAVES
+            else jnp.bfloat16, sharding=one_chip),
+        glm5.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        glm5.state_spec(cfg, rows, span, jnp.bfloat16))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        compiled = jax.jit(
+            lambda p, s, *rest: glm5.prefill(p, cfg, s, *rest),
+            donate_argnums=(1,),
+        ).lower(params, state, ints(16, 128), ints(16), ints(16), ints(16)
+                ).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, s: glm5.decode(p, cfg, s, 8), donate_argnums=(1,)
+        ).lower(params, state).compile()
+    text = compiled.as_text()
+    # gate, up and down of the expert layers' scan body; in prefill the
+    # selection kernel besides, once in each of the two layer scans
+    assert text.count("tpu_custom_call") == (5 if program == "prefill" else 3)
+    assert len(re.findall(r"%dsa_select[.\d]* = ", text)) == (
+        2 if program == "prefill" else 0)
+    assert not [line for line in text.splitlines()
+                if ("bf16[5,16,30848,576]" in line
+                    or "bf16[5,16,576,30848]" in line
+                    or "bf16[5,16,30848,128]" in line)
+                and re.search(r"= \S+ copy(-start)?\(", line)]
+    m = compiled.memory_analysis()
+    # 3,909.6 M parameters: 7.82 GB of bfloat16; the caches 3.47 GB
+    weights = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(params))
+    assert 7.81e9 < weights < 7.83e9
+    caches = rows * span * 5 * (576 + 128) * 2
+    assert m.alias_size_in_bytes >= caches  # the state is donated
+    assert m.argument_size_in_bytes < weights + caches + 1e7
+    assert m.temp_size_in_bytes < (0.6e9 if program == "prefill" else 1e9)
