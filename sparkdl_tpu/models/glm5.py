@@ -433,6 +433,11 @@ class Glm5Model(HybridModel):
     def dense_layers(self) -> int:
         return self.config.dense
 
+    def keys_fused(self, start, segment: int, span: int) -> int:
+        """The cache slots a prefill's attention folds in its kernel, as
+        :func:`~sparkdl_tpu.ops.mla.keys_fused` counts them."""
+        return mla.keys_fused(self.config.mla, start, segment, span)
+
     def index_pairs(self, start, segment: int, span: int) -> Tuple[int, int]:
         """``(keys_indexed, keys_selected)`` of a prefill whose pairs of
         ``segment`` positions start at ``start`` under a span of ``span``,

@@ -372,6 +372,11 @@ class MoonlightModel(HybridModel):
     def dense_layers(self) -> int:
         return self.config.dense
 
+    def keys_fused(self, start, segment: int, span: int) -> int:
+        """The cache slots a prefill's attention folds in its kernel, as
+        :func:`~sparkdl_tpu.ops.mla.keys_fused` counts them."""
+        return mla.keys_fused(self.config.mla, start, segment, span)
+
     def slots_read(self, positions, span: int) -> int:
         """What :func:`~sparkdl_tpu.ops.mla.mla_token` reads: whole blocks up
         to each row's position where its kernel runs, the whole span where

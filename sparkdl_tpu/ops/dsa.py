@@ -35,8 +35,11 @@ attention reads beside causality; unselected keys take no part in any
 softmax.  The attention itself is MLA's absorbed form over whole blocks of
 the cache (:func:`~sparkdl_tpu.ops.mla.segment_attention`) with the
 unselected slots masked, so its work is that of dense attention over the
-visible slots (the models' ``attention_paths`` call it ``"masked"``): what
-selection could save is left to a later change.
+visible slots (the models' ``attention_paths`` call it ``"masked"``).  On
+the TPU that fold is one kernel, which holds its scores, statistics and
+accumulator in fast memory and reads the mask a block at a time beside the
+latent; a form that scores the selected slots only, and so saves what
+selection could, is still open.
 
 **Two forms of the prefill's selection**, picked by what
 :func:`select_block` can see (the backend and the shapes; no argument, no

@@ -41,9 +41,24 @@ can see (the backend and the shapes; no argument, no environment variable):
 - elsewhere (the tier-1 tests' CPU, the rehearsal) plain ``jax.numpy``:
   every slot of the span scored, the slots past a row's position masked.
 
-Both keep the same precision: float32 scores and softmax statistics,
-probabilities in the cache's dtype against the cache, float32 sums (the
-kernel divides by the sum at the end, as :func:`mla_segment` does).
+**Two forms of the prefill's attention** (:func:`segment_attention`),
+picked by what :func:`segment_group` can see (the backend and the shapes):
+
+- on the TPU, where the latent and its ``rank`` channels are whole sublane
+  tiles, a segment too, and the span whole lane tiles, ONE Pallas kernel
+  (:func:`_segment_kernel`, ``mla_segment`` in a trace): a grid step a
+  (pair, head group), whose group's query rows stay in fast memory while
+  the pair's visible blocks of ``block`` slots are copied in, each once for
+  the group, out of the leaf where it lies (and the pair's tiles of a
+  selection's mask beside them), and whose scores, running statistics and
+  float32 accumulator never leave fast memory; the sum takes the ``rank``
+  channels only (the rope channels meet zero rows of ``W_UV``, so the
+  output's are left zero);
+- elsewhere the same fold in plain ``jax.numpy``, a row at a time.
+
+All four keep the same precision: float32 scores and softmax statistics,
+probabilities in the cache's dtype against the cache, float32 sums divided
+by the softmax's sum at the end.
 """
 
 from __future__ import annotations
@@ -58,7 +73,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from sparkdl_tpu.models.hybrid import NEG, rms_norm
+from sparkdl_tpu.models.hybrid import NEG, keys_scored, rms_norm
 
 class Shape(NamedTuple):
     """One MLA layer's widths and constants (``deepseek_v3``'s keys)."""
@@ -230,20 +245,49 @@ def segment_attention(lp, q_nope, q_pe, latent, layer, rows, start,
                       shape: Shape, block: int = 512, chosen=None):
     """The attention of a segment's queries (``q_nope``, ``q_pe`` [c, n, H,
     ...] at ``start[c] + arange(n)``) over their rows ``rows`` [c] of layer
-    ``layer`` of the leaf, as written: one row at a time (``lax.map``), and
-    of its cache only the blocks of ``block`` slots that hold one the
-    segment can see (as :func:`~sparkdl_tpu.models.hybrid.attention_segment`,
-    so :func:`~sparkdl_tpu.models.hybrid.keys_scored` counts them), each
+    ``layer`` of the leaf, as written: of each row's cache only the blocks
+    of ``block`` slots that hold one the segment can see (as
+    :func:`~sparkdl_tpu.models.hybrid.attention_segment`, so
+    :func:`~sparkdl_tpu.models.hybrid.keys_scored` counts them), each
     folded into a running maximum, sum and float32 accumulator, in the
-    absorbed form.  ``chosen`` [c, n, span] (nonzero: kept), where given,
-    leaves out of each query's softmax the visible slots it does not keep
-    (a learned sparse attention's selection, ``ops/dsa.py``).  Returns the
-    output projection's input [c, n, H v]."""
+    absorbed form: ONE kernel on the TPU at the shapes it takes
+    (:func:`segment_group`, :func:`_segment_attention`), the plain fold
+    elsewhere (:func:`_segment_attention_plain`).  ``chosen`` [c, n, span]
+    (nonzero: kept), where given, leaves out of each query's softmax the
+    visible slots it does not keep (a learned sparse attention's selection,
+    ``ops/dsa.py``).  Returns the output projection's input [c, n, H v]."""
     n = q_nope.shape[1]
-    span, width = latent.shape[2], shape.latent
     w_uk, w_uv = _up(lp, shape)
     heads = shape.heads
     query = absorbed_query(q_nope, q_pe, w_uk)  # [c, n, H, r + rope]
+    group = segment_group(shape, n, latent.shape[2])
+    if group:
+        out = _segment_attention(
+            query.transpose(0, 2, 1, 3), latent, layer, rows, start,
+            shape.scale, shape.rank, min(block, latent.shape[2]), group,
+            chosen)
+        out = from_latent(out.transpose(0, 2, 1, 3), w_uv)
+        return out.reshape(*out.shape[:2], heads * shape.v)
+
+    def finish(out):
+        # back from latent space: the value half of W_kvb
+        out = from_latent(out.transpose(1, 0, 2), w_uv)
+        return out.reshape(n, heads * shape.v)
+
+    return _segment_attention_plain(query, latent, layer, rows, start,
+                                    shape.scale, block, chosen, finish)
+
+
+def _segment_attention_plain(query, latent, layer, rows, start,
+                             scale: float, block: int, chosen=None,
+                             finish=None):
+    """The fold of :func:`segment_attention` in plain ``jax.numpy``, one row
+    at a time (``lax.map``) for the absorbed ``query`` [c, n, H, width]:
+    ``finish`` (of a pair's ``softmax . [c | k_pe]`` [H, n, width] in the
+    cache's dtype) of every pair; without it [c, H, n, width], the kernel's
+    output (:func:`_segment_attention`)."""
+    n, heads = query.shape[1], query.shape[2]
+    span, width = latent.shape[2], latent.shape[3]
     block = min(block, span)
     ahead = jnp.arange(n, dtype=jnp.int32)[:, None]
     within = jnp.arange(block, dtype=jnp.int32)
@@ -265,7 +309,7 @@ def segment_attention(lp, q_nope, q_pe, latent, layer, rows, start,
                     pair[3], (0, at), (n, block)) != 0)
             scores = jnp.einsum("nhc,mc->hnm", query, slots,
                                 preferred_element_type=jnp.float32)
-            scores = jnp.where(visible, scores * shape.scale, NEG)
+            scores = jnp.where(visible, scores * scale, NEG)
             new_top = jnp.maximum(top, scores.max(axis=-1))
             probs = jnp.exp(scores - new_top[..., None])
             kept = jnp.exp(top - new_top)
@@ -280,14 +324,189 @@ def segment_attention(lp, q_nope, q_pe, latent, layer, rows, start,
              jnp.zeros((heads, n), jnp.float32),
              jnp.zeros((heads, n, width), jnp.float32)))
         out = (acc / total[..., None]).astype(latent.dtype)
-        # back from latent space: the value half of W_kvb
-        out = from_latent(out.transpose(1, 0, 2), w_uv)
-        return out.reshape(n, heads * shape.v)
+        return out if finish is None else finish(out)
 
     pairs = (query, jnp.minimum(rows, latent.shape[1] - 1), start)
     if chosen is not None:
         pairs = pairs + (chosen,)
     return jax.lax.map(one_row, pairs)
+
+
+#: query rows (a head group's heads x a segment's positions) that a grid step
+#: of the prefill kernel folds against each block of the cache: the block is
+#: the matrix unit's stationary operand, and the rows stream through it.  At
+#: the GLM-5 cell's shape (64 heads, segments of 128, a selection's mask) a
+#: dispatch's fold took 32.7 / 31.8 / 31.3 ms on the chip with groups of
+#: 1,024 / 2,048 / 4,096 rows, against 38.0 for the plain fold (PERF.md
+#: section 5)
+SEGMENT_QUERY_ROWS = 4096
+
+
+def segment_group(shape: Shape, n: int, span: int) -> int:
+    """The heads a grid step of :func:`segment_attention`'s kernel takes for
+    segments of ``n`` positions under a span of ``span``: on the TPU, where
+    the latent and its ``rank`` channels are whole sublane tiles, a segment
+    too, and the span whole lane tiles, the largest divisor of the heads
+    whose group holds at most :data:`SEGMENT_QUERY_ROWS` query rows (at
+    least one head); elsewhere 0, the plain fold."""
+    taken = (jax.default_backend() == "tpu" and shape.latent % 16 == 0
+             and shape.rank % 16 == 0 and n % 16 == 0 and span % 128 == 0)
+    if not taken:
+        return 0
+    group = max(1, min(shape.heads, SEGMENT_QUERY_ROWS // n))
+    while shape.heads % group:
+        group -= 1
+    return group
+
+
+def keys_fused(shape: Shape, start, n: int, span: int) -> int:
+    """The cache slots :func:`segment_attention`'s kernel folds for segments
+    of ``n`` positions at ``start`` under a span of ``span``: every slot
+    the fold scores (:func:`~sparkdl_tpu.models.hybrid.keys_scored`) where
+    the kernel runs (:func:`segment_group`), none where the plain fold
+    does.  Host arithmetic, for the stage's span attributes."""
+    return keys_scored(start, n, span) if segment_group(shape, n, span) else 0
+
+
+def _segment_kernel(layer_ref, rows_ref, start_ref, query_ref, latent_ref,
+                    *refs, block, span, scale, rank, masked):
+    """One (pair, head group)'s attention over its row's cache: the blocks
+    of ``block`` slots that hold one the segment can see, each copied once
+    from the leaf in HBM (``latent_ref`` [layers, rows, width, span], the
+    channel-major view) into one half of ``buffer`` [2, width, block] while
+    the block before is folded, and with it the pair's ``[n, block]`` tile
+    of ``chosen`` (``masked``) into ``tiles``.  Each block's scores of the
+    group's query rows (``query_ref`` [heads, n, width]) are folded into a
+    running maximum, sum and float32 accumulator held in fast memory (``top``,
+    ``total``, ``acc``); the sum takes the first ``rank`` channels only (the
+    rope channels meet zero rows of ``W_UV``), and the rope channels of the
+    output stay zero.  The last block of the span is moved back to end with
+    it, and what it shares with the block before is masked.  The copies run
+    on across grid steps (they are sequential): a step's last block starts
+    the next step's first, and ``first`` (SMEM) says which half that went
+    to."""
+    if masked:
+        chosen_ref, out_ref, buffer, tiles, copies, first, top, total, acc = (
+            refs)
+    else:
+        out_ref, buffer, copies, first, top, total, acc = refs
+    pair, group = pl.program_id(0), pl.program_id(1)
+    pairs, groups = pl.num_programs(0), pl.num_programs(1)
+    layer, start = layer_ref[0], start_ref[pair]
+    heads, n, width = query_ref.shape
+    last = latent_ref.shape[1] - 1
+
+    def copies_of(p, j, half):
+        at = jnp.minimum(j * block, span - block)
+        row = jnp.minimum(rows_ref[p], last)
+        made = [pltpu.make_async_copy(
+            latent_ref.at[layer, row, :, pl.ds(at, block)], buffer.at[half],
+            copies.at[0, half])]
+        if masked:
+            made.append(pltpu.make_async_copy(
+                chosen_ref.at[p, :, pl.ds(at, block)], tiles.at[half],
+                copies.at[1, half]))
+        return made
+
+    @pl.when((pair == 0) & (group == 0))
+    def _():
+        first[0] = 0
+        for made in copies_of(0, 0, 0):
+            made.start()
+
+    blocks = (start + n + block - 1) // block
+    following = jnp.where(group + 1 < groups, pair, pair + 1)
+    more = (group + 1 < groups) | (pair + 1 < pairs)
+    position = start + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    within = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    top[...] = jnp.full(top.shape, NEG, jnp.float32)
+    total[...] = jnp.zeros(total.shape, jnp.float32)
+    acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    def fold(j, carry):
+        half = (first[0] + j) % 2
+
+        @pl.when(j + 1 < blocks)
+        def _():
+            for made in copies_of(pair, j + 1, 1 - half):
+                made.start()
+
+        @pl.when((j + 1 == blocks) & more)
+        def _():
+            for made in copies_of(following, 0, 1 - half):
+                made.start()
+
+        for made in copies_of(pair, j, half):
+            made.wait()
+        query = query_ref[...].reshape(heads * n, width)
+        scores = jax.lax.dot_general(
+            query, buffer[half], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        seen = jnp.minimum(j * block, span - block) + within
+        visible = (seen >= j * block) & (seen <= position)  # [n, block]
+        if masked:
+            visible = visible & (tiles[half].astype(jnp.float32) != 0)
+        scores = jnp.where(visible[None], scores.reshape(heads, n, block),
+                           NEG).reshape(heads * n, block)
+        was = top[...]
+        new_top = jnp.maximum(was, scores.max(axis=1, keepdims=True))
+        probs = jnp.exp(scores - new_top)
+        kept = jnp.exp(was - new_top)
+        top[...] = new_top
+        total[...] = total[...] * kept + probs.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * kept + jax.lax.dot_general(
+            probs.astype(buffer.dtype), buffer[half, :rank, :],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, blocks, fold, 0)
+    out = (acc[...] / total[...]).reshape(heads, n, rank)
+    out_ref[:, :, :rank] = out.astype(out_ref.dtype)
+    out_ref[:, :, rank:] = jnp.zeros((heads, n, width - rank), out_ref.dtype)
+    first[0] = (first[0] + blocks) % 2
+
+
+def _segment_attention(query, latent, layer, rows, start, scale: float,
+                       rank: int, block: int, group: int, chosen=None,
+                       interpret=False):
+    """``softmax . [c | k_pe]`` [c, H, n, width] in the cache's dtype (the
+    rope channels zero) for the absorbed ``query`` [c, H, n, width] of every
+    pair, head-major, at ``start[c] + arange(n)`` against layer ``layer`` of
+    the leaf ``latent`` [layers, rows, span, width] at the rows ``rows``
+    [c] (past the last: the last), ``chosen`` [c, n, span] (nonzero: kept)
+    masking where given: ONE Pallas kernel (:func:`_segment_kernel`), a grid
+    step a (pair, group of ``group`` heads), that reads of the leaf only
+    each pair's blocks up to its segment's end, in the channel-major view
+    the chip stores."""
+    c, heads, n, width = query.shape
+    span = latent.shape[2]
+    masked = chosen is not None
+    group_block = pl.BlockSpec((None, group, n, width),
+                               lambda i, g, *_: (i, g, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scratch = [pltpu.VMEM((2, width, block), latent.dtype)]
+    if masked:
+        scratch.append(pltpu.VMEM((2, n, block), chosen.dtype))
+    scratch += [pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((group * n, 1), jnp.float32),
+                pltpu.VMEM((group * n, 1), jnp.float32),
+                pltpu.VMEM((group * n, rank), jnp.float32)]
+    operands = [jnp.swapaxes(latent, 2, 3)] + ([chosen] if masked else [])
+    return pl.pallas_call(
+        functools.partial(_segment_kernel, block=block, span=span,
+                          scale=scale, rank=rank, masked=masked),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(c, heads // group),
+            in_specs=[group_block] + [hbm] * len(operands),
+            out_specs=group_block, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(query.shape, latent.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name="mla_segment",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      start.astype(jnp.int32), query.astype(latent.dtype), *operands)
 
 
 #: slots a block of the decode kernel's walk over a row's cache.  Each block

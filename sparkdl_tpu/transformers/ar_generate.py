@@ -40,7 +40,10 @@ Spans (``obs.trace`` boundaries, made whether or not tracing is enabled):
 dispatch), ``engine.place``,
 ``ar_generate.prefill`` (``tokens``, ``pad_tokens``, ``segments``,
 ``keys_scored``, ``keys_spanned``; ``rule_chunks``, ``rule_chunks_fused``
-where the model has a chunked rule; ``keys_indexed``, ``keys_selected``
+where the model has a chunked rule; ``keys_fused`` where the model has a
+kernel for its prefill's latent attention: the slots of ``keys_scored`` that
+it folded, the model's ``keys_fused(starts, segment, span)`` (0 on the plain
+fold); ``keys_indexed``, ``keys_selected``
 where it has a learned sparse attention: the (query, key) pairs its indexer
 scores and those its attention scores after selection, over the layers, as
 the model's ``index_pairs(starts, segment, span)`` gives them for the path
@@ -428,6 +431,9 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
                     rule_chunks_fused=fused * len(plan.dispatches))
     if "prefill" in paths:
         rule["attention_path"] = paths["prefill"]
+    if hasattr(model, "keys_fused"):
+        rule["keys_fused"] = model.keys_fused(plan.starts, runner.segment,
+                                              plan.span)
     if hasattr(model, "index_pairs"):
         rule["keys_indexed"], rule["keys_selected"] = model.index_pairs(
             plan.starts, runner.segment, plan.span)

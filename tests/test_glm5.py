@@ -377,6 +377,8 @@ def test_the_model_generates_through_the_stage(
     assert planned.attributes["packed"] == 2
     (prefill,) = [r for r in spans if r.name == "ar_generate.prefill"][-1:]
     assert prefill.attributes["attention_path"] == "masked"
+    # the plain fold: no slot through the prefill's kernel
+    assert prefill.attributes["keys_fused"] == 0
     assert (prefill.attributes["keys_indexed"],
             prefill.attributes["keys_selected"]) == model.index_pairs(
                 plan.starts, 8, plan.span)
@@ -418,6 +420,54 @@ def test_the_selection_kernel_through_the_stage(
         np.testing.assert_array_equal(want.argmax(axis=-1), tokens)
         np.testing.assert_allclose(np.asarray(row["record"])[:, 1],
                                    want.max(axis=-1), atol=4e-6)
+
+
+def test_the_attention_kernel_through_the_stage(
+        tpu_session, params, rows, small_dispatches, monkeypatch):
+    """The prefill's attention on the kernel's branch, as on the chip:
+    ``segment_group`` says two heads a grid step (two groups) and the kernel
+    runs in Pallas' TPU interpreter inside the layer scans, over packed
+    segments of one row and the selection's mask.  Every generated position
+    is still the reference's, and the prefill span's ``keys_fused`` is its
+    ``keys_scored``: every slot the fold scores went through the kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from sparkdl_tpu import AutoregressiveTransformer
+    from sparkdl_tpu.obs.trace import tracer
+
+    # a fingerprint of its own: no executable of another branch from the
+    # engine's memory or store
+    monkeypatch.setattr(glm5, "_source_digest",
+                        lambda: "the-attention-kernels-branch")
+    monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "off")
+    monkeypatch.setattr(mla, "segment_group", lambda shape, n, span: 2)
+    ran = []
+    kernel = mla._segment_attention
+
+    def interpreted(*args):
+        ran.append((args[-2], args[-1] is not None))
+        return kernel(*args, interpret=pltpu.InterpretParams())
+
+    monkeypatch.setattr(mla, "_segment_attention", interpreted)
+    frame = tpu_session.createDataFrame(
+        list(enumerate(rows)), ["rowId", "prompt"], numPartitions=1)
+    out = AutoregressiveTransformer(
+        inputCol="prompt", outputCol="generated", recordCol="record",
+        model=glm5.Glm5Model(CONFIG, params), genLength=4,
+        batchSize=4).transform(frame).collect()
+    # traced once a layer scan, each with the selection's mask
+    assert ran and set(ran) == {(2, True)}
+    for prompt, row in zip(rows, out):
+        tokens = np.asarray(row["generated"])
+        want = reference.teacher_forced(params, CONFIG, prompt, tokens)
+        np.testing.assert_array_equal(want.argmax(axis=-1), tokens)
+        np.testing.assert_allclose(np.asarray(row["record"])[:, 1],
+                                   want.max(axis=-1), atol=4e-6)
+    (prefill,) = [r for r in tracer.recent()
+                  if r.name == "ar_generate.prefill"][-1:]
+    plan = SegmentPlan(rows, 4, 8, 3, 4, packed=True)
+    assert prefill.attributes["keys_fused"] == (
+        prefill.attributes["keys_scored"]) == plan.keys_scored > 0
 
 
 def test_the_packed_plan_keeps_each_row_in_order():

@@ -144,3 +144,77 @@ def test_the_kernel_is_picked_by_backend_and_shapes_alone(
     assert mla.token_block(width, span) == block
     positions = np.array([[0, 511, 512, span - 1]]) % span
     assert mla.token_slots_read(positions, width, span) == read
+
+
+# -- the prefill's fold -------------------------------------------------------
+
+#: segments of 32 positions against a span of 320 slots in blocks of 128:
+#: pair 0 at start 0; pairs 1 and 2 consecutive segments of row 1 in one
+#: call; pair 3 a row past the last (the last is read) ending with the span,
+#: whose third block is moved back to end with it
+SEGMENT = dict(n=32, span=320, block=128, rows=[0, 1, 1, 7],
+               starts=[0, 64, 96, 288])
+
+
+@pytest.mark.parametrize("heads,group,masked", [
+    (4, 4, False), (4, 4, True), (16, 4, False), (16, 4, True)],
+    ids=["one-group", "one-group-chosen", "four-groups",
+         "four-groups-chosen"])
+def test_the_prefill_kernel_is_the_plain_fold(heads, group, masked):
+    """``softmax . [c | k_pe]`` of the kernel (under Pallas' TPU interpret
+    mode) against the plain fold over one float32 leaf, at the published
+    latent width: the same to float32 rounding in the ``rank`` channels the
+    kernel sums, and zero in the rope channels it leaves out."""
+    n, span, block = SEGMENT["n"], SEGMENT["span"], SEGMENT["block"]
+    c = len(SEGMENT["starts"])
+    rng = np.random.default_rng(heads + masked)
+    query = jnp.asarray(0.1 * rng.normal(size=(c, n, heads, SHAPE.latent)),
+                        jnp.float32)
+    latent = jnp.asarray(rng.normal(size=(2, 3, span, SHAPE.latent)),
+                         jnp.float32)
+    rows = jnp.asarray(SEGMENT["rows"], jnp.int32)
+    start = jnp.asarray(SEGMENT["starts"], jnp.int32)
+    chosen = None
+    if masked:
+        kept = rng.random((c, n, span)) < 0.4
+        kept[:, :, 0] = True  # every query keeps a slot it sees
+        chosen = jnp.asarray(kept, jnp.bfloat16)
+    got = mla._segment_attention(
+        query.transpose(0, 2, 1, 3), latent, jnp.int32(1), rows, start,
+        SHAPE.scale, SHAPE.rank, block, group, chosen,
+        interpret=pltpu.InterpretParams())
+    want = mla._segment_attention_plain(query, latent, jnp.int32(1), rows,
+                                        start, SHAPE.scale, block, chosen)
+    assert got.shape == want.shape == (c, heads, n, SHAPE.latent)
+    got, want = np.asarray(got), np.asarray(want)
+    assert not got[..., SHAPE.rank:].any()
+    np.testing.assert_allclose(got[..., :SHAPE.rank], want[..., :SHAPE.rank],
+                               rtol=1e-5, atol=1e-5)
+    # the tolerance sees one slot too many: the pair at the span's end with
+    # the slot before its segment masked reads otherwise
+    if not masked:
+        hidden = jnp.asarray(np.arange(span) != 287, jnp.bfloat16)
+        fewer = mla._segment_attention_plain(
+            query, latent, jnp.int32(1), rows, start, SHAPE.scale, block,
+            jnp.broadcast_to(hidden, (c, n, span)))
+        assert np.abs(np.asarray(fewer)[3] - want[3]).max() > 1e-3
+
+
+@pytest.mark.parametrize("backend,heads,n,width,span,group", [
+    ("tpu", 64, 128, 576, 30848, 32),  # GLM-5's cell: two groups of 32
+    ("tpu", 16, 128, 576, 7424, 16),   # Moonlight's: one group
+    ("tpu", 16, 16, 576, 640, 16),     # a short segment: every head
+    ("tpu", 24, 256, 576, 640, 12),    # a divisor of the heads
+    ("tpu", 64, 128, 584, 30848, 0),   # a width of no whole tiles
+    ("tpu", 64, 128, 576, 30800, 0),   # a span of no whole lane tiles
+    ("tpu", 4, 8, 576, 640, 0),        # a segment of no whole tiles
+    ("cpu", 64, 128, 576, 30848, 0)],
+    ids=["tpu-glm5", "tpu-moonlight", "tpu-short", "tpu-divisor",
+         "tpu-ragged-width", "tpu-ragged-span", "tpu-ragged-segment", "cpu"])
+def test_the_prefill_kernel_is_picked_by_backend_and_shapes_alone(
+        monkeypatch, backend, heads, n, width, span, group):
+    """``segment_group`` is what ``segment_attention`` asks: the heads of a
+    grid step where the kernel runs, 0 (the plain fold) elsewhere."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    shape = SHAPE._replace(heads=heads, rank=width - SHAPE.rope)
+    assert mla.segment_group(shape, n, span) == group

@@ -408,6 +408,8 @@ def test_the_model_generates_through_the_stage(
     spans = tracer.recent()
     (prefill,) = [r for r in spans if r.name == "ar_generate.prefill"][-1:]
     assert prefill.attributes["attention_path"] == "absorbed"
+    # the plain fold: no slot through the prefill's kernel
+    assert prefill.attributes["keys_fused"] == 0
     decodes = [r for r in spans if r.name == "ar_generate.decode"][-3:]
     assert [d.attributes["steps"] for d in decodes] == [2, 2, 1]
     assert all(d.attributes["attention_path"] == "absorbed" for d in decodes)

@@ -592,6 +592,51 @@ def test_mla_token_kernel_compiles(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e8
 
 
+@pytest.mark.parametrize("heads,rows,span,masked", [
+    (64, 16, 30848, True), (16, 128, 7424, False)],
+    ids=["glm5", "moonlight"])
+def test_mla_segment_kernel_compiles(heads, rows, span, masked, one_chip,
+                                     no_compile_cache, as_on_the_chip):
+    """``ops/mla``'s prefill kernel alone at a cell's prefill shape: 16
+    pairs of 128 queries' absorbed queries x ``heads`` heads x 576 channels
+    against the channel-major view of the latent leaf (5 layers x ``rows``
+    rows x ``span`` slots), blocks of 512, and GLM-5's selection mask; the
+    heads in groups of 32 (GLM-5: two a pair) or all 16 (Moonlight)."""
+    from sparkdl_tpu.ops import mla
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shape = mla.Shape(heads=heads, nope=192, rope=64, v=256, rank=512,
+                      theta=1e6, kv_eps=1e-6, scale=256 ** -0.5)
+    group = mla.segment_group(shape, 128, span)
+    assert group == min(heads, 32)
+    chosen = spec(jnp.bfloat16, 16, 128, span) if masked else None
+    compiled = jax.jit(
+        lambda query, latent, layer, rows, start, chosen:
+        mla._segment_attention(query, latent, layer, rows, start,
+                               shape.scale, shape.rank, 512, group, chosen)
+    ).lower(
+        spec(jnp.bfloat16, 16, heads, 128, 576),
+        spec(jnp.bfloat16, 5, rows, span, 576), spec(jnp.int32),
+        spec(jnp.int32, 16), spec(jnp.int32, 16), chosen,
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%mla_segment[.\d]* = ", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+    _fits(compiled)
+    # the leaf is read where it lies: its channel-major view is a bitcast,
+    # and there is no copy of it, nor anything of a layer's share of it among
+    # the temporaries (a standalone compile's only ones: the queries and the
+    # output in the kernel's layout)
+    assert re.search(rf"bf16\[5,{rows},576,{span}\]\S* bitcast\(", text)
+    assert not [line for line in text.splitlines()
+                if f"bf16[5,{rows},{span},576]" in line
+                and re.search(r"= \S+ copy(-start)?\(", line)]
+    queries = 16 * heads * 128 * 576 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * queries
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
         program, one_chip, no_compile_cache, as_on_the_chip):
@@ -636,12 +681,13 @@ def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
             lambda p, s: ml.decode(p, cfg, s, 8), donate_argnums=(1,)
         ).lower(params, state).compile()
     text = compiled.as_text()
-    # gate, up and down of the expert layers' scan body; in decode the
-    # attention's kernel besides, once in each of the two layer scans'
-    # bodies (the dense layer's and the expert layers')
-    assert text.count("tpu_custom_call") == (3 if program == "prefill" else 5)
+    # gate, up and down of the expert layers' scan body; the attention's
+    # kernel besides, once in each of the two layer scans' bodies (the dense
+    # layer's and the expert layers')
+    assert text.count("tpu_custom_call") == 5
+    kernel = "mla_segment" if program == "prefill" else "mla_token"
+    assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2
     if program == "decode":
-        assert len(re.findall(r"%mla_token[.\d]* = ", text)) == 2
         # the scores of every row against the whole span are gone from HBM
         assert "f32[128,7424,16]" not in text
     leaf = "bf16[5,128,7424,576]"
@@ -736,10 +782,15 @@ def test_glm5_programs_compile_and_fit(
         ).lower(params, state).compile()
     text = compiled.as_text()
     # gate, up and down of the expert layers' scan body; in prefill the
-    # selection kernel besides, once in each of the two layer scans
-    assert text.count("tpu_custom_call") == (5 if program == "prefill" else 3)
+    # selection kernel and the attention's besides, once each in each of the
+    # two layer scans
+    assert text.count("tpu_custom_call") == (7 if program == "prefill" else 3)
     assert len(re.findall(r"%dsa_select[.\d]* = ", text)) == (
         2 if program == "prefill" else 0)
+    assert len(re.findall(r"%mla_segment[.\d]* = ", text)) == (
+        2 if program == "prefill" else 0)
+    # the prefill fold's float32 accumulator is held in fast memory
+    assert "f32[64,128,576]" not in text
     assert not [line for line in text.splitlines()
                 if ("bf16[5,16,30848,576]" in line
                     or "bf16[5,16,576,30848]" in line
