@@ -382,3 +382,11 @@ class MoonlightModel(HybridModel):
         to each row's position where its kernel runs, the whole span where
         the plain form does (:func:`~sparkdl_tpu.ops.mla.token_slots_read`)."""
         return mla.token_slots_read(positions, self.config.mla.latent, span)
+
+    def slots_written_fused(self, rows: int, steps: int, span: int) -> int:
+        """The new latent slots a decode dispatch of ``steps`` steps writes
+        inside :func:`~sparkdl_tpu.ops.mla.mla_token`'s kernel, over every
+        layer (:func:`~sparkdl_tpu.ops.mla.token_slots_written`)."""
+        cfg = self.config
+        return steps * cfg.num_hidden_layers * mla.token_slots_written(
+            rows, cfg.mla.latent, span)

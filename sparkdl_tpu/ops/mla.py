@@ -37,9 +37,12 @@ can see (the backend and the shapes; no argument, no environment variable):
   whole lane tiles, ONE Pallas kernel (:func:`_token_kernel`): a grid step
   a row, which copies the row's blocks of ``TOKEN_BLOCK`` slots up to its
   position, each once, out of the leaf where it lies and scores, softmaxes
-  and sums all heads against that one copy;
-- elsewhere (the tier-1 tests' CPU, the rehearsal) plain ``jax.numpy``:
-  every slot of the span scored, the slots past a row's position masked.
+  and sums all heads against that one copy, after it has put the row's new
+  latent into its slot there and sent the tile that holds it back to the
+  leaf, in place;
+- elsewhere (the tier-1 tests' CPU, the rehearsal) plain ``jax.numpy``
+  after :func:`write_positions`: every slot of the span scored, the slots
+  past a row's position masked.
 
 **Two forms of the prefill's attention** (:func:`segment_attention`),
 picked by what :func:`segment_group` can see (the backend and the shapes):
@@ -543,8 +546,17 @@ def token_slots_read(positions, width: int, span: int) -> int:
     return int((positions // block + 1).sum()) * block
 
 
-def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
-                  buffer, copies, first, *, block, span, scale):
+def token_slots_written(rows: int, width: int, span: int) -> int:
+    """The new slots a call of :func:`mla_token` for ``rows`` rows writes
+    inside its attention kernel: every row's where the kernel runs
+    (:func:`token_block`), none where :func:`write_positions` writes them
+    before the plain form."""
+    return rows if token_block(width, span) else 0
+
+
+def _token_kernel(layer_ref, position_ref, query_ref, new_ref, latent_ref,
+                  out_ref, leaf_ref, buffer, copies, written, first, *, block,
+                  span, scale):
     """One row's attention over its cache: the blocks of ``block`` slots
     that hold one at or before the row's position, each copied once from
     the leaf in HBM (``latent_ref`` [layers, rows, width, span], the
@@ -554,10 +566,20 @@ def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
     moved back to end with it, and what it shares with the block before is
     masked.  The copies run on across rows (the grid is sequential): a
     row's last block starts the next row's first, and ``first`` (SMEM) says
-    which half that went to."""
+    which half that went to.
+
+    The row's new latent (``new_ref`` [width, WRITE_BLOCK]: the latents of
+    the ``WRITE_BLOCK`` rows from this one's tile of rows on, channel-major)
+    goes into its slot in the last block once that block has landed, before
+    the block is scored; the tile of ``WRITE_BLOCK`` slots (of the block,
+    where that is narrower) that holds the slot then goes back from fast
+    memory to the leaf (``leaf_ref``, aliased to ``latent_ref``) while the
+    block is scored, its other slots as they were read."""
     row, rows = pl.program_id(0), pl.num_programs(0)
     layer, position = layer_ref[0], position_ref[row]
     heads = query_ref.shape[0]
+    width = buffer.shape[1]
+    wide = min(WRITE_BLOCK, block)  # the slots written back
 
     def copy(r, j, half):
         at = jnp.minimum(j * block, span - block)
@@ -565,12 +587,26 @@ def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
             latent_ref.at[layer, r, :, pl.ds(at, block)], buffer.at[half],
             copies.at[half])
 
+    def tile_of(j):
+        # the first slot of the tile of ``wide`` that holds the row's
+        # position, in the leaf and in block j's copy
+        at = jnp.minimum(j * block, span - block)
+        tile = pl.multiple_of(position // wide * wide, wide)
+        return tile, pl.multiple_of(tile - at, wide)
+
+    def write_back(j, half):
+        tile, held = tile_of(j)
+        return pltpu.make_async_copy(
+            buffer.at[half, :, pl.ds(held, wide)],
+            leaf_ref.at[layer, row, :, pl.ds(tile, wide)], written)
+
     @pl.when(row == 0)
     def _():
         first[0] = 0
         copy(0, 0, 0).start()
 
     blocks = position // block + 1
+    last_half = (first[0] + blocks - 1) % 2
     query = query_ref[...]
     within = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
 
@@ -587,6 +623,25 @@ def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
             copy(row + 1, 0, 1 - half).start()
 
         copy(row, j, half).wait()
+
+        @pl.when(j + 1 == blocks)
+        def _():
+            # the row's column of its tile of rows, on every lane (a
+            # maximum over one real value: exact), into the slot's lane
+            lanes = jax.lax.broadcasted_iota(jnp.int32, new_ref.shape, 1)
+            column = jnp.max(
+                jnp.where(lanes == row % WRITE_BLOCK,
+                          new_ref[...].astype(jnp.float32), -jnp.inf),
+                axis=1, keepdims=True)
+            tile, held = tile_of(j)
+            slots = buffer.at[half, :, pl.ds(held, wide)]
+            slots[...] = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (1, wide), 1)
+                == position - tile,
+                jnp.broadcast_to(column, (width, wide)).astype(slots.dtype),
+                slots[...])
+            write_back(j, half).start()
+
         slots = buffer[half]  # [width, block]
         scores = jax.lax.dot_general(
             query, slots, (((1,), (0,)), ((), ())),
@@ -608,36 +663,55 @@ def _token_kernel(layer_ref, position_ref, query_ref, latent_ref, out_ref,
          jnp.zeros((heads, 1), jnp.float32),
          jnp.zeros(query_ref.shape, jnp.float32)))
     out_ref[...] = (acc / total).astype(out_ref.dtype)
+    # the half is the next row's second block's: the tile has to be out
+    write_back(blocks - 1, last_half).wait()
     first[0] = (first[0] + blocks) % 2
 
 
-def _token_attention(query, latent, layer, position, scale: float,
+def _token_attention(query, new, latent, layer, position, scale: float,
                      block: int, interpret=False):
-    """``softmax . [c | k_pe]`` [r, H, width] in the cache's dtype for the
-    absorbed ``query`` [r, H, width] of every row against layer ``layer`` of
-    the leaf ``latent`` [layers, r, span, width], slots past ``position``
-    [r] masked: ONE Pallas kernel (:func:`_token_kernel`), a grid step a
-    row, that reads of the leaf only each row's blocks up to its position,
-    once, in the channel-major view the chip stores."""
+    """``(out, leaf)``: ``softmax . [c | k_pe]`` [r, H, width] in the
+    cache's dtype for the absorbed ``query`` [r, H, width] of every row
+    against layer ``layer`` of the leaf ``latent`` [layers, r, span, width]
+    with each row's ``new`` [r, width] latent written at its ``position``
+    [r] first, slots past it masked, and the leaf so written: ONE Pallas
+    kernel (:func:`_token_kernel`), a grid step a row, that reads of the
+    leaf only each row's blocks up to its position, once, in the
+    channel-major view the chip stores, and writes back of it only the
+    ``WRITE_BLOCK``-slot tile that holds the new slot, in place (the leaf is
+    aliased)."""
     r, heads, width = query.shape
     span = latent.shape[2]
     row_block = pl.BlockSpec((None, heads, width), lambda i, *_: (i, 0, 0))
-    return pl.pallas_call(
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # channel-major, WRITE_BLOCK rows a block: a row's latent is a column of
+    # its tile of rows
+    tiles = -(-r // WRITE_BLOCK) * WRITE_BLOCK
+    new = jnp.pad(new.astype(latent.dtype).T, ((0, 0), (0, tiles - r)))
+    out, leaf = pl.pallas_call(
         functools.partial(_token_kernel, block=block, span=span, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(r,),
-            in_specs=[row_block, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=row_block,
+            in_specs=[row_block,
+                      pl.BlockSpec((width, WRITE_BLOCK),
+                                   lambda i, *_: (0, i // WRITE_BLOCK)),
+                      hbm],
+            out_specs=[row_block, hbm],
             scratch_shapes=[pltpu.VMEM((2, width, block), latent.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA(()),
                             pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct(query.shape, latent.dtype),
+        out_shape=[jax.ShapeDtypeStruct(query.shape, latent.dtype),
+                   jax.ShapeDtypeStruct(
+                       (*latent.shape[:2], width, span), latent.dtype)],
+        input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="mla_token",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), position.astype(jnp.int32),
-      query.astype(latent.dtype), jnp.swapaxes(latent, 2, 3))
+      query.astype(latent.dtype), new, jnp.swapaxes(latent, 2, 3))
+    return out, jnp.swapaxes(leaf, 2, 3)
 
 
 def _token_attention_plain(query, latent, layer, position, scale: float,
@@ -665,20 +739,22 @@ def mla_token(lp, u, latent, layer, position, shape: Shape):
     position's latent goes into its slot first, then ``[q_lat | q_pe]``
     scores the row's slots up to its position, softmax in float32,
     ``softmax . [c | k_pe]`` in latent space and ``W_UV`` after.  One
-    kernel on the TPU at widths it takes (:func:`token_block`), plain
-    ``jax.numpy`` elsewhere.  Returns (the output projection's input [r,
+    kernel on the TPU at widths it takes (:func:`token_block`), which
+    writes the slot itself; plain ``jax.numpy`` elsewhere, after
+    :func:`write_positions`.  Returns (the output projection's input [r,
     H v], latent)."""
     r = u.shape[0]
     q_nope, q_pe, new = project(lp, u, position, shape)
-    latent = write_positions(
-        latent, new[:, None], layer, jnp.arange(r, dtype=jnp.int32),
-        position, jnp.ones((r,), jnp.int32))
+    block = token_block(shape.latent, latent.shape[2])
+    if not block:
+        latent = write_positions(
+            latent, new[:, None], layer, jnp.arange(r, dtype=jnp.int32),
+            position, jnp.ones((r,), jnp.int32))
     w_uk, w_uv = _up(lp, shape)
     query = absorbed_query(q_nope, q_pe, w_uk)  # [r, H, r + rope]
-    block = token_block(shape.latent, latent.shape[2])
     if block:
-        out = _token_attention(query, latent, layer, position, shape.scale,
-                               block)
+        out, latent = _token_attention(query, new, latent, layer, position,
+                                       shape.scale, block)
     else:
         out = _token_attention_plain(query, latent, layer, position,
                                      shape.scale)

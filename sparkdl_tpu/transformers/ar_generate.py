@@ -55,7 +55,11 @@ attention scores the whole span, whole blocks up to each row's position
 where Moonlight's decode kernel walks them — and ``slots_visible``: those
 at or before each row's own position; ``slots_selected`` where the model
 selects: those its attention scores after selection, the model's
-``slots_selected(positions, span)``), and on both
+``slots_selected(positions, span)``; ``slots_written_fused`` where the
+model's decode kernel can write the new slots itself: those it wrote in the
+dispatch, the model's ``slots_written_fused(rows, steps, span)`` — rows x
+steps x layers on Moonlight's kernel, 0 where ``write_positions`` writes
+them), and on both
 ``attention_path`` where the model's ``attention_paths`` names the form each
 program takes (``"absorbed"``; ``"masked"``: selected keys by a mask
 over every visible one),
@@ -458,6 +462,9 @@ def _generate_batch(runner: _Runner, prompts, rows: int, gen: int,
             if hasattr(model, "slots_selected"):
                 decoded["slots_selected"] = model.slots_selected(
                     positions, plan.span)
+            if hasattr(model, "slots_written_fused"):
+                decoded["slots_written_fused"] = model.slots_written_fused(
+                    rows, now, plan.span)
             with tracer.boundary(
                     "ar_generate.decode", steps=now, rows=rows,
                     slots_read=model.slots_read(positions, plan.span),

@@ -62,7 +62,8 @@ def _latent(span, rows, seed):
 def test_the_kernel_is_the_plain_form_over_ragged_positions(span):
     """``softmax . [c | k_pe]`` of both forms against one leaf: every row's
     blocks up to its position, the last one's overlap with the block before
-    masked, slots past the position left out."""
+    masked, slots past the position left out.  The kernel is handed each
+    row's slot as the leaf holds it, and gives the leaf back as it was."""
     rows = len(CASES[span])
     rng = np.random.default_rng(span)
     query = jnp.asarray(0.1 * rng.normal(size=(rows, SHAPE.heads,
@@ -70,8 +71,11 @@ def test_the_kernel_is_the_plain_form_over_ragged_positions(span):
     latent = _latent(span, rows, seed=1)
     position = jnp.asarray(CASES[span], jnp.int32)
     block = min(mla.TOKEN_BLOCK, span)
-    got = _interpreted_kernel(query, latent, jnp.int32(1), position,
-                              SHAPE.scale, block)
+    got, leaf = _interpreted_kernel(
+        query, latent[1, jnp.arange(rows), position], latent, jnp.int32(1),
+        position, SHAPE.scale, block)
+    np.testing.assert_array_equal(np.asarray(leaf, np.float32),
+                                  np.asarray(latent, np.float32))
     want = mla._token_attention_plain(query, latent, jnp.int32(1), position,
                                       SHAPE.scale)
     assert got.dtype == want.dtype == jnp.bfloat16
@@ -87,18 +91,33 @@ def test_the_kernel_is_the_plain_form_over_ragged_positions(span):
     assert (moved[:-1] > 2 * TOLERANCE * size[:-1]).all()
 
 
-@pytest.mark.parametrize("span", sorted(CASES))
+#: (span, positions) of the kernel's own write besides CASES: a lane tile's
+#: last and first slot (127, 128) in a span of several blocks, the first
+#: slot of the moved-back last block of 2.25 (1,024: its lane 384) and one
+#: inside it; and more rows than a tile of rows (the kernel takes each row's
+#: new latent from its tile of 128 rows), their positions wrapping the span
+WRITES = {
+    "1152": (1152, CASES[1152]), "384": (384, CASES[384]),
+    "tile-edges-and-moved-back": (1152, [127, 128, 0, 1024, 1100]),
+    "rows-past-a-tile": (384, [(7 * i) % 384 for i in range(130)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITES))
 def test_mla_token_writes_and_attends_alike_on_both_branches(
-        span, monkeypatch):
-    """``mla_token`` whole, plain here and then on the kernel's branch: the
-    leaf as written is the same to the bit, the output within bfloat16
-    rounding."""
-    rows = len(CASES[span])
+        case, monkeypatch):
+    """``mla_token`` whole, plain here (``write_positions``, then the plain
+    form) and then on the kernel's branch, where the kernel writes each
+    row's new latent itself: the leaf as written is the same to the bit,
+    every other slot, row and layer keeps its bytes, the new slots read
+    back, and the output is the same within bfloat16 rounding."""
+    span, positions = WRITES[case]
+    rows = len(positions)
     lp = _layer_params(seed=4)
     rng = np.random.default_rng(5)
     u = jnp.asarray(rng.normal(size=(rows, HIDDEN)), jnp.bfloat16)
     latent = _latent(span, rows, seed=6)
-    position = jnp.asarray(CASES[span], jnp.int32)
+    position = jnp.asarray(positions, jnp.int32)
     assert mla.token_block(SHAPE.latent, span) == 0
     want, want_leaf = mla.mla_token(lp, u, latent, jnp.int32(1), position,
                                     SHAPE)
@@ -111,15 +130,17 @@ def test_mla_token_writes_and_attends_alike_on_both_branches(
         return _interpreted_kernel(*args)
 
     monkeypatch.setattr(mla, "_token_attention", kernel)
+    monkeypatch.setattr(mla, "write_positions", None)  # not on this branch
     got, leaf = mla.mla_token(lp, u, latent, jnp.int32(1), position, SHAPE)
     assert ran == [min(mla.TOKEN_BLOCK, span)]
-    np.testing.assert_array_equal(np.asarray(leaf, np.float32),
-                                  np.asarray(want_leaf, np.float32))
-    # the new slots are written, and read by the attention that follows
+    leaf, before = np.asarray(leaf, np.float32), np.asarray(latent, np.float32)
+    np.testing.assert_array_equal(leaf, np.asarray(want_leaf, np.float32))
+    # the new slots read back, and nothing else moved
     _, _, new = mla.project(lp, u, position, SHAPE)
-    np.testing.assert_array_equal(
-        np.asarray(leaf[1, np.arange(rows), position], np.float32),
-        np.asarray(new, np.float32))
+    written = (1, np.arange(rows), np.asarray(position))
+    np.testing.assert_array_equal(leaf[written], np.asarray(new, np.float32))
+    leaf[written] = before[written]
+    np.testing.assert_array_equal(leaf, before)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     size = np.abs(want).max(axis=1)  # a row's largest
     assert (np.abs(got - want).max(axis=1) < 2 * TOLERANCE * size).all()

@@ -415,6 +415,8 @@ def test_the_model_generates_through_the_stage(
     assert all(d.attributes["attention_path"] == "absorbed" for d in decodes)
     assert sum(d.attributes["slots_read"] for d in decodes) == (
         4 * plan.span * (gen - 1))
+    # write_positions writes the new slots: none inside the attention
+    assert [d.attributes["slots_written_fused"] for d in decodes] == [0] * 3
     # at decode step t a row of L tokens sees L + t + 1 slots
     assert sum(d.attributes["slots_visible"] for d in decodes) == sum(
         n + t + 1 for n in LENGTHS for t in range(gen - 1))
@@ -425,9 +427,10 @@ def test_the_kernels_branch_through_the_stage_reads_whole_blocks(
     """The decode on the kernel's branch, as on the chip: ``token_block``
     says blocks of 8 slots (the chip's are 512 at the published width) and
     the kernel runs in Pallas' TPU interpreter inside the layer scans.
-    Every generated position is still the reference's, and ``slots_read``
+    Every generated position is still the reference's, ``slots_read``
     counts whole blocks up to each row's position, where the plain branch
-    above counts the whole span."""
+    above counts the whole span, and ``slots_written_fused`` every row's new
+    slot in every layer, where the plain branch counts none."""
     from jax.experimental.pallas import tpu as pltpu
 
     from sparkdl_tpu import AutoregressiveTransformer
@@ -468,6 +471,9 @@ def test_the_kernels_branch_through_the_stage_reads_whole_blocks(
         8 * ((n + t) // 8 + 1) for n in LENGTHS for t in range(gen - 1))
     assert sum(d.attributes["slots_visible"] for d in decodes[-3:]) == sum(
         n + t + 1 for n in LENGTHS for t in range(gen - 1))
+    # the kernel writes each row's new slot itself: rows x steps x layers
+    assert [d.attributes["slots_written_fused"] for d in decodes[-3:]] == [
+        4 * steps * CONFIG["num_hidden_layers"] for steps in (2, 2, 1)]
 
 
 def test_a_model_without_paths_leaves_the_spans_without_one(

@@ -570,26 +570,38 @@ def test_solar_programs_compile_and_fit(
 
 def test_mla_token_kernel_compiles(one_chip, no_compile_cache):
     """``ops/mla``'s decode kernel alone at the cell's decode shape: 128
-    rows' absorbed queries of 16 heads x 576 channels against the channel-
-    major view of the latent leaf, 5 layers x 7,424 slots, blocks of 512
-    (the span is 14.5 of them: the last one moved back)."""
+    rows' absorbed queries of 16 heads x 576 channels and their new latents
+    against the channel-major view of the latent leaf, 5 layers x 7,424
+    slots, blocks of 512 (the span is 14.5 of them: the last one moved
+    back); the leaf aliased to the kernel's second output, which writes
+    each row's new slot back in place."""
     from sparkdl_tpu.ops import mla
 
     def spec(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     compiled = jax.jit(
-        lambda query, latent, layer, position: mla._token_attention(
-            query, latent, layer, position, 192 ** -0.5, mla.TOKEN_BLOCK)
+        lambda query, new, latent, layer, position: mla._token_attention(
+            query, new, latent, layer, position, 192 ** -0.5,
+            mla.TOKEN_BLOCK),
+        donate_argnums=(2,),
     ).lower(
-        spec(jnp.bfloat16, 128, 16, 576), spec(jnp.bfloat16, 5, 128, 7424, 576),
-        spec(jnp.int32), spec(jnp.int32, 128),
+        spec(jnp.bfloat16, 128, 16, 576), spec(jnp.bfloat16, 128, 576),
+        spec(jnp.bfloat16, 5, 128, 7424, 576), spec(jnp.int32),
+        spec(jnp.int32, 128),
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
     _fits(compiled)
-    # the leaf is read where it lies: nothing of its size among the
-    # temporaries
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+    # the leaf is read and written where it lies: donated into the kernel's
+    # output, never copied, nothing of its size among the temporaries
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 5 * 128 * 7424 * 576 * 2
+    assert not [line for line in text.splitlines()
+                if ("bf16[5,128,7424,576]" in line
+                    or "bf16[5,128,576,7424]" in line)
+                and re.search(r"= \S+ copy(-start)?\(", line)]
+    assert m.temp_size_in_bytes < 1e8
 
 
 @pytest.mark.parametrize("heads,rows,span,masked", [
@@ -690,6 +702,14 @@ def test_moonlight_programs_take_the_latent_leaf_where_it_lies(
     if program == "decode":
         # the scores of every row against the whole span are gone from HBM
         assert "f32[128,7424,16]" not in text
+        # the kernel writes each row's new slot itself: no 128-slot window
+        # of every row gathered, and no scatter or dynamic-update-slice of
+        # the leaf outside it
+        assert "bf16[128,576,128]" not in text
+        assert not [line for line in text.splitlines()
+                    if re.search(r"bf16\[5,128,(7424,576|576,7424)\]", line)
+                    and re.search(r"= \S+ (scatter|dynamic-update-slice)\(",
+                                  line)]
     leaf = "bf16[5,128,7424,576]"
     # the kernel takes the channel-major view, a bitcast of the leaf
     assert not [line for line in text.splitlines()
